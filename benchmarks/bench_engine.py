@@ -251,6 +251,16 @@ def mesh_child(k: int):
 
 
 def run_mesh():
+    """CPU-only sweep: each device count runs in a child process with
+    forced host devices.  On an accelerator host the children would
+    compete with this process for the chip, so it refuses there."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"engine_mesh sweeps forced CPU host devices in child "
+            f"processes; on a {jax.default_backend()} backend a child "
+            f"cannot reach the chip this process holds.  Run it with "
+            f"JAX_PLATFORMS=cpu; the on-chip client mesh runs in one "
+            f"process (python chip_smoke.py --chips 4)")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -272,7 +282,7 @@ def run_mesh():
         rec = json.loads(out.stdout.strip().splitlines()[-1])
         sweep.append(rec)
         on, off = rec["donate"]["on"], rec["donate"]["off"]
-        emit(f"engine_mesh{k}", 1e6 / max(on["rounds_per_sec"], 1e-9),
+        emit(f"engine_mesh{k}_cpu", 1e6 / max(on["rounds_per_sec"], 1e-9),
              f"rps_on={on['rounds_per_sec']:.2f};"
              f"rps_off={off['rounds_per_sec']:.2f};"
              f"peak_on={on['peak_live_bytes']};"
@@ -283,10 +293,11 @@ def run_mesh():
         with open(path) as f:
             record = json.load(f)
     record["mesh"] = {
-        "policy": POLICY, "n": N_MESH, "rounds": ROUNDS,
-        "note": "forced host devices; donate on/off compared per device "
-                "count.  peak_live_bytes = argument+output+temp-alias of "
-                "the compiled fused server step (donation aliases the "
+        "policy": POLICY, "n": N_MESH, "rounds": ROUNDS, "platform": "cpu",
+        "note": "forced CPU host devices, not a chip measurement; donate "
+                "on/off compared per device count.  peak_live_bytes = "
+                "argument+output+temp-alias of the compiled fused server "
+                "step (donation aliases the "
                 "previous global model + caches into the outputs)",
         "sweep": sweep}
     os.makedirs(RESULTS, exist_ok=True)

@@ -41,8 +41,8 @@ def main() -> None:
         "roofline": bench_roofline,
         "server_step": bench_server_step,
         "engine": bench_engine,
-        # client-mesh sweep (forced-host-device subprocesses, so it works
-        # from this single-device parent process)
+        # CPU-only client-mesh sweep (forced-host-device subprocesses, so
+        # it works from this single-device parent; refuses on a TPU)
         "engine_mesh": types.SimpleNamespace(run=bench_engine.run_mesh),
         # host-RNG vs device-resident fleet-draw paths (repro.fleet)
         "engine_dynamics": types.SimpleNamespace(

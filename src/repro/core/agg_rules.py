@@ -40,7 +40,6 @@ from typing import Dict, Tuple, Type
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.fed_agg.ops import (fed_agg_packed,
@@ -195,11 +194,11 @@ class TrustRule(AggRule):
                     axis)
                 return vec, new_t
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis), P(axis, None), P(None), P(axis)),
                 out_specs=(P(), P(axis)),
-                check_rep=False)(weights, buf, gvec, state)
+                check_vma=False)(weights, buf, gvec, state)
 
         w = weights.astype(jnp.float32)
         dist = residual_norms(buf, gvec, impl=impl, block_c=block_c,

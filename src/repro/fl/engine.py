@@ -927,39 +927,7 @@ class FleetEngine:
         block (O(X·D), fleet-size-independent) and the host side is the
         store's current live rows.
         """
-        N = self.fl_cfg.num_clients
-        rows = N if self.cohort is None else int(self.cohort)
-        step = self._server_step(uses_cache)
-        meta_only = self.offload is not None
-        caches = core.init_caches({} if meta_only else self._template, N)
-        stacked = jax.tree.map(
-            lambda a: jnp.zeros((rows,) + a.shape, a.dtype),
-            self._template)
-        if self.mesh is not None:
-            caches = SP.place_fleet(caches, self.mesh, N)
-            stacked = SP.place_fleet(stacked, self.mesh, rows)
-        mask = self._put1(np.zeros(rows, bool))
-        steps_i = self._put1(np.zeros(rows, np.int32))
-        ones = self._put1(np.ones(N, np.float32))
-        rule_state = self._init_rule_state()
-        extra = self._step_extra(rule_state)
-        # lower() only traces — nothing executes, nothing is donated
-        if self.cohort is None:
-            lowered = step.lower(self._template, caches, stacked, stacked,
-                                 steps_i, mask, mask, mask, mask,
-                                 self._n_samples, ones, 0, *extra)
-        elif meta_only:
-            idx = self._put1(np.arange(rows, dtype=np.int32))
-            mask_n = self._put1(np.zeros(N, bool))
-            lowered = step.lower(self._template, caches, stacked,
-                                 steps_i, idx, mask_n, mask, mask, mask_n,
-                                 self._n_samples, ones, 0, *extra)
-        else:
-            idx = self._put1(np.arange(rows, dtype=np.int32))
-            mask_n = self._put1(np.zeros(N, bool))
-            lowered = step.lower(self._template, caches, stacked, stacked,
-                                 steps_i, idx, mask_n, mask, mask, mask_n,
-                                 self._n_samples, ones, 0, *extra)
+        lowered, caches, rule_state = self._lower_server_step(uses_cache)
         ma = lowered.compile().memory_analysis()
         out = {"argument_bytes": int(ma.argument_size_in_bytes),
                "output_bytes": int(ma.output_size_in_bytes),
@@ -969,6 +937,9 @@ class FleetEngine:
                                   + out["output_bytes"]
                                   + out["temp_bytes"]
                                   - out["alias_bytes"])
+        rows = self.fl_cfg.num_clients if self.cohort is None \
+            else int(self.cohort)
+        meta_only = self.offload is not None
         layout = core.pack_layout(self._template)
         out["packed_rows"] = rows
         out["packed_buffer_bytes"] = layout.buffer_bytes(rows)
@@ -992,6 +963,51 @@ class FleetEngine:
                 + tree_bytes(caches.params)
             out["cache_host_bytes"] = 0
         return out
+
+    def compiled_server_step(self, uses_cache: bool = True):
+        """The fused server step compiled for representative round
+        inputs: ``as_text()`` shows which aggregation path it lowered to
+        (a ``tpu_custom_call`` per Pallas kernel under
+        ``agg_impl="pallas"`` on a TPU)."""
+        return self._lower_server_step(uses_cache)[0].compile()
+
+    def _lower_server_step(self, uses_cache: bool):
+        """Lower the active server step on representative round inputs;
+        returns ``(lowered, caches, rule_state)``.  ``lower()`` only
+        traces — nothing executes, nothing is donated."""
+        N = self.fl_cfg.num_clients
+        rows = N if self.cohort is None else int(self.cohort)
+        step = self._server_step(uses_cache)
+        meta_only = self.offload is not None
+        caches = core.init_caches({} if meta_only else self._template, N)
+        stacked = jax.tree.map(
+            lambda a: jnp.zeros((rows,) + a.shape, a.dtype),
+            self._template)
+        if self.mesh is not None:
+            caches = SP.place_fleet(caches, self.mesh, N)
+            stacked = SP.place_fleet(stacked, self.mesh, rows)
+        mask = self._put1(np.zeros(rows, bool))
+        steps_i = self._put1(np.zeros(rows, np.int32))
+        ones = self._put1(np.ones(N, np.float32))
+        rule_state = self._init_rule_state()
+        extra = self._step_extra(rule_state)
+        if self.cohort is None:
+            lowered = step.lower(self._template, caches, stacked, stacked,
+                                 steps_i, mask, mask, mask, mask,
+                                 self._n_samples, ones, 0, *extra)
+        elif meta_only:
+            idx = self._put1(np.arange(rows, dtype=np.int32))
+            mask_n = self._put1(np.zeros(N, bool))
+            lowered = step.lower(self._template, caches, stacked,
+                                 steps_i, idx, mask_n, mask, mask, mask_n,
+                                 self._n_samples, ones, 0, *extra)
+        else:
+            idx = self._put1(np.arange(rows, dtype=np.int32))
+            mask_n = self._put1(np.zeros(N, bool))
+            lowered = step.lower(self._template, caches, stacked, stacked,
+                                 steps_i, idx, mask_n, mask, mask, mask_n,
+                                 self._n_samples, ones, 0, *extra)
+        return lowered, caches, rule_state
 
     def run(self, policy: Union[str, Policy], rounds: Optional[int] = None,
             time_budget: Optional[float] = None, eval_every: int = 1,

@@ -3,8 +3,11 @@
 Tiling: parameters are flattened to (C, D) and blocked (BC, BD); the grid is
 (nd, nc) with the client dimension innermost so each output tile accumulates
 in a VMEM fp32 scratch across client blocks (grid iterations on TPU are
-sequential over the trailing axis, so the scratch carries).  Weights ride in
-VMEM as (BC,) blocks; MXU sees a (1, BC) × (BC, BD) matmul per tile.
+sequential over the trailing axis, so the scratch carries).  Every block is
+2-D, as Mosaic requires of blocks that do not span their array: weights
+ride as (BC, 1) column blocks and the output/scratch as (1, BD) rows.  The
+reduction is a broadcast multiply plus a sum over the client (sublane)
+axis on the VPU, exact fp32 like the XLA reference.
 """
 from __future__ import annotations
 
@@ -23,9 +26,9 @@ def _agg_kernel(w_ref, u_ref, o_ref, acc_ref, *, n_cblocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = w_ref[...].astype(jnp.float32)          # (BC,)
+    w = w_ref[...].astype(jnp.float32)          # (BC, 1)
     u = u_ref[...].astype(jnp.float32)          # (BC, BD)
-    acc_ref[...] += jnp.einsum("c,cd->d", w, u)
+    acc_ref[...] += jnp.sum(w * u, axis=0, keepdims=True)
 
     @pl.when(j == n_cblocks - 1)
     def _done():
@@ -53,12 +56,12 @@ def fed_agg_pallas(updates: jnp.ndarray, weights: jnp.ndarray,
         functools.partial(_agg_kernel, n_cblocks=nc),
         grid=(nd, nc),
         in_specs=[
-            pl.BlockSpec((bc,), lambda i, j: (j,)),
+            pl.BlockSpec((bc, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((bc, bd), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((bd,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Dp,), updates.dtype),
-        scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bd), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Dp), updates.dtype),
+        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
         interpret=interpret,
-    )(weights, updates)
-    return out[:D]
+    )(weights.reshape(Cp, 1), updates)
+    return out[0, :D]

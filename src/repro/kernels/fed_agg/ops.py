@@ -5,11 +5,24 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.fed_agg.kernel import fed_agg_pallas
 from repro.kernels.fed_agg.ref import fed_agg_ref
+
+
+def interpret_flag(impl: str, kernel: str = "fed_agg") -> bool:
+    """The Pallas ``interpret`` flag for a kernel ``impl`` other than xla.
+
+    The interpreter is a CPU test tool: on a TPU backend
+    ``"pallas_interpret"`` raises instead of quietly running it there.
+    """
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown {kernel} impl: {impl!r}")
+    if impl == "pallas_interpret" and jax.default_backend() == "tpu":
+        raise ValueError(f"{kernel} impl 'pallas_interpret' runs the Pallas "
+                         f"interpreter; on a TPU use 'pallas'")
+    return impl == "pallas_interpret"
 
 
 def fed_agg(updates: jnp.ndarray, weights: jnp.ndarray, *,
@@ -22,7 +35,7 @@ def fed_agg(updates: jnp.ndarray, weights: jnp.ndarray, *,
         return fed_agg_ref(updates, weights)
     flat = updates.reshape(C, -1)
     out = fed_agg_pallas(flat, weights, block_c=block_c, block_d=block_d,
-                         interpret=(impl == "pallas_interpret"))
+                         interpret=interpret_flag(impl))
     return out.reshape(shape).astype(updates.dtype)
 
 
@@ -43,11 +56,8 @@ def fed_agg_packed(updates: jnp.ndarray, weights: jnp.ndarray, *,
     """
     if impl == "xla":
         return fed_agg_ref(updates, weights)
-    if impl not in ("pallas", "pallas_interpret"):
-        raise ValueError(f"unknown fed_agg impl: {impl!r}")
     return fed_agg_pallas(updates, weights, block_c=block_c,
-                          block_d=block_d,
-                          interpret=(impl == "pallas_interpret"))
+                          block_d=block_d, interpret=interpret_flag(impl))
 
 
 def fed_agg_packed_sharded(updates: jnp.ndarray, weights: jnp.ndarray, *,
@@ -66,8 +76,8 @@ def fed_agg_packed_sharded(updates: jnp.ndarray, weights: jnp.ndarray, *,
     Weights must already be normalized globally (Σw = 1 across ALL
     clients); each shard contributes w_local · u_local unscaled.
     """
-    if impl not in ("xla", "pallas", "pallas_interpret"):
-        raise ValueError(f"unknown fed_agg impl: {impl!r}")
+    if impl != "xla":
+        interpret_flag(impl)
 
     def partial_sum(w_blk, u_blk):
         # per-shard partial Σ_c w_c·u_c in fp32, then one cross-shard psum
@@ -76,7 +86,7 @@ def fed_agg_packed_sharded(updates: jnp.ndarray, weights: jnp.ndarray, *,
                               block_c=block_c, block_d=block_d)
         return jax.lax.psum(part.astype(jnp.float32), axis)
 
-    return shard_map(partial_sum, mesh=mesh,
-                     in_specs=(P(axis), P(axis, None)),
-                     out_specs=P(),
-                     check_rep=False)(weights, updates)
+    return jax.shard_map(partial_sum, mesh=mesh,
+                         in_specs=(P(axis), P(axis, None)),
+                         out_specs=P(),
+                         check_vma=False)(weights, updates)

@@ -6,9 +6,11 @@ the only part of the geometric median the weighted-sum kernel
 with the roles of the axes swapped: the packed (C, D) buffer is blocked
 (BC, BD) and the grid is (nc, nd) with the *parameter* dimension
 innermost, so each client block accumulates its squared residuals in a
-(BC,) VMEM fp32 scratch across D blocks (TPU grid iterations are
+(BC, 1) VMEM fp32 scratch across D blocks (TPU grid iterations are
 sequential over the trailing axis, so the scratch carries) and takes one
-sqrt at the flush.
+sqrt at the flush.  Every block is 2-D, as Mosaic requires of blocks that
+do not span their array: the center rides as (1, BD) rows and the output
+as (BC, 1) columns.
 """
 from __future__ import annotations
 
@@ -27,10 +29,10 @@ def _dist_kernel(z_ref, u_ref, o_ref, acc_ref, *, n_dblocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    z = z_ref[...].astype(jnp.float32)          # (BD,)
+    z = z_ref[...].astype(jnp.float32)          # (1, BD)
     u = u_ref[...].astype(jnp.float32)          # (BC, BD)
-    r = u - z[None, :]
-    acc_ref[...] += jnp.sum(r * r, axis=1)
+    r = u - z
+    acc_ref[...] += jnp.sum(r * r, axis=1, keepdims=True)
 
     @pl.when(j == n_dblocks - 1)
     def _done():
@@ -61,12 +63,12 @@ def residual_norms_pallas(updates: jnp.ndarray, center: jnp.ndarray,
         functools.partial(_dist_kernel, n_dblocks=nd),
         grid=(nc, nd),
         in_specs=[
-            pl.BlockSpec((bd,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bd), lambda i, j: (0, j)),
             pl.BlockSpec((bc, bd), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bc,), jnp.float32)],
+        out_specs=pl.BlockSpec((bc, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Cp, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bc, 1), jnp.float32)],
         interpret=interpret,
-    )(center, updates)
-    return out[:C]
+    )(center.reshape(1, Dp), updates)
+    return out[:C, 0]

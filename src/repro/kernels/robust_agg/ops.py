@@ -21,10 +21,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels.fed_agg.ops import fed_agg_packed
+from repro.kernels.fed_agg.ops import fed_agg_packed, interpret_flag
 from repro.kernels.robust_agg.kernel import residual_norms_pallas
 
 TINY = 1e-30
@@ -37,11 +36,9 @@ def residual_norms(updates: jnp.ndarray, center: jnp.ndarray, *,
     if impl == "xla":
         r = updates.astype(jnp.float32) - center.astype(jnp.float32)[None]
         return jnp.sqrt(jnp.sum(r * r, axis=1))
-    if impl not in ("pallas", "pallas_interpret"):
-        raise ValueError(f"unknown robust_agg impl: {impl!r}")
-    return residual_norms_pallas(updates, center, block_c=block_c,
-                                 block_d=block_d,
-                                 interpret=(impl == "pallas_interpret"))
+    return residual_norms_pallas(
+        updates, center, block_c=block_c, block_d=block_d,
+        interpret=interpret_flag(impl, "robust_agg"))
 
 
 def _weiszfeld_step(updates, w, z, *, eps, impl, block_c, block_d,
@@ -105,8 +102,9 @@ def geometric_median_sharded(updates: jnp.ndarray, weights: jnp.ndarray,
                                 psum_axis=axis)
         return z
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis, None)),
-                     out_specs=P(), check_rep=False)(weights, updates)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P(axis, None)),
+                         out_specs=P(), check_vma=False)(weights, updates)
 
 
 def trimmed_mean(updates: jnp.ndarray, weights: jnp.ndarray, *,
@@ -149,8 +147,9 @@ def trimmed_mean_sharded(updates: jnp.ndarray, weights: jnp.ndarray, *,
         ug = jax.lax.all_gather(u_blk, axis, tiled=True)
         return trimmed_mean(ug, wg, trim=trim)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis, None)),
-                     out_specs=P(), check_rep=False)(weights, updates)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P(axis, None)),
+                         out_specs=P(), check_vma=False)(weights, updates)
 
 
 def masked_median(x: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:

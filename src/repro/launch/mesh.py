@@ -55,12 +55,20 @@ def force_host_platform_device_count(n: int) -> None:
                 f"(see tests/test_mesh_engine.py)")
 
 
+def _auto(n: int):
+    """``Auto`` axis types for an n-axis mesh: the round path steers
+    placement with ``with_sharding_constraint``, which only Auto axes
+    accept (``jax.make_mesh`` defaults to Explicit axes)."""
+    from jax.sharding import AxisType
+    return (AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = 256 chips; two pods: (2, 16, 16) = 512."""
     import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -69,7 +77,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(min(model, n // data), 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_fleet_mesh(num_devices: Optional[int] = None):
@@ -86,7 +95,7 @@ def make_fleet_mesh(num_devices: Optional[int] = None):
     if n < 1 or n > avail:
         raise ValueError(f"make_fleet_mesh({num_devices}): {avail} "
                          f"device(s) visible")
-    return jax.make_mesh((n,), ("clients",))
+    return jax.make_mesh((n,), ("clients",), axis_types=_auto(1))
 
 
 def n_silos(mesh) -> int:

@@ -105,7 +105,7 @@ ENTRY %main (p0: f32[4]) -> f32[4] {
 
 
 def test_check_no_f64_flags_upcast():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         text = jax.jit(lambda v: v * 2).lower(
             jnp.ones(4, jnp.float64)).compile().as_text()
     bad = HC.check_no_f64("metrics", text)

@@ -207,3 +207,18 @@ def test_fed_agg_matches_core_aggregation():
         np.testing.assert_allclose(np.asarray(got[key]),
                                    np.asarray(want[key]), rtol=1e-5,
                                    atol=1e-5)
+
+
+def test_interpret_impl_refused_on_tpu(monkeypatch):
+    """The Pallas interpreter is a CPU test tool: on a TPU backend
+    ``pallas_interpret`` raises instead of quietly running it."""
+    from repro.kernels.fed_agg.ops import fed_agg_packed, interpret_flag
+    from repro.kernels.robust_agg.ops import residual_norms
+    assert interpret_flag("pallas_interpret") is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_flag("pallas") is False
+    u, w = jnp.ones((3, 4)), jnp.ones((3,))
+    with pytest.raises(ValueError, match="on a TPU use 'pallas'"):
+        fed_agg_packed(u, w, impl="pallas_interpret")
+    with pytest.raises(ValueError, match="robust_agg impl 'pallas_interp"):
+        residual_norms(u, u[0], impl="pallas_interpret")

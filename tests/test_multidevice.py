@@ -19,7 +19,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.configs.base import TrainConfig
@@ -49,7 +49,8 @@ step1 = jax.jit(cross_silo.make_train_step(model, tc, 4))
 s_ref, m_ref = step1(state, batch, w)
 
 # sharded (4 data x 2 model)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = SP.make_rules(cfg, mesh)
 ecfg = ExecConfig(mesh=mesh, rules=rules)
 pspecs = SP.param_shardings(model.specs, mesh, rules)
@@ -96,7 +97,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.data.synthetic import federated_classification
 from repro.fl import SimConfig
@@ -119,7 +120,7 @@ cache_every = jnp.full((32,), 2, jnp.int32)
 
 ref = trainer(params, caches, resume, steps, stop, cache_every)
 
-mesh = jax.make_mesh((8,), ("clients",))
+mesh = jax.make_mesh((8,), ("clients",), axis_types=(AxisType.Auto,))
 shard = NamedSharding(mesh, P("clients"))
 caches_sh = jax.device_put(caches, jax.tree.map(lambda _: shard, caches))
 with mesh:

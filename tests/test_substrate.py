@@ -149,6 +149,24 @@ def test_checkpoint_restores_train_state(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_dir(monkeypatch):
+    """The environment's JAX_COMPILATION_CACHE_DIR wins; otherwise the
+    cache sits at the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+    from repro.launch import compile_cache as CC
+    monkeypatch.delenv(CC.ENV_VAR, raising=False)
+    assert CC.compile_cache_dir() == CC.REPO_CACHE
+    assert CC.REPO_CACHE.name == ".jax_cache"
+    assert (CC.REPO_CACHE.parent / "src" / "repro" / "launch"
+            / "compile_cache.py").is_file()
+    monkeypatch.setenv(CC.ENV_VAR, "/elsewhere/cache")
+    assert CC.compile_cache_dir() == Path("/elsewhere/cache")
+
+
+# ---------------------------------------------------------------------------
 # sharding rules (pure logic — no devices needed)
 # ---------------------------------------------------------------------------
 
@@ -157,7 +175,9 @@ def test_rules_and_divisibility():
     from repro.sharding import partitioning as SP
     if len(_jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = _jax.make_mesh((1, 1), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     for arch in ("qwen2-7b", "llama3-405b", "mixtral-8x7b",
                  "deepseek-v2-236b", "whisper-large-v3"):
         cfg = get_config(arch)
