@@ -1,0 +1,9 @@
+"""Make the benchmark package and the program importable from any
+working directory (the program lives under ``src/``)."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
