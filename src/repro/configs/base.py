@@ -337,9 +337,11 @@ class FLConfig:
     #   jitted dispatch per round; "full" adds update/residual norms,
     #   trust quantiles and the staleness histogram.  Either way metric
     #   values ride the pipelined round ledger — zero added per-round
-    #   host syncs.  ``FleetEngine.run(telemetry=...)`` overrides per
-    #   run (a level string or a ``repro.obs.Telemetry`` session with
-    #   sinks/tracing attached).
+    #   host syncs.  "spans" turns on host span tracing alone (the
+    #   ``fl.*`` annotations of a ``jax.profiler`` trace) and compiles
+    #   no metrics dispatch.  ``FleetEngine.run(telemetry=...)``
+    #   overrides per run (a level string or a ``repro.obs.Telemetry``
+    #   session with sinks/tracing attached).
     debug_checks: bool = False
     # ^ runtime-sanitizer mode (repro.analysis.runtime): after each
     #   server step a checkify guard validates the new global model and
@@ -351,10 +353,10 @@ class FLConfig:
     #   same contracts with zero runtime cost.
 
     def __post_init__(self):
-        if self.telemetry not in (None, "basic", "full"):
+        if self.telemetry not in (None, "spans", "basic", "full"):
             raise ValueError(
-                f"FLConfig.telemetry must be None, 'basic' or 'full', "
-                f"got {self.telemetry!r}")
+                f"FLConfig.telemetry must be None, 'spans', 'basic' or "
+                f"'full', got {self.telemetry!r}")
         if self.agg_impl not in ("xla", "pallas", "pallas_interpret"):
             raise ValueError(
                 f"FLConfig.agg_impl must be one of 'xla', 'pallas', "

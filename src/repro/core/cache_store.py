@@ -41,6 +41,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER
+
 
 @dataclasses.dataclass
 class TransferStats:
@@ -203,6 +205,13 @@ class CohortCacheStream:
       the server step is dispatched.  Starts ``copy_to_host_async`` on
       every handle and parks them; nothing blocks until the next
       round's ``fetch`` (or ``flush``) reads them.
+
+    ``tracer`` (the engine hands over its run's tracer, and
+    ``NULL_TRACER`` when none) spans each step of the protocol as a
+    child of the engine's ``cache_fetch``/``cache_stage``/
+    ``cache_flush`` span: ``cache_d2h_issue``, ``cache_count_bytes``,
+    ``cache_drain``, ``cache_read``, ``cache_apply``, ``cache_gather``
+    and ``cache_put``.
     """
 
     def __init__(self, store: HostCacheStore, mesh=None,
@@ -213,6 +222,7 @@ class CohortCacheStream:
         self.cohort_size = cohort_size
         # per-stream counters (the engine passes its own instance)
         self.stats = stats if stats is not None else TransferStats()
+        self.tracer = NULL_TRACER
         self._pending = None
 
     def _sharding(self, tree):
@@ -224,26 +234,31 @@ class CohortCacheStream:
             tree)
 
     def _start_d2h(self, tree) -> None:
-        for leaf in jax.tree.leaves(tree):
-            if isinstance(leaf, jax.Array):
-                leaf.copy_to_host_async()
+        with self.tracer.span("cache_d2h_issue"):
+            for leaf in jax.tree.leaves(tree):
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
         self.stats.d2h_async += 1
-        self.stats.d2h_bytes += _tree_bytes(tree)
+        with self.tracer.span("cache_count_bytes"):
+            self.stats.d2h_bytes += _tree_bytes(tree)
 
     def _read(self, tree):
         """Blocking read of handles whose copy was pre-issued."""
         self.stats.pre_issued_reads += 1
-        return jax.tree.map(np.asarray, tree)
+        with self.tracer.span("cache_read"):
+            return jax.tree.map(np.asarray, tree)
 
     def fetch(self, idx, rnd: int):
         """(X, ...) device block of the cohort's cache rows (async put)."""
         self._start_d2h(idx)           # overlap with draining the pending
         self.drain(rnd)
         idx_np = self._read(idx)
-        block = self.store.gather(idx_np)
+        with self.tracer.span("cache_gather"):
+            block = self.store.gather(idx_np)
         sh = self._sharding(block)
-        put = jax.device_put(block) if sh is None \
-            else jax.device_put(block, sh)
+        with self.tracer.span("cache_put"):
+            put = jax.device_put(block) if sh is None \
+                else jax.device_put(block, sh)
         self.stats.h2d_async += 1
         self.stats.h2d_bytes += _tree_bytes(block)
         return put
@@ -259,10 +274,12 @@ class CohortCacheStream:
         """Apply the parked write-back (blocks on pre-issued copies)."""
         if self._pending is None:
             return
-        idx, write, clear, stamps, block = self._read(self._pending)
-        self._pending = None
-        self.store.apply(idx, write, clear, stamps, block,
-                         0 if rnd is None else int(rnd))
+        with self.tracer.span("cache_drain"):
+            idx, write, clear, stamps, block = self._read(self._pending)
+            self._pending = None
+            with self.tracer.span("cache_apply"):
+                self.store.apply(idx, write, clear, stamps, block,
+                                 0 if rnd is None else int(rnd))
 
     def flush(self, rnd: Optional[int] = None) -> None:
         self.drain(rnd)

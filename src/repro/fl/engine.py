@@ -820,7 +820,8 @@ class FleetEngine:
         that level, metrics land on ``History.metrics``); ``False``
         forces telemetry off for this run; a level string builds a bare
         session; a ``repro.obs.Telemetry`` is used as-is (sinks, trace
-        paths and profiler window included)."""
+        paths and profiler window included).  Level ``"spans"`` traces
+        the seams alone: ``_metrics_fn`` builds no metrics dispatch."""
         if arg is False:
             return None
         if arg is None:
@@ -840,6 +841,8 @@ class FleetEngine:
         on the full-scan path (rows there are the fleet-sized (N, ...)
         stack): O(rows · D) metrics use it to gather the received rows
         into a compact block before reducing."""
+        if level == "spans":
+            return None, ()
         key = (level, self.cohort, self.offload, self._agg_stateful,
                bool(uses_cache), rows_bound)
         if key not in self._metrics_fns:
@@ -1034,11 +1037,11 @@ class FleetEngine:
 
         ``telemetry`` (see ``_resolve_telemetry``): ``None`` defers to
         ``FLConfig.telemetry``, a level string or ``repro.obs.Telemetry``
-        enables device metrics + host span tracing for this run, and
-        ``False`` forces it off.  Metric values ride the round ledger's
-        existing readback, so the trajectory is bit-identical (and the
-        per-round host-sync count unchanged) with telemetry on or
-        off."""
+        enables device metrics + host span tracing for this run
+        (``"spans"``: tracing alone), and ``False`` forces it off.
+        Metric values ride the round ledger's existing readback, so the
+        trajectory is bit-identical (and the per-round host-sync count
+        unchanged) with telemetry on or off."""
         sim_cfg, fl_cfg = self.sim_cfg, self.fl_cfg
         fleet = self._fleet if self._fleet is not None else Fleet(sim_cfg)
         if isinstance(policy, str):
@@ -1069,6 +1072,8 @@ class FleetEngine:
         tel = self._resolve_telemetry(telemetry)
         tracer = tel.tracer if tel is not None else obs.NULL_TRACER
         self._tracer = tracer       # seams outside the loops (placement)
+        if self._cache_stream is not None:
+            self._cache_stream.tracer = tracer
         if tel is not None:
             tel.open_run({"policy": policy.name,
                           "num_clients": fl_cfg.num_clients,
@@ -1130,6 +1135,8 @@ class FleetEngine:
                 else 0.0,
                 "transfer_stats": self._transfer_stats.snapshot()})
             self._tracer = obs.NULL_TRACER
+            if self._cache_stream is not None:
+                self._cache_stream.tracer = obs.NULL_TRACER
         hist.final_params = global_params
         # final device-resident fleet state (stays sharded under the mesh;
         # the seam for multi-round pipelining / warm restarts)
@@ -1251,7 +1258,7 @@ class FleetEngine:
         server_step = self._server_step(policy.uses_cache)
         rule_state = self._init_rule_state()
 
-        for rnd in range(n_rounds):
+        for rnd in tracer.steps("round", n_rounds):
             if time_budget is not None and cum_time >= time_budget:
                 break
             rng, k_sel = jax.random.split(rng)
@@ -1382,8 +1389,10 @@ class FleetEngine:
                 return (SP.fleet_constraint(s, mesh, N),
                         SP.fleet_constraint(d, mesh, N))
 
-            init_fn = jax.jit(lambda k: SP.fleet_constraint(
-                process.init_state(k), mesh, N))
+            def dynamics_init(k):
+                return SP.fleet_constraint(process.init_state(k), mesh, N)
+
+            init_fn = jax.jit(dynamics_init)
             trainer = make_trainer(
                 self.sim_cfg, self.data, mesh=mesh,
                 dynamics_features=feats, cohort_size=self.cohort,
@@ -1520,7 +1529,7 @@ class FleetEngine:
         fstate = init_fn(jax.random.fold_in(dyn_base, 1 << 20))
 
         draw = None
-        for rnd in range(n_rounds):
+        for rnd in tracer.steps("round", n_rounds):
             if time_budget is not None:
                 # the budget check needs cum_time: resolve everything
                 # in flight (budget runs are effectively depth 1)
@@ -1644,7 +1653,8 @@ class FleetEngine:
                 # being issued; the trainer/cut/server step are the same
                 # cohort ops over the same rows, so trajectories stay
                 # bit-identical to the resident path
-                idx, overflow = self._offload_idx_fn()(sel_d)
+                with tracer.span("cohort_index", round=rnd):
+                    idx, overflow = self._offload_idx_fn()(sel_d)
                 if policy.uses_cache:
                     with tracer.span("cache_fetch", round=rnd):
                         cache_x = self._cache_stream.fetch(idx, rnd)

@@ -64,8 +64,10 @@ def _plan_body(st, caches, online, rng, hints, fl_cfg, with_hints):
 
 @functools.lru_cache(maxsize=8)
 def _flude_plan_jit(fl_cfg, with_hints: bool):
-    return jax.jit(lambda st, caches, online, rng, hints: _plan_body(
-        st, caches, online, rng, hints, fl_cfg, with_hints))
+    def flude_plan(st, caches, online, rng, hints):
+        return _plan_body(st, caches, online, rng, hints, fl_cfg,
+                          with_hints)
+    return jax.jit(flude_plan)
 
 
 @functools.lru_cache(maxsize=8)
@@ -84,8 +86,9 @@ def _flude_update_plan_jit(fl_cfg, with_hints: bool):
 
 @functools.lru_cache(maxsize=8)
 def _flude_update_jit(fl_cfg):
-    return jax.jit(lambda st, plan, received:
-                   core.update_after_round(st, plan, received, fl_cfg))
+    def flude_update(st, plan, received):
+        return core.update_after_round(st, plan, received, fl_cfg)
+    return jax.jit(flude_update)
 
 
 @register_policy("flude")

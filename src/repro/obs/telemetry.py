@@ -6,8 +6,11 @@
   ``"full"``, see ``repro.obs.metrics``).  The engine fuses them into
   one extra jitted dispatch per round whose scalar outputs ride the
   pipelined round ledger — no per-round host sync is added.
+  ``"spans"`` compiles no metrics in: the run's compiled programs are
+  those of a telemetry-off run, and only the tracer is on.
 * ``tracer`` — host span tracing of the dispatch seams
-  (``repro.obs.trace``); ``trace=`` saves the Chrome/Perfetto
+  (``repro.obs.trace``), also written into any ``jax.profiler`` trace
+  as ``fl.<seam>`` annotations; ``trace=`` saves the Chrome/Perfetto
   ``trace_event`` JSON at run end.
 * ``sink`` — the event stream (``run_start`` / ``round`` / ``run_end``
   dicts).  ``jsonl=`` appends to a JSONL file (the
@@ -32,15 +35,18 @@ from repro.obs.sink import JsonlSink, MemorySink, TeeSink
 from repro.obs.trace import Tracer
 from repro.obs import metrics as _metrics
 
+# "spans": the tracer alone, no device metrics
+LEVELS = ("spans",) + _metrics.LEVELS
+
 
 class Telemetry:
     def __init__(self, level: str = "full", jsonl: Optional[str] = None,
                  trace: Optional[str] = None,
                  profile_dir: Optional[str] = None,
                  profile_rounds: Optional[Tuple[int, int]] = None):
-        if level not in _metrics.LEVELS:
+        if level not in LEVELS:
             raise ValueError(
-                f"telemetry level must be one of {_metrics.LEVELS}, got "
+                f"telemetry level must be one of {LEVELS}, got "
                 f"{level!r}")
         self.level = level
         self.tracer = Tracer()

@@ -6,30 +6,44 @@ stream, eval — and :class:`Tracer` wraps each in a lightweight span
 (``time.perf_counter`` pairs, one appended tuple per span).  Because the
 round path is asynchronous, a span measures the *host-side* cost of its
 seam (argument prep + dispatch + any blocking read it performs), which
-is exactly the budget the zero-per-round-host-sync invariant protects;
-device-side compute is captured separately via the optional
-``jax.profiler`` window (see :class:`repro.obs.telemetry.Telemetry`).
+is exactly the budget the zero-per-round-host-sync invariant protects.
+
+Each span also opens a ``jax.profiler.TraceAnnotation("fl.<name>")``
+for its lifetime, with its args as annotation arguments, so a
+``jax.profiler`` trace of the run holds every seam on the profiler's
+own clock, in the host plane beside the device's operations (outside a
+profiler session the annotation records nothing).  ``steps`` marks
+each round with a ``StepTraceAnnotation("fl.<name>", step_num=i)``, the
+marker XProf's step view groups by.
 
 Spans export as Chrome ``trace_event`` JSON (``save``) loadable in
 Perfetto / ``chrome://tracing``, and aggregate into a per-name summary
 (``summary``) that the report CLI renders as the round-time breakdown.
+The tracer keeps the stack of open spans, so each recorded span knows
+its parent and its self time (its own time less its children's).
 
 ``NULL_TRACER`` is the disabled path: ``span`` returns a shared no-op
-context manager, so instrumented code needs no branches and the default
-(telemetry off) path pays a single attribute lookup per seam.
+context manager and ``steps`` a plain ``range``, so instrumented code
+needs no branches and the default (telemetry off) path pays a single
+attribute lookup per seam, with no annotation and no allocation.
 """
 from __future__ import annotations
 
 import json
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+PREFIX = "fl."
 
 
 class Span:
     """One timed section; also usable as ``with tracer.span(..) as sp``
     for its ``seconds`` reading (the benchmark clock)."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "t1")
+    __slots__ = ("_tracer", "name", "args", "t0", "t1", "parent",
+                 "child_s", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -37,13 +51,25 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self.t1 = 0.0
+        self.parent: Optional[Span] = None
+        self.child_s = 0.0
 
     def __enter__(self) -> "Span":
+        stack = self._tracer._stack
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        self._annotation = TraceAnnotation(PREFIX + self.name,
+                                           **(self.args or {}))
+        self._annotation.__enter__()
         self.t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc) -> None:
         self.t1 = self._tracer._clock()
+        self._annotation.__exit__(*exc)
+        self._tracer._stack.pop()
+        if self.parent is not None:
+            self.parent.child_s += self.seconds
         self._tracer._record(self)
 
     @property
@@ -52,8 +78,8 @@ class Span:
 
 
 class Tracer:
-    """Append-only span/instant/counter recorder with a perf_counter
-    clock; timestamps are relative to tracer construction (reset)."""
+    """Append-only span recorder with a perf_counter clock; timestamps
+    are relative to tracer construction (reset)."""
 
     def __init__(self):
         self._clock = time.perf_counter
@@ -61,41 +87,40 @@ class Tracer:
 
     def reset(self) -> None:
         self._epoch = self._clock()
-        # (name, ts_us, dur_us, args) — dur_us None for instants,
-        # args holding values for counters (ph "C")
-        self.events: List[Tuple[str, float, Optional[float], Any]] = []
-        self._counters: set = set()
+        self._stack: List[Span] = []
+        # (name, ts_us, dur_us, args, parent name or None, self_us)
+        self.events: List[Tuple[str, float, float, Any, Optional[str],
+                                float]] = []
 
     # -- recording ----------------------------------------------------------
 
     def span(self, name: str, **args) -> Span:
         return Span(self, name, args or None)
 
+    def steps(self, name: str, n: int) -> Iterator[int]:
+        """``range(n)``, each step's loop body inside a profiler step
+        marker ``fl.<name>`` numbered by the step."""
+        for i in range(n):
+            with StepTraceAnnotation(PREFIX + name, step_num=i):
+                yield i
+
     def _record(self, sp: Span) -> None:
-        self.events.append((sp.name, (sp.t0 - self._epoch) * 1e6,
-                            (sp.t1 - sp.t0) * 1e6, sp.args))
-
-    def instant(self, name: str, **args) -> None:
-        self.events.append((name, (self._clock() - self._epoch) * 1e6,
-                            None, args or None))
-
-    def counter(self, name: str, **values) -> None:
-        self._counters.add(name)
-        self.events.append((name, (self._clock() - self._epoch) * 1e6,
-                            None, values))
+        self.events.append((
+            sp.name, (sp.t0 - self._epoch) * 1e6, sp.seconds * 1e6,
+            sp.args, None if sp.parent is None else sp.parent.name,
+            (sp.seconds - sp.child_s) * 1e6))
 
     # -- aggregation / export -----------------------------------------------
 
     def summary(self) -> Dict[str, dict]:
-        """Per-span-name aggregate: count, total/mean/max seconds."""
+        """Per-span-name aggregate: count, total/self/mean/max seconds."""
         out: Dict[str, dict] = {}
-        for name, _ts, dur, _args in self.events:
-            if dur is None:
-                continue
+        for name, _ts, dur, _args, _parent, self_us in self.events:
             s = out.setdefault(name, {"count": 0, "total_s": 0.0,
-                                      "max_s": 0.0})
+                                      "self_s": 0.0, "max_s": 0.0})
             s["count"] += 1
             s["total_s"] += dur * 1e-6
+            s["self_s"] += self_us * 1e-6
             s["max_s"] = max(s["max_s"], dur * 1e-6)
         for s in out.values():
             s["mean_s"] = s["total_s"] / s["count"]
@@ -105,18 +130,11 @@ class Tracer:
         """Chrome ``trace_event`` JSON (Perfetto-loadable)."""
         evs = [{"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
                 "args": {"name": "fleet-engine host"}}]
-        for name, ts, dur, args in self.events:
-            ev = {"name": name, "pid": 0, "tid": 0, "ts": ts, "cat": "fl"}
-            if name in self._counters:
-                ev.update(ph="C", args=args or {})
-            elif dur is None:
-                ev.update(ph="i", s="t")
-                if args:
-                    ev["args"] = args
-            else:
-                ev.update(ph="X", dur=dur)
-                if args:
-                    ev["args"] = args
+        for name, ts, dur, args, _parent, _self in self.events:
+            ev = {"name": name, "pid": 0, "tid": 0, "ts": ts, "cat": "fl",
+                  "ph": "X", "dur": dur}
+            if args:
+                ev["args"] = args
             evs.append(ev)
         return {"traceEvents": evs, "displayTimeUnit": "ms"}
 
@@ -148,11 +166,8 @@ class NullTracer:
     def span(self, name: str, **args) -> _NullSpan:
         return _NULL_SPAN
 
-    def instant(self, name: str, **args) -> None:
-        pass
-
-    def counter(self, name: str, **values) -> None:
-        pass
+    def steps(self, name: str, n: int) -> range:
+        return range(n)
 
     def summary(self) -> dict:
         return {}

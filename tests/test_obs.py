@@ -5,6 +5,7 @@ host syncs while telemetry=None stays bit- and dispatch-identical to an
 uninstrumented engine.
 """
 import dataclasses
+import glob
 import json
 import os
 
@@ -60,8 +61,6 @@ def test_tracer_chrome_export(tmp_path):
     tr = Tracer()
     with tr.span("trainer", round=1):
         pass
-    tr.instant("mark")
-    tr.counter("received", value=3)
     path = str(tmp_path / "trace.json")
     tr.save(path)
     doc = json.load(open(path))
@@ -72,9 +71,6 @@ def test_tracer_chrome_export(tmp_path):
     x = by_name["trainer"]
     assert x["ph"] == "X" and x["dur"] >= 0 and x["args"] == {"round": 1}
     assert {"pid", "tid", "ts"} <= set(x)
-    assert by_name["mark"]["ph"] == "i"
-    assert by_name["received"]["ph"] == "C"
-    assert by_name["received"]["args"] == {"value": 3}
 
 
 def test_null_tracer_is_inert():
@@ -82,8 +78,6 @@ def test_null_tracer_is_inert():
     with nt.span("x", round=9) as sp:
         pass
     assert sp.seconds == 0.0
-    nt.instant("y")
-    nt.counter("z", v=1)
     assert nt.summary() == {} and nt.events == []
     # the module-level singleton hands out one shared span object
     assert obs.NULL_TRACER.span("a") is obs.NULL_TRACER.span("b")
@@ -95,6 +89,77 @@ def test_tracer_reset_clears_events():
         pass
     tr.reset()
     assert tr.events == [] and tr.summary() == {}
+
+
+def _profile_events(trace_dir, prefix="fl."):
+    """``(name, start_ns, end_ns, stats)`` of the host events named
+    ``prefix...`` in the one xplane file under ``trace_dir``."""
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(paths[0]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_tracer_spans_reach_the_profiler_trace(tmp_path):
+    """Spans land in a ``jax.profiler`` trace as ``fl.<name>`` host
+    events with their args, nested as they were opened; ``steps`` marks
+    each step with ``fl.<name>`` and its number."""
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        for rnd in tr.steps("round", 2):
+            with tr.span("outer", round=rnd):
+                with tr.span("inner"):
+                    jax.numpy.ones(4).block_until_ready()
+    evs = _profile_events(str(tmp_path))
+    by = {}
+    for name, s, e, stats in evs:
+        by.setdefault(name, []).append((s, e, stats))
+    assert sorted(by) == ["fl.inner", "fl.outer", "fl.round"]
+    assert all(len(v) == 2 for v in by.values())
+    steps = sorted(by["fl.round"])
+    assert [st["step_num"] for _, _, st in steps] == [0, 1]
+    for k, (s, e, st) in enumerate(sorted(by["fl.outer"])):
+        assert st["round"] == k
+        rs, re_, _ = steps[k]
+        assert rs <= s and e <= re_                 # inside its step
+        ins, ine, _ = sorted(by["fl.inner"])[k]
+        assert s <= ins and ine <= e                # child inside parent
+    # the tracer's own record agrees on the nesting
+    assert [(n, p) for n, _, _, _, p, _ in tr.events] == [
+        ("inner", "outer"), ("outer", None)] * 2
+
+
+def test_tracer_self_time_is_total_less_children():
+    """``summary()["self_s"]`` is a span's time less its direct
+    children's (a scripted clock makes every duration exact)."""
+    tr = Tracer()
+    ticks = iter([0.0, 1.0, 2.0, 4.0, 5.0, 8.0, 10.0,     # first tree
+                  20.0, 21.0])                            # a lone leaf
+    tr._clock = lambda: next(ticks)
+    tr.reset()                                            # epoch 0.0
+    with tr.span("a"):                                    # [1, 10)
+        with tr.span("b"):                                # [2, 8)
+            with tr.span("c"):                            # [4, 5)
+                pass
+    with tr.span("c"):                                    # [20, 21)
+        pass
+    s = tr.summary()
+    assert s["a"]["total_s"] == 9.0 and s["a"]["self_s"] == 9.0 - 6.0
+    assert s["b"]["total_s"] == 6.0 and s["b"]["self_s"] == 6.0 - 1.0
+    assert s["c"]["count"] == 2
+    assert s["c"]["total_s"] == s["c"]["self_s"] == 2.0
+    assert [p for *_, p, _ in tr.events] == ["b", "a", None, None]
+
+
+def test_null_tracer_steps_is_a_plain_range():
+    assert obs.NULL_TRACER.steps("round", 3) == range(3)
 
 
 # ---------------------------------------------------------------------------
@@ -368,6 +433,125 @@ def test_full_telemetry_adds_zero_host_syncs(monkeypatch):
     assert on == off > 0
 
 
+class _CompileEvents:
+    """Counts JAX traces and backend compiles while open (the events
+    the benchmark harness counts)."""
+    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.count = 0
+
+    def _on(self, event, duration, **kw):
+        if event in self.EVENTS:
+            self.count += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+
+def test_spans_level_runs_the_telemetry_off_programs(monkeypatch):
+    """telemetry="spans" after a warm untraced run: bit-identical
+    History, no trace or compile (no metrics dispatch is built), the
+    same host-sync count as telemetry off, an empty metrics dict, and
+    the seams traced."""
+    def boom(*a, **k):
+        raise AssertionError("make_metrics_fn called at level 'spans'")
+
+    monkeypatch.setattr(obs, "make_metrics_fn", boom)
+    monkeypatch.setattr(OM, "make_metrics_fn", boom)
+    data, sim, fl = _setup(dynamics="bernoulli", pipeline_depth=2,
+                           cohort_size=8, cache_offload="host")
+    engine = FleetEngine(data, sim, fl)
+    engine.run("flude", diagnostics=False, telemetry=False)   # warm up
+
+    counts = []
+    real = jax.device_get
+
+    def counting(x):
+        counts.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", counting)
+    h0 = engine.run("flude", diagnostics=False, telemetry=False)
+    off = len(counts)
+    counts.clear()
+    tel = obs.Telemetry(level="spans")
+    with _CompileEvents() as compiles:
+        h1 = engine.run("flude", diagnostics=False, telemetry=tel)
+    assert compiles.count == 0
+    assert len(counts) == off > 0
+    assert _rows(h1) == _rows(h0)
+    assert h1.metrics == {} and h0.metrics is None
+    spans = tel.tracer.summary()
+    assert spans["trainer"]["count"] == len(h1.acc)
+    assert spans["cohort_index"]["count"] == len(h1.acc)
+    assert engine._tracer is obs.NULL_TRACER
+    assert engine._cache_stream.tracer is obs.NULL_TRACER
+
+
+def test_spans_level_from_config():
+    data, sim, fl = _setup(dynamics="bernoulli", telemetry="spans")
+    h = FleetEngine(data, sim, fl).run("flude", diagnostics=False)
+    assert h.metrics == {}
+
+
+def test_offload_run_nests_cache_stream_spans():
+    """Every cache-stream step is spanned, under the engine seam that
+    drives it."""
+    data, sim, fl = _setup(dynamics="bernoulli", cohort_size=8,
+                           cache_offload="host")
+    tel = obs.Telemetry(level="spans")
+    h = FleetEngine(data, sim, fl).run("flude", diagnostics=False,
+                                      telemetry=tel)
+    parents = {}
+    for name, _ts, _dur, _args, parent, _self in tel.tracer.events:
+        parents.setdefault(name, set()).add(parent)
+    want = {"cache_d2h_issue": {"cache_fetch", "cache_stage"},
+            "cache_count_bytes": {"cache_fetch", "cache_stage"},
+            "cache_drain": {"cache_fetch", "cache_flush"},
+            "cache_read": {"cache_fetch", "cache_drain"},
+            "cache_apply": {"cache_drain"},
+            "cache_gather": {"cache_fetch"},
+            "cache_put": {"cache_fetch"},
+            "cache_fetch": {"rounds"}, "cache_stage": {"rounds"},
+            "cache_flush": {"rounds"}, "cohort_index": {"rounds"}}
+    for name, ps in want.items():
+        assert parents.get(name) == ps, (name, parents.get(name))
+    s = tel.tracer.summary()
+    n = len(h.acc)
+    assert s["cache_gather"]["count"] == s["cache_put"]["count"] == n
+    # one drain a round after the first, and the run-end flush's
+    assert s["cache_drain"]["count"] == s["cache_apply"]["count"] == n
+    assert s["cache_fetch"]["self_s"] <= s["cache_fetch"]["total_s"]
+
+
+def test_device_round_path_traces_into_the_profiler(tmp_path):
+    """A traced offload run writes its seams, the cache stream's
+    children and one ``fl.round`` step marker per round into a
+    ``jax.profiler`` trace."""
+    data, sim, fl = _setup(dynamics="bernoulli", cohort_size=8,
+                           cache_offload="host")
+    engine = FleetEngine(data, sim, fl)
+    engine.run("flude", diagnostics=False, telemetry=False)   # warm up
+    with jax.profiler.trace(str(tmp_path)):
+        h = engine.run("flude", diagnostics=False, telemetry="spans")
+    names = [n for n, *_ in _profile_events(str(tmp_path))]
+    assert names.count("fl.round") == len(h.acc)
+    (_, init_fn, _, _), = engine._dyn_cache.values()
+    assert init_fn.__name__ == "dynamics_init"
+    assert {"fl.rounds", "fl.dynamics_step", "fl.plan", "fl.cohort_index",
+            "fl.cache_fetch", "fl.cache_read", "fl.cache_put",
+            "fl.trainer", "fl.round_cut", "fl.server_step",
+            "fl.cache_stage", "fl.cache_d2h_issue",
+            "fl.cache_count_bytes", "fl.ledger_resolve", "fl.eval",
+            "fl.cache_flush"} <= set(names)
+
+
 def test_telemetry_off_never_builds_metrics(monkeypatch):
     """telemetry=None is compiled out: the metrics factory must never
     run and the tracer stays the shared null singleton."""
@@ -439,6 +623,7 @@ def test_basic_level_and_config_default():
 def test_flconfig_telemetry_validated():
     with pytest.raises(ValueError, match="telemetry"):
         FLConfig(num_clients=8, telemetry="verbose")
+    assert FLConfig(num_clients=8, telemetry="spans").telemetry == "spans"
     with pytest.raises(ValueError, match="telemetry level"):
         obs.Telemetry(level="loud")
 
