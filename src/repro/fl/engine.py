@@ -666,10 +666,10 @@ class FleetEngine:
                 if self.offload == "discard" else None
             self.cache_store = core.HostCacheStore(
                 self._template, fl_cfg.num_clients,
-                staleness_bound=bound)
+                staleness_bound=bound, stats=self._transfer_stats)
             self._cache_stream = core.CohortCacheStream(
                 self.cache_store, mesh=self.mesh,
-                cohort_size=self.cohort, stats=self._transfer_stats)
+                cohort_size=self.cohort)
         # telemetry (repro.obs): fused metrics dispatches are memoized
         # per (level, path); the run-scoped tracer is NULL when off, so
         # instrumented seams cost one attribute lookup on default runs

@@ -4,7 +4,7 @@ Covers, on a single device (the sharded variant is the slow subprocess
 test at the bottom):
 
 * config validation of the offload knobs;
-* ``HostCacheStore`` semantics — sparse rows, empty-slot gathers,
+* ``HostCacheStore`` semantics — slab rows, empty-slot gathers,
   write/clear/prune bookkeeping, owned-copy rows;
 * offload-vs-resident golden parity: every registered policy, padded
   cohorts, pipelined depths, repeated runs on one engine, the stateful
@@ -189,6 +189,186 @@ def test_store_roundtrip_random_sequences(seed):
     assert len(store) == int((ref_stamp >= 0).sum())
 
 
+def _block(rng, template, x):
+    return {k: rng.normal(size=(x,) + v.shape).astype(v.dtype)
+            for k, v in template.items()}
+
+
+def test_store_clear_keeps_capacity_and_reuses_slots():
+    """Slabs grow a chunk (the first block's X) at a time; slots freed
+    by a clear, a prune or ``clear()`` are reused before the store
+    grows again, and ``clear()`` keeps the slabs."""
+    rng = np.random.default_rng(0)
+    template = _template()
+    store = CS.HostCacheStore(template, num_clients=16, staleness_bound=2)
+    ones, none = np.ones(4, bool), np.zeros(4, bool)
+    store.apply(np.array([0, 1, 2, 3]), ones, none, np.zeros(4, int),
+                _block(rng, template, 4), 0)
+    assert store.stats.host_grows == 1
+    assert store.capacity_bytes == 4 * store.row_bytes == store.nbytes
+    # a clear frees two slots; two new ids take them
+    store.apply(np.array([0, 1, 16, 16]), none, np.array([1, 1, 0, 0], bool),
+                np.zeros(4, int), _block(rng, template, 4), 1)
+    store.apply(np.array([4, 5, 16, 16]), np.array([1, 1, 0, 0], bool), none,
+                np.ones(4, int), _block(rng, template, 4), 1)
+    assert len(store) == 4 and store.stats.host_grows == 1
+    assert store.stats.rows_cleared == 2
+    # a prune frees the round-0 rows; new ids reuse those slots too
+    store.prune(3)
+    assert store.ids() == [4, 5]
+    store.apply(np.array([6, 7, 16, 16]), np.array([1, 1, 0, 0], bool), none,
+                np.full(4, 3), _block(rng, template, 4), 3)
+    assert len(store) == 4 and store.stats.host_grows == 1
+    # a fifth live row grows one more chunk
+    store.apply(np.array([8, 16, 16, 16]), np.array([1, 0, 0, 0], bool),
+                none, np.full(4, 3), _block(rng, template, 4), 3)
+    assert store.stats.host_grows == 2
+    assert store.capacity_bytes == 8 * store.row_bytes
+    store.clear()
+    assert len(store) == 0 and store.ids() == [] and store.nbytes == 0
+    assert store.capacity_bytes == 8 * store.row_bytes
+    blk = _block(rng, template, 4)
+    for rnd in range(2):
+        store.apply(np.arange(4) + 4 * rnd, ones, none, np.full(4, rnd),
+                    blk, rnd)
+    assert store.stats.host_grows == 2 and len(store) == 8
+    assert not store.gather(np.array([16, 99, -1]))["w"].any()
+
+
+@pytest.mark.parametrize("x", [1, 7, 64])
+def test_store_reads_cohort_minor_blocks(x, monkeypatch):
+    """A block that stores its cohort axis innermost (the TPU's layout
+    for the trainer's cache block) is written exactly like the same
+    values stored row by row, whatever the tile size."""
+    monkeypatch.setattr(CS, "_TILE_BYTES", 64)
+    rng = np.random.default_rng(x)
+    template = {"w": np.zeros((9, 5), np.float32),
+                "b": np.zeros((5,), np.float32),
+                "v": np.zeros((3, 2, 4), np.float64)}
+    n = 3 * x
+    block = _block(rng, template, x)
+    minor = {k: np.moveaxis(np.ascontiguousarray(np.moveaxis(v, 0, -1)),
+                            -1, 0) for k, v in block.items()}
+    assert not minor["w"].flags.c_contiguous or x == 1
+    first, idx = rng.permutation(n)[:x], rng.permutation(n)[:x]
+    write = rng.random(x) < 0.6
+    stores = []
+    for blk in (block, minor):
+        store = CS.HostCacheStore(template, n)
+        # a first round spreads the slots over two chunks
+        store.apply(first, np.ones(x, bool),
+                    np.zeros(x, bool), np.zeros(x, int), block, 0)
+        store.apply(idx, write, np.zeros(x, bool), np.ones(x, int), blk, 1)
+        stores.append(store)
+    probe = np.arange(n + 1)
+    for k in template:
+        np.testing.assert_array_equal(stores[1].gather(probe)[k],
+                                      stores[0].gather(probe)[k])
+    got = stores[1].gather(idx[write])
+    for k in template:
+        np.testing.assert_array_equal(got[k], block[k][write])
+
+
+def test_store_duplicate_ids_last_write_wins():
+    """An id repeated in one apply keeps its last write or clear."""
+    template = _template()
+    store = CS.HostCacheStore(template, num_clients=8)
+    blk = _block(np.random.default_rng(1), template, 4)
+    store.apply(np.array([3, 3, 5, 5]), np.array([1, 1, 1, 0], bool),
+                np.array([0, 0, 0, 1], bool), np.array([1, 2, 3, 4]), blk, 2)
+    assert store.ids() == [3] and store.stamp_of(3) == 2
+    np.testing.assert_array_equal(store.gather(np.array([3]))["w"][0],
+                                  blk["w"][1])
+    assert store.stats.rows_written == 1
+
+
+def test_gather_returns_owned_arrays():
+    """``gather`` hands out fresh arrays: neither later fetches (which
+    reuse the stream's staging blocks) nor writes to the result touch
+    the other."""
+    rng = np.random.default_rng(2)
+    template = _template()
+    store = CS.HostCacheStore(template, num_clients=8)
+    stream = CS.CohortCacheStream(store)
+    idx = np.array([1, 2, 8])
+    store.apply(idx, np.ones(3, bool), np.zeros(3, bool), np.zeros(3, int),
+                _block(rng, template, 3), 0)
+    got = store.gather(idx)
+    again = store.gather(idx)
+    keep = {k: v.copy() for k, v in got.items()}
+    assert all(got[k] is not again[k] for k in got)
+    for rnd in range(3):
+        stream.fetch(np.array([2, 1, 5][: rnd + 1] + [8] * (2 - rnd)), rnd)
+        staging = [leaf for blk in stream._blocks for leaf in blk]
+        assert not any(np.shares_memory(v, leaf)
+                       for v in got.values() for leaf in staging)
+    for k in got:
+        np.testing.assert_array_equal(got[k], keep[k])
+    got["w"][:] = np.inf
+    np.testing.assert_array_equal(store.gather(idx)["w"], keep["w"])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_staging_block_equals_gather_across_rounds(seed):
+    """Every block the stream puts equals a fresh ``store.gather(idx)``
+    bit for bit, across rounds whose hits move (rows that held hits in
+    a staging block's previous use read as zeros again), and still does
+    after the next fetch, which fills the other staging block."""
+    rng = np.random.default_rng(seed)
+    n, x = 10, 6
+    template = _template()
+    store = CS.HostCacheStore(template, n)
+    stream = CS.CohortCacheStream(store)
+    prev = None
+    for rnd in range(10):
+        idx = np.full(x, n, np.int64)
+        k = int(rng.integers(0, x + 1))
+        idx[:k] = np.sort(rng.choice(n, size=k, replace=False))
+        put = stream.fetch(idx, rnd)
+        want = store.gather(idx)
+        for p, w in ((put, want),) if prev is None else ((put, want), prev):
+            for name in template:
+                np.testing.assert_array_equal(np.asarray(p[name]), w[name],
+                                              err_msg=f"r{rnd} {name}")
+        prev = (put, want)
+        op = rng.integers(0, 3, size=x)
+        stream.stage(idx, op == 0, op == 1, _block(rng, template, x),
+                     np.full(x, rnd))
+    assert store.stats.rows_hit > 0
+
+
+def test_stream_counters_equal_mask_sums():
+    """``rows_written`` is the sum of the write masks over known ids,
+    ``rows_hit`` the ids of each fetched block that held a row, and
+    ``rows_cleared`` the rows a clear released."""
+    rng = np.random.default_rng(5)
+    n, x = 20, 8
+    template = _template()
+    store = CS.HostCacheStore(template, n)
+    stream = CS.CohortCacheStream(store)
+    live = set()
+    want = {"rows_written": 0, "rows_hit": 0, "rows_cleared": 0}
+    for rnd in range(10):
+        idx = np.full(x, n, np.int64)
+        k = int(rng.integers(1, x + 1))
+        idx[:k] = np.sort(rng.choice(n, size=k, replace=False))
+        stream.fetch(idx, rnd)          # drains the previous write-back
+        want["rows_hit"] += sum(int(c) in live for c in idx)
+        op = rng.integers(0, 3, size=x)
+        write, clear = op == 0, op == 1
+        stream.stage(idx, write, clear, _block(rng, template, x),
+                     np.full(x, rnd))
+        want["rows_written"] += int((write & (idx < n)).sum())
+        want["rows_cleared"] += sum(int(c) in live for c in idx[clear])
+        live |= {int(c) for c in idx[write & (idx < n)]}
+        live -= {int(c) for c in idx[clear]}
+        stream.drain(rnd)
+        assert store.ids() == sorted(live)
+    got = store.stats.snapshot()
+    assert {k: got[k] for k in want} == want
+    assert got["host_grows"] * x * store.row_bytes == store.capacity_bytes
+
+
 def test_store_matches_device_expiry_predicate():
     """Host prune and device ``expire_caches`` share one predicate
     (``current_round - stamp > bound``) — a row is pruned iff its device
@@ -262,6 +442,26 @@ def test_parity_with_stateful_rule(data):
                    "flude")
     _assert_hist_equal(resident, offload, "trust")
     np.testing.assert_array_equal(resident.trust, offload.trust)
+
+
+def test_rerun_allocates_no_host_memory(data):
+    """A second identical run on one engine reuses the first run's
+    slabs: no chunk is allocated, the capacity is unchanged and the
+    row counters repeat exactly."""
+    fl = dataclasses.replace(FL, cache_offload="host")
+    engine = FleetEngine(data, dataclasses.replace(SIM, rounds=6), fl)
+    runs = []
+    for _ in range(2):
+        engine.transfer_stats.reset()
+        engine.run("flude", diagnostics=False)
+        runs.append((engine.transfer_stats.snapshot(),
+                     engine.cache_store.capacity_bytes))
+    (first, cap1), (second, cap2) = runs
+    assert first["host_grows"] >= 1 and first["rows_written"] > 0
+    assert second["host_grows"] == 0 and cap2 == cap1
+    for key in ("rows_written", "rows_cleared", "rows_hit"):
+        assert second[key] == first[key], key
+    assert cap1 >= engine.cache_store.nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -401,7 +601,7 @@ def test_discard_prunes_stale_store_rows(data):
     engine.run("flude", diagnostics=False)
     # every surviving row was written within the bound of the final
     # prune (run end drains at round ``rounds``)
-    for cid in list(engine.cache_store._stamps):
+    for cid in engine.cache_store.ids():
         assert sim.rounds - engine.cache_store.stamp_of(cid) <= 1
     loose = FleetEngine(data, sim,
                         dataclasses.replace(fl, cache_staleness_bound=64))

@@ -75,7 +75,7 @@ class _ResidentReference:
 
 
 @st.composite
-def _round_sequences(draw):
+def _round_sequences(draw, unique=True):
     n = draw(st.integers(2, 24))
     x = draw(st.integers(1, min(n, 8)))
     dim = draw(st.integers(1, 4))
@@ -84,7 +84,7 @@ def _round_sequences(draw):
     rounds = []
     for r in range(n_rounds):
         ids = draw(st.lists(st.integers(0, n - 1), min_size=0,
-                            max_size=x, unique=True))
+                            max_size=x, unique=unique))
         idx = np.full(x, n, np.int64)          # sentinel padding
         idx[:len(ids)] = sorted(ids)
         write = np.zeros(x, bool)
@@ -126,6 +126,50 @@ def test_store_roundtrip_matches_resident_reference(case):
     # prune predicate) keep the sparse store and the reference's live
     # stamps in lockstep
     assert len(store) == int((ref.stamp >= 0).sum())
+
+
+def _cohort_minor(block):
+    """The same (X, ...) values, stored with the cohort axis innermost
+    (the layout a TPU gives the trainer's cache block)."""
+    return {name: np.moveaxis(np.ascontiguousarray(np.moveaxis(v, 0, -1)),
+                              -1, 0) for name, v in block.items()}
+
+
+@given(_round_sequences(unique=False), st.integers(0, 6),
+       st.lists(st.booleans(), min_size=6, max_size=6))
+def test_slab_store_matches_resident_reference(case, clear_at, minor):
+    """The slab store against the dense reference with ids repeated
+    inside a round (the last write or clear wins), sentinels and
+    prune, blocks stored row by row or cohort axis innermost, and a
+    ``clear()`` part way (capacity kept, both restart empty): gathers,
+    ``ids()``, ``stamp_of`` and the live-row count agree after every
+    round."""
+    n, x, dim, bound, rounds = case
+    template = _template(dim)
+    store = HostCacheStore(template, n, staleness_bound=bound)
+    ref = _ResidentReference(template, n, bound=bound)
+    for rnd, (idx, write, clear, stamps, seed, probe) in enumerate(rounds):
+        if rnd == clear_at:
+            cap = store.capacity_bytes
+            store.clear()
+            ref = _ResidentReference(template, n, bound=bound)
+            assert store.capacity_bytes == cap and len(store) == 0
+        rng = np.random.default_rng(seed)
+        block = {name: rng.normal(size=(x,) + v.shape).astype(v.dtype)
+                 for name, v in template.items()}
+        store.apply(idx, write, clear, stamps,
+                    _cohort_minor(block) if minor[rnd] else block, rnd)
+        ref.apply(idx, write, clear, stamps, block, rnd)
+        got = store.gather(np.asarray(probe))
+        want = ref.gather(np.asarray(probe))
+        for name in template:
+            np.testing.assert_array_equal(got[name], want[name],
+                                          err_msg=f"round {rnd} {name}")
+        live = np.flatnonzero(ref.stamp >= 0)
+        assert store.ids() == live.tolist()
+        assert [store.stamp_of(c) for c in live] == ref.stamp[live].tolist()
+        assert store.nbytes == len(live) * store.row_bytes \
+            <= store.capacity_bytes
 
 
 @given(st.integers(2, 16), st.integers(1, 6), st.integers(0, 2 ** 16))

@@ -667,7 +667,8 @@ def test_engine_without_offload_has_zero_transfer_stats():
     e.run("flude", diagnostics=False)
     assert e.transfer_stats.snapshot() == {
         "h2d_async": 0, "d2h_async": 0, "h2d_bytes": 0, "d2h_bytes": 0,
-        "pre_issued_reads": 0, "sync_copies": 0}
+        "pre_issued_reads": 0, "sync_copies": 0, "rows_written": 0,
+        "rows_cleared": 0, "rows_hit": 0, "host_grows": 0}
 
 
 # ---------------------------------------------------------------------------
