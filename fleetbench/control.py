@@ -45,11 +45,10 @@ def as_run(ref: dict, seed: int) -> dict:
 def readings(cell, seed: int, variants=("control", "half_batch")) -> list:
     import jax.numpy as jnp
     from fleetbench import checks, harness, reference
-    from fleetbench.data import make_data
 
     spec = harness.spec_of(cell)
     rounds = spec["model_rounds"]
-    data = make_data(seed, spec["data"])
+    data = spec["model_code"].make_data(seed, spec["data"])
     ref = reference.simulate(spec, data, seed, rounds,
                              numeric_rounds=rounds)
     out = []

@@ -5,7 +5,10 @@ per-layer metric lives in a file of its own, found by the names in
 ``BENCHMARK.json``:
 
 * ``configs/<config>.json``  the deployment (SimConfig and FLConfig
-  fields, policy, model widths, data scale, source, cuts);
+  fields, policy, model kind and widths, data scale, source, cuts);
+* ``models/<kind>.py``  the model a configuration's ``model`` block
+  names by ``kind``: its data, its plain reference and its counts
+  (``MODEL_API``; ``models/mlp.py`` says what each function does);
 * ``traffic/<traffic>.json``  the fleet's behaviour (availability
   process and its parameters, adversary);
 * ``limits/<workload>.json``  the limit of each number the check
@@ -44,6 +47,8 @@ NUMERIC_ROUNDS = 3
 CALIBRATION_ROUNDS = 10
 TRACE_SECONDS = 3.0
 CACHE_SAMPLE = 256
+MODEL_API = ("make_data", "leaf_shapes", "init_params", "loss_fn",
+             "train_flops", "eval_flops", "packed_dim")
 
 
 # ---------------------------------------------------------------------------
@@ -59,6 +64,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     chips: int
+    model_code: Any       # the module models/<kind>.py
 
 
 def load_json(path: Path) -> dict:
@@ -66,10 +72,44 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def _load_module(path: Path, name: str):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(kind: str, base: Path = HERE):
+    """The module ``models/<kind>.py``; raises ``FileNotFoundError``
+    without the file and ``AttributeError`` naming a function of
+    ``MODEL_API`` that it lacks."""
+    path = base / "models" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model file models/{kind}.py "
+                                f"(looked for {path})")
+    mod = _load_module(path, f"fleetbench_model_{kind.replace('.', '_')}")
+    for fn in MODEL_API:
+        if not callable(getattr(mod, fn, None)):
+            raise AttributeError(f"models/{kind}.py has no {fn}()")
+    return mod
+
+
+def model_of(config: dict, base: Path = HERE):
+    """The model file a configuration names; raises ``KeyError`` where
+    its ``model`` block names no ``kind``."""
+    kind = config.get("model", {}).get("kind")
+    if not kind:
+        raise KeyError(f"configuration {config.get('name')!r} names no "
+                       f"model kind (\"model\": {{\"kind\": ...}})")
+    return load_model(kind, base)
+
+
 def resolve(root: Path, workload: str, bench: Optional[dict] = None,
             base: Path = HERE) -> Cell:
-    """The files of ``workload``; raises ``KeyError``/``FileNotFoundError``
-    when the benchmark does not name it or a file is missing."""
+    """The files of ``workload``; raises ``KeyError`` when the benchmark
+    does not name it or its configuration names no model kind,
+    ``FileNotFoundError`` when a file is missing and ``AttributeError``
+    when the model file lacks a function of ``MODEL_API``."""
     bench = bench if bench is not None else load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -78,6 +118,7 @@ def resolve(root: Path, workload: str, bench: Optional[dict] = None,
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     cfg = load_json(root / configs[w["config"]]["file"])
+    model_code = model_of(cfg, base)
     traffic = load_json(base / "traffic" / f"{w['traffic']}.json")
     limits = load_json(base / "limits" / f"{workload}.json")
     e2e = [m for m in bench["end_to_end"]
@@ -88,7 +129,7 @@ def resolve(root: Path, workload: str, bench: Optional[dict] = None,
         if not (base / "metrics" / f"{m['name']}.py").is_file():
             raise FileNotFoundError(f"no reader metrics/{m['name']}.py")
     return Cell(workload, cfg, traffic, limits, e2e, layer,
-                int(w["chips"]))
+                int(w["chips"]), model_code)
 
 
 def spec_of(cell: Cell) -> dict:
@@ -105,6 +146,7 @@ def spec_of(cell: Cell) -> dict:
             "model_rounds": int(cell.limits.get("model_rounds",
                                                 NUMERIC_ROUNDS)),
             "fl": dict(c["fl"]), "model": dict(c["model"]),
+            "model_code": cell.model_code,
             "data": dict(c["data"]), "eval_every": int(c["eval_every"]),
             "dynamics": t["dynamics"],
             "dynamics_params": dict(t.get("dynamics_params", {})),
@@ -113,12 +155,8 @@ def spec_of(cell: Cell) -> dict:
 
 
 def load_reader(name: str, base: Path = HERE) -> Callable:
-    path = base / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"fleetbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(base / "metrics" / f"{name}.py",
+                        f"fleetbench_metric_{name.replace('.', '_')}").read
 
 
 # ---------------------------------------------------------------------------
@@ -233,11 +271,10 @@ class Program:
 def build(spec: dict, seed: int, agg_impl: Optional[str] = None,
           log: Callable = print) -> Program:
     from repro.fl import Fleet, FleetEngine, make_policy
-    from fleetbench.data import make_data
     import jax
 
     t0 = time.perf_counter()
-    data = make_data(seed, spec["data"])
+    data = spec["model_code"].make_data(seed, spec["data"])
     jax.block_until_ready(data.x)
     log(f"[setup] data {tuple(data.x.shape)} in "
         f"{time.perf_counter() - t0:.2f} s")

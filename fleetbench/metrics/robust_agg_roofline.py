@@ -16,7 +16,8 @@ def read(ctx):
     if ns <= 0:
         return None
     iters = int(dict(fl.get("agg_rule_params", {})).get("iters", 6))
-    rows, dim = int(fl["cohort_size"]), counts.packed_dim(ctx.spec["model"])
+    rows = int(fl["cohort_size"])
+    dim = ctx.spec["model_code"].packed_dim(ctx.spec["model"])
     least = ctx.rounds * (iters + 1) * counts.agg_bytes(rows, dim) \
         / peaks(ctx.device_kind)["hbm_bytes_per_s"]
     return 100.0 * least / (ns * 1e-9)

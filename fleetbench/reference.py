@@ -2,10 +2,12 @@
 
 It imports nothing of the program under test and takes nothing the
 program made: the fleet population, the availability process, the
-policy, the round cut, the cache bookkeeping, the model and its
-training are all rebuilt here from the configuration and the seed, in
-straightforward ``jax.numpy`` (numpy where the semantics are host
-numpy).  The data is the benchmark's own (``fleetbench.data``).
+policy, the round cut, the cache bookkeeping and the training are all
+rebuilt here from the configuration and the seed, in straightforward
+``jax.numpy`` (numpy where the semantics are host numpy).  The data
+and the model (its leaves, initial values and loss) are the
+configuration's model file's, ``models/<kind>.py``, which the spec
+carries as ``model_code``.
 
 Two parts:
 
@@ -19,15 +21,17 @@ Two parts:
   selected clients (resumed from their cached state where the plan
   says so), the staleness-discounted aggregation weights, the poisoned
   uploads of an adversary, and the weighted mean or the smoothed
-  Weiszfeld geometric median.  Matrix products run at ``HIGHEST``
-  precision in the reference dtype; ``dtype=bfloat16`` gives the
-  control, and ``fault="half_batch"`` leaves the second half of each
-  round's received clients out of the aggregate.
+  Weiszfeld geometric median.  Only the trainable leaves are trained,
+  packed, aggregated and cached; the frozen ones are made once from the
+  seed and held fixed.  Matrix products run at ``HIGHEST`` precision in
+  the reference dtype; ``dtype=bfloat16`` gives the control, and
+  ``fault="half_batch"`` leaves the second half of each round's
+  received clients out of the aggregate.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,57 +44,8 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
-# Model: the MLP classifier (tanh hidden layers, softmax cross-entropy)
+# Packing: trainable leaves <-> the (D,) rows uploads and caches hold
 # ---------------------------------------------------------------------------
-
-def leaf_names(depth: int) -> List[str]:
-    """Leaves in the order a sorted-key pytree flattens them."""
-    names = []
-    for i in range(depth):
-        names += [f"h{i}/b", f"h{i}/w"]
-    return names + ["out/b", "out/w"]
-
-
-def leaf_shapes(model: dict) -> Dict[str, tuple]:
-    dim, hidden = int(model["dim"]), int(model["hidden"])
-    depth, classes = int(model["depth"]), int(model["num_classes"])
-    shapes, d_in = {}, dim
-    for i in range(depth):
-        shapes[f"h{i}/w"] = (d_in, hidden)
-        shapes[f"h{i}/b"] = (hidden,)
-        d_in = hidden
-    shapes["out/w"] = (d_in, classes)
-    shapes["out/b"] = (classes,)
-    return {k: shapes[k] for k in leaf_names(depth)}
-
-
-def init_params(seed: int, model: dict) -> Dict[str, jax.Array]:
-    """Fan-in scaled normal weights, zero biases, one split key per leaf
-    in flattening order, from ``key(seed + 1)``."""
-    shapes = leaf_shapes(model)
-    keys = jax.random.split(jax.random.key(int(seed) + 1), len(shapes))
-    out = {}
-    for k, (name, shape) in zip(keys, shapes.items()):
-        if name.endswith("/b"):
-            out[name] = jnp.zeros(shape, jnp.float32)
-        else:
-            std = 1.0 / np.sqrt(max(shape[0], 1))
-            out[name] = jax.random.normal(k, shape, jnp.float32) * std
-    return out
-
-
-def logits(params, x, depth: int):
-    h = x
-    for i in range(depth):
-        h = jnp.tanh(jnp.dot(h, params[f"h{i}/w"], precision=HIGHEST)
-                     + params[f"h{i}/b"])
-    return jnp.dot(h, params["out/w"], precision=HIGHEST) + params["out/b"]
-
-
-def loss_fn(params, x, y, depth: int):
-    lp = jax.nn.log_softmax(logits(params, x, depth), axis=-1)
-    return -jnp.take_along_axis(lp, y[:, None], axis=-1).mean()
-
 
 def pack(params: dict, names) -> jax.Array:
     """(C, ...) leaves -> (C, D) rows, leaves in flattening order."""
@@ -108,6 +63,13 @@ def unpack(vec, shapes: dict) -> dict:
         out[name] = vec[..., off:off + n].reshape(lead + tuple(shape))
         off += n
     return out
+
+
+def _to_ref(a, dtype):
+    """``a`` in the reference dtype where it is floating; integer arrays
+    (token ids, index tables) keep their dtype."""
+    a = jnp.asarray(a)
+    return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a
 
 
 # ---------------------------------------------------------------------------
@@ -441,23 +403,25 @@ def _round_fn(spec: dict, step_dyn, n: int):
 
 def _train_fn(spec: dict, dtype):
     sim, model = spec["sim"], spec["model"]
-    depth = int(model["depth"])
+    code = spec["model_code"]
     max_steps = int(sim["local_steps"])
     lr = float(sim["lr"])
     grad = jax.vmap(jax.value_and_grad(
-        lambda p, x, y: loss_fn(p, x, y, depth)))
+        lambda p, f, x, y: code.loss_fn(p, f, x, y, model)),
+        in_axes=(0, None, 0, 0))
 
     @jax.jit
-    def train(start, x, y, steps, stop, ce):
-        """SGD from ``start`` (leaves (X, ...)) on each client's rows."""
+    def train(start, frozen, x, y, steps, stop, ce):
+        """SGD from ``start`` (trainable leaves (X, ...)) on each
+        client's rows, the ``frozen`` leaves held fixed."""
         n = x.shape[1]
         b = min(int(sim["batch_size"]), n)
-        x = x.astype(dtype)
+        x = _to_ref(x, dtype)
 
         def body(carry, j):
             params, cache, loss_sum = carry
             sl = (j * b + jnp.arange(b)) % n
-            loss, g = grad(params, x[:, sl], y[:, sl])
+            loss, g = grad(params, frozen, x[:, sl], y[:, sl])
             active = (j < steps) & (j < stop)
 
             def upd(p, gg):
@@ -538,19 +502,19 @@ def simulate(spec: dict, data, seed: int, rounds: int,
     """Reference outputs of ``rounds`` rounds from ``seed``.
 
     ``spec`` holds the configuration's ``sim``, ``fl``, ``model``,
-    ``data`` and ``policy`` blocks and the traffic's ``dynamics`` and
-    adversary.  Returns per-round History columns for every round, the
-    first rounds' masks and mean local losses, the global model after
-    each of the first ``numeric_rounds`` rounds (packed, float32), the
-    cache metadata after the last round, and the cached rows after
+    ``data`` and ``policy`` blocks, its model file as ``model_code``,
+    and the traffic's ``dynamics`` and adversary.  Returns per-round
+    History columns for every round, the first rounds' masks and mean
+    local losses, the global model's trainable leaves after each of the
+    first ``numeric_rounds`` rounds (packed, float32), the cache
+    metadata after the last round, and the cached rows after
     ``numeric_rounds`` rounds."""
     sim = dict(spec["sim"], seed=int(seed))
-    fl, model = spec["fl"], spec["model"]
+    fl, model, code = spec["fl"], spec["model"], spec["model_code"]
     spec = dict(spec, sim=sim)
     n = int(sim["num_clients"])
-    depth = int(model["depth"])
-    names = leaf_names(depth)
-    shapes = leaf_shapes(model)
+    shapes = code.leaf_shapes(model)
+    names = list(shapes)
     prof = fleet_profile(sim)
     feats = {k: jnp.asarray(np.asarray(v, np.float32))
              for k, v in prof.items()}
@@ -579,8 +543,9 @@ def simulate(spec: dict, data, seed: int, rounds: int,
 
     train = _train_fn(spec, dtype)
     aggregate = _aggregate_fn(spec, mal_scale)
-    theta0 = pack({k: v[None] for k, v in
-                   init_params(seed, model).items()}, names)[0]
+    trainable, frozen = code.init_params(seed, model)
+    frozen = jax.tree.map(lambda a: _to_ref(a, dtype), frozen)
+    theta0 = pack({k: v[None] for k, v in trainable.items()}, names)[0]
     gvec = theta0.astype(dtype).astype(jnp.float32)
     store: Dict[int, np.ndarray] = {}
     D = int(gvec.shape[0])
@@ -613,7 +578,8 @@ def simulate(spec: dict, data, seed: int, rounds: int,
                       fill_value=0)
         ys = jnp.take(data.y, jnp.asarray(idx), axis=0, mode="fill",
                       fill_value=0)
-        final, cache_p, loss = train(start, xs, ys, jnp.asarray(o["steps"]),
+        final, cache_p, loss = train(start, frozen, xs, ys,
+                                     jnp.asarray(o["steps"]),
                                      jnp.asarray(o["stop"]),
                                      jnp.asarray(o["ce"]))
         recv = o["recv_x"]

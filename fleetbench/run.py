@@ -57,7 +57,7 @@ def main(argv=None) -> int:
 
     try:
         cell = harness.resolve(ROOT, args.workload)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, AttributeError) as e:
         return fail(str(e))
 
     import jax

@@ -1,25 +1,28 @@
-"""The FLOP and byte counts the per-layer metrics divide by."""
+"""The FLOP and byte counts the per-layer metrics divide by: the MLP's
+in ``models/mlp.py``, the aggregation's in ``counts.py``."""
 import pytest
 
 from fleetbench import counts, harness, tracing
 from fleetbench.tests.tiny import file_cell
 
-MODEL = {"dim": 32, "hidden": 128, "depth": 2, "num_classes": 10}
+MLP = harness.load_model("mlp")
+MODEL = {"kind": "mlp", "dim": 32, "hidden": 128, "depth": 2,
+         "num_classes": 10}
 
 
 def test_mlp_macs_by_hand():
     # 32x128 + 128x128 + 128x10
-    assert counts.mlp_macs(MODEL) == 4096 + 16384 + 1280 == 21760
+    assert MLP.mlp_macs(MODEL) == 4096 + 16384 + 1280 == 21760
 
 
 def test_packed_dim_by_hand():
-    assert counts.packed_dim(MODEL) == (32 * 128 + 128) + (128 * 128 + 128) \
+    assert MLP.packed_dim(MODEL) == (32 * 128 + 128) + (128 * 128 + 128) \
         + (128 * 10 + 10) == 22026
 
 
 def test_train_and_eval_flops():
-    assert counts.train_flops(MODEL, 1) == 6 * 21760
-    assert counts.eval_flops(MODEL, 2048) == 2 * 21760 * 2048
+    assert MLP.train_flops(MODEL, 1) == 6 * 21760
+    assert MLP.eval_flops(MODEL, 2048) == 2 * 21760 * 2048
 
 
 def test_aggregation_bytes_and_flops():

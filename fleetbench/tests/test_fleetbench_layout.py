@@ -32,6 +32,8 @@ def test_cell_resolves_to_its_files(workload):
         "round_ms", "setup_s", "peak_hbm_gb"]
     for m in cell.per_layer:
         assert callable(harness.load_reader(m["name"]))
+    assert all(callable(getattr(cell.model_code, fn))
+               for fn in harness.MODEL_API)
 
 
 @pytest.mark.parametrize("files", FILE_CELLS, ids=".".join)

@@ -5,6 +5,7 @@ that ``BENCHMARK.json`` does not (yet) list, judged by the limits of
 the benchmarked cell, so that the program paths of the configurations
 kept for later cells (MIFA, the geometric median) stay tested."""
 import copy
+import dataclasses
 from pathlib import Path
 
 from fleetbench import harness
@@ -18,15 +19,16 @@ FILE_CELLS = [("xdevice-flude", "diurnal"), ("selectall-mifa", "bernoulli"),
 
 def file_cell(config: str, traffic: str) -> harness.Cell:
     limits = harness.load_json(BASE / "limits" / f"{BENCHMARKED}.json")
+    cfg = harness.load_json(BASE / "configs" / f"{config}.json")
     return harness.Cell(
-        f"{config}.{traffic}",
-        harness.load_json(BASE / "configs" / f"{config}.json"),
+        f"{config}.{traffic}", cfg,
         harness.load_json(BASE / "traffic" / f"{traffic}.json"), limits,
-        harness.resolve(ROOT, BENCHMARKED).end_to_end, [], 1)
+        harness.resolve(ROOT, BENCHMARKED).end_to_end, [], 1,
+        harness.model_of(cfg))
 
 
 def tiny(cell: harness.Cell, n: int = 64, x: int = 8) -> harness.Cell:
-    cell = copy.deepcopy(cell)
+    cell = dataclasses.replace(cell, config=copy.deepcopy(cell.config))
     c = cell.config
     if c["policy"] == "mifa":
         x = n
