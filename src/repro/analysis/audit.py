@@ -201,7 +201,7 @@ def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
 
     if engine.cohort is None:
         t_args = (global_params, caches, draw, sel_d, dist_d, res_d,
-                  base_steps, cache_every)
+                  base_steps, cache_every, engine._frozen)
         out.append(_Dispatch("trainer", trainer, t_args))
         (final, cache_p, cached_steps, losses, _steps, fail, success,
          times) = trainer(*t_args)
@@ -217,7 +217,7 @@ def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
             min_aliases=donated))
     elif engine.offload is None:
         t_args = (global_params, caches, draw, sel_d, dist_d, res_d,
-                  base_steps, cache_every)
+                  base_steps, cache_every, engine._frozen)
         out.append(_Dispatch("trainer", trainer, t_args))
         (final, cache_p, cached_steps, _lx, _sx, fail, success, times,
          idx, _overflow, losses_n, fail_n, times_n) = trainer(*t_args)
@@ -244,7 +244,7 @@ def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
         else:
             cache_x = engine._zero_cohort_block()
         t_args = (global_params, caches, cache_x, idx, draw, sel_d,
-                  dist_d, res_d, base_steps, cache_every)
+                  dist_d, res_d, base_steps, cache_every, engine._frozen)
         out.append(_Dispatch("trainer", trainer, t_args))
         (final, cache_p, cached_steps, _lx, _sx, fail, success, times,
          losses_n, fail_n, times_n) = trainer(*t_args)
@@ -278,7 +278,8 @@ def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
     # eval reads replicated operands by design — exempt from contract 4
     out.append(_Dispatch(
         "eval_accuracy", engine._acc_fn,
-        (global_params, engine._test_x, engine._test_y), sharded=False))
+        (global_params, engine._frozen, engine._test_x, engine._test_y),
+        sharded=False))
     return out
 
 

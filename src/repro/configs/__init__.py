@@ -5,6 +5,7 @@ from repro.configs.base import (
     FLConfig,
     INPUT_SHAPES,
     InputShape,
+    LoRAConfig,
     MeshConfig,
     MLAConfig,
     ModelConfig,
@@ -12,6 +13,7 @@ from repro.configs.base import (
     RWKVConfig,
     SSMConfig,
     TrainConfig,
+    YarnConfig,
 )
 
 from repro.configs.h2o_danube_1p8b import CONFIG as _h2o
@@ -53,6 +55,6 @@ def list_configs():
 
 __all__ = [
     "ASSIGNED_ARCHS", "FLConfig", "INPUT_SHAPES", "InputShape", "MeshConfig",
-    "MLAConfig", "ModelConfig", "MoEConfig", "RWKVConfig", "SSMConfig",
-    "TrainConfig", "get_config", "list_configs",
+    "LoRAConfig", "MLAConfig", "ModelConfig", "MoEConfig", "RWKVConfig", "SSMConfig",
+    "TrainConfig", "YarnConfig", "get_config", "list_configs",
 ]

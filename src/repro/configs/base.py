@@ -25,16 +25,44 @@ class MoEConfig:
     shared_d_ff: Optional[int] = None      # d_ff of the shared expert(s)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    first_k_dense_replace: int = 0         # leading layers with a dense MLP
+    experts_held: Optional[int] = None
+    # ^ experts one chip holds under expert parallelism (a share of
+    #   num_experts); None = all.  The router still scores every expert.
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2)."""
-    q_lora_rank: int = 1536
+    """Multi-head Latent Attention (DeepSeek-V2).  ``q_lora_rank=None``
+    projects queries with one plain ``q_proj`` (DeepSeek-V2-Lite)."""
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv 2309.00071), DeepSeek-V2's form."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
+class LoRAConfig:
+    """Low-rank adapters: a projection x W becomes x W + (alpha / rank)
+    x a b, on MLA's q, kv_a, kv_b and o projections."""
+    rank: int = 16
+    alpha: float = 32.0
+
+    @property
+    def scale(self) -> float:
+        return self.alpha / self.rank
 
 
 @dataclass(frozen=True)
@@ -99,6 +127,8 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: Optional[int] = None
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
+    norm_eps: float = 1e-5
     # mlp flavour
     mlp_act: str = "silu_glu"          # silu_glu | gelu | relu2
     norm: str = "rmsnorm"              # rmsnorm | layernorm
@@ -149,7 +179,8 @@ class ModelConfig:
             )
         if self.mla is not None:
             changes["mla"] = MLAConfig(
-                q_lora_rank=64, kv_lora_rank=32,
+                q_lora_rank=None if self.mla.q_lora_rank is None else 64,
+                kv_lora_rank=32,
                 qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32)
             changes["head_dim"] = None
         if self.ssm is not None:
@@ -220,6 +251,12 @@ class FLConfig:
     num_clients: int = 256
     clients_per_round: int = 32
     local_steps: int = 4
+    model: str = "mlp"
+    # ^ registered FL model (repro.fl.models) the clients train: "mlp"
+    #   (the classifier of repro.fl.classifier, every leaf trained) or
+    #   "deepseek-v2-lite" (LoRA adapters over a frozen MLA + MoE trunk,
+    #   repro.configs.deepseek_v2_lite).  Only the trainable leaves are
+    #   packed, aggregated and cached.
     # selection (Alg. 1)
     selection_mode: str = "mean"       # mean | thompson (beyond-paper)
     epsilon_init: float = 0.9          # exploration factor
@@ -378,6 +415,11 @@ class FLConfig:
                     f"FLConfig.adversary must be a registered adversary "
                     f"({', '.join(available_adversaries())}) or None, "
                     f"got {self.adversary!r}")
+        from repro.fl.models import available_models
+        if self.model not in available_models():
+            raise ValueError(
+                f"FLConfig.model must be a registered FL model "
+                f"({', '.join(available_models())}), got {self.model!r}")
         from repro.fleet.api import available_dynamics
         if self.dynamics not in available_dynamics():
             raise ValueError(

@@ -14,8 +14,10 @@ CONFIG = ModelConfig(
     num_heads=128,
     num_kv_heads=128,          # MLA decompresses to per-head K/V (MHA-like)
     d_ff=12288,                # dense-equivalent ff (first layer is dense in
-                               # DeepSeek-V2; we keep all layers MoE for
-                               # uniform scan, noting the delta in DESIGN.md)
+                               # DeepSeek-V2; this stack keeps all layers MoE
+                               # for a uniform scan; models/lora_lm.py, the
+                               # fleet path, builds the dense first layer
+                               # from MoEConfig.first_k_dense_replace)
     vocab_size=102400,
     attention="mla",
     mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512,

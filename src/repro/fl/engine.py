@@ -40,7 +40,7 @@ import numpy as np
 from repro import core
 from repro.configs.base import FLConfig
 from repro.data.synthetic import FederatedClassification
-from repro.fl import classifier as CLF
+from repro.fl import models as FLM
 from repro.fl.api import (Policy, RoundObservation, RoundPlan, RoundReport,
                           cohort_index, cohort_overflow, make_policy)
 from repro.fl import policies as _builtin_policies  # noqa: F401  (registers)
@@ -61,8 +61,17 @@ BIG = 1 << 20
 def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
                  mesh=None, donate: bool = False, dynamics_features=None,
                  cohort_size: Optional[int] = None,
-                 external_cache_params: bool = False):
+                 external_cache_params: bool = False,
+                 model: Optional["FLM.FLModel"] = None):
     """Build the jitted all-fleet local trainer.
+
+    ``model`` (a ``repro.fl.models.FLModel``, default the ``"mlp"``
+    classifier) supplies the per-client loss and gradient.  Every variant
+    takes the model's frozen leaves as its last argument ``frozen``
+    (default: none): one copy the whole cohort shares, a jit argument
+    (never closed over, so it is not compiled into the executable, and
+    never donated).  Only the trainable leaves are trained, snapshotted
+    into caches and returned.
 
     ``mesh``: optional ``("clients",)`` fleet mesh — the per-client
     training set (N, n, d)/(N, n) is placed sharded over clients so each
@@ -117,7 +126,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
     lr = sim_cfg.lr
     max_steps = sim_cfg.local_steps
 
-    grad_fn = jax.vmap(jax.value_and_grad(CLF.clf_loss))
+    grad_fn = (model or FLM.get_model("mlp")).value_and_grad
     donate_argnums = (3,) if donate and dynamics_features is None else ()
     if cohort_size is not None and dynamics_features is None:
         raise ValueError("cohort_size requires the dynamics trainer "
@@ -127,7 +136,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
                          "cohort trainer variant (pass cohort_size)")
 
     def local_scan(x_arr, y_arr, start_params, steps_needed, stop_step,
-                   cache_every):
+                   cache_every, frozen):
         """The shared masked local-training scan body.  ``x_arr``/
         ``y_arr`` carry the client axis — the full (N, n, d) fleet or a
         gathered (X, n, d) cohort block; the per-client math is
@@ -140,7 +149,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
             idx = (j * b + jnp.arange(b)) % n
             xb = x_arr[:, idx]
             yb = y_arr[:, idx]
-            loss, grads = grad_fn(params, xb, yb)
+            loss, grads = grad_fn(params, frozen, xb, yb)
             active = (j < steps_needed) & (j < stop_step)
 
             def upd(p, g):
@@ -174,7 +183,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
     if dynamics_features is None:
         @functools.partial(jax.jit, donate_argnums=donate_argnums)
         def train_all(global_params, caches, resume, steps_needed,
-                      stop_step, cache_every):
+                      stop_step, cache_every, frozen=None):
             """All-fleet masked local training (incl. fused resume
             selection).
 
@@ -190,7 +199,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
             """
             start_params = core.resume_params(caches, global_params, resume)
             return local_scan(x_all, y_all, start_params, steps_needed,
-                              stop_step, cache_every)
+                              stop_step, cache_every, frozen)
 
         return train_all
 
@@ -199,7 +208,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
 
     def round_body(x_arr, y_arr, steps_per_sec, global_params, caches,
                    draw, selected, distribute, resume, base_steps,
-                   cache_every):
+                   cache_every, frozen):
         """Workload + failures + training + timing over one client axis
         (the full fleet, or a gathered cohort block — every input is
         aligned along dim 0)."""
@@ -216,7 +225,8 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         stop = jnp.where(fail, draw.interruption_step(steps_needed), BIG)
         start_params = core.resume_params(caches, global_params, resume)
         params, cache, cached_steps, mean_loss = local_scan(
-            x_arr, y_arr, start_params, steps_needed, stop, cache_every)
+            x_arr, y_arr, start_params, steps_needed, stop, cache_every,
+            frozen)
         # timing model (Algorithm 2 lines 13–16) on the round's bandwidth
         success = selected & ~fail & (steps_needed > 0)
         completed = jnp.minimum(steps_needed, stop)
@@ -231,7 +241,8 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
     if cohort_size is None:
         @jax.jit
         def train_all_dyn(global_params, caches, draw, selected,
-                          distribute, resume, base_steps, cache_every):
+                          distribute, resume, base_steps, cache_every,
+                          frozen=None):
             """Dynamics round body: workload + failures + training +
             timing.
 
@@ -245,7 +256,8 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
             """
             return round_body(x_all, y_all, feats.steps_per_sec,
                               global_params, caches, draw, selected,
-                              distribute, resume, base_steps, cache_every)
+                              distribute, resume, base_steps, cache_every,
+                              frozen)
 
         return train_all_dyn
 
@@ -254,7 +266,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
 
     def cohort_round(idx, cache_params_x, global_params, caches, draw,
                      selected, distribute, resume, base_steps,
-                     cache_every):
+                     cache_every, frozen):
         """Shared gather → (X, ...) round body → scatter given the cohort
         index.  ``cache_params_x`` is None on the resident path (the
         cohort's cache slots are gathered from the (N, D) pytree) or the
@@ -287,7 +299,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         (params, cache, cached_steps, mean_loss, steps_needed, fail,
          success, times) = round_body(
             x_x, y_x, sps_x, global_params, caches_x, draw_x, sel_x,
-            dist_x, res_x, base_x, ce_x)
+            dist_x, res_x, base_x, ce_x, frozen)
 
         # (N,) report views: scatter the cohort rows, fill the rest with
         # exactly what the full scan computes for idle clients (loss 0,
@@ -307,7 +319,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         def train_cohort_dyn_offload(global_params, caches,
                                      cache_params_x, idx, draw, selected,
                                      distribute, resume, base_steps,
-                                     cache_every):
+                                     cache_every, frozen=None):
             """Offload cohort round body: like ``train_cohort_dyn`` but
             the cohort index arrives precomputed (the engine's idx jit —
             same ``cohort_index`` values) and the cohort's cache params
@@ -317,13 +329,14 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
             idx = SP.cohort_constraint(idx, mesh, X)
             return cohort_round(idx, cache_params_x, global_params,
                                 caches, draw, selected, distribute,
-                                resume, base_steps, cache_every)
+                                resume, base_steps, cache_every, frozen)
 
         return train_cohort_dyn_offload
 
     @jax.jit
     def train_cohort_dyn(global_params, caches, draw, selected,
-                         distribute, resume, base_steps, cache_every):
+                         distribute, resume, base_steps, cache_every,
+                         frozen=None):
         """Compact-cohort dynamics round body (see the factory
         docstring): gather → (X, ...) round body → scatter, one dispatch.
 
@@ -342,7 +355,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         overflow = cohort_overflow(selected, X)
         outs = cohort_round(idx, None, global_params, caches, draw,
                             selected, distribute, resume, base_steps,
-                            cache_every)
+                            cache_every, frozen)
         overflow, = SP.replicated_constraint((overflow,), mesh)
         return outs[:8] + (idx, overflow) + outs[8:]
 
@@ -622,20 +635,29 @@ class FleetEngine:
                 f"device process, e.g. 'bernoulli', or set "
                 f"cohort_size=None)")
         self._trainer = None      # legacy trainer, built on first host run
-        self._acc_fn = jax.jit(CLF.clf_accuracy)
+        # the model clients train (repro.fl.models): its trainable leaves
+        # are the global model every round moves; its frozen leaves (an
+        # LLM trunk) are placed once and passed to the trainer and eval
+        self.model = FLM.get_model(fl_cfg.model)
+        self._acc_fn = jax.jit(self.model.accuracy)
         self._server_steps = {}
         self._last_caches = None  # previous run's fleet caches (recycled)
         self._cache_reset = None  # donated in-place zero-fill, built lazily
-        template = CLF.init_classifier(
-            jax.random.key(sim_cfg.seed + 1), dim=data.x.shape[-1],
-            num_classes=data.num_classes, hidden=sim_cfg.model_hidden,
-            depth=sim_cfg.model_depth)
+        template, frozen = self.model.init(sim_cfg.seed, data, sim_cfg)
         # place everything the rounds touch once, at construction: the
-        # global model + test set replicated, per-client arrays sharded
+        # global model, frozen leaves and test set replicated, per-client
+        # arrays sharded
         if self.mesh is not None:
             template = jax.device_put(
                 template, jax.tree.map(
                     lambda _: SP.replicated_sharding(self.mesh), template))
+        tracer = obs.Tracer() if fl_cfg.telemetry is not None \
+            else obs.NULL_TRACER
+        with tracer.span("trunk_put"):
+            if self.mesh is not None:
+                frozen = jax.device_put(frozen, jax.tree.map(
+                    lambda _: SP.replicated_sharding(self.mesh), frozen))
+            self._frozen = jax.block_until_ready(frozen)
         self._template = template
         self._test_x, self._test_y, self._n_samples = self._place_eval()
         # device-resident fleet dynamics (repro.fleet): jitted step /
@@ -674,6 +696,7 @@ class FleetEngine:
         # per (level, path); the run-scoped tracer is NULL when off, so
         # instrumented seams cost one attribute lookup on default runs
         self._metrics_fns = {}
+        self._load_fn = None      # expert-load counter, built lazily
         self._tracer = obs.NULL_TRACER
         # debug_checks sanitizer mode (repro.analysis.runtime): checkify
         # round guard + recompilation detector, both built lazily —
@@ -720,7 +743,8 @@ class FleetEngine:
         if self._trainer is None:
             self._trainer = make_trainer(self.sim_cfg, self.data,
                                          mesh=self.mesh,
-                                         donate=self.donate)
+                                         donate=self.donate,
+                                         model=self.model)
         return self._trainer
 
     def _put1(self, arr):
@@ -851,6 +875,8 @@ class FleetEngine:
                      "stamp", "rnd", "rows", "rows_mask", "global"}
             if self.cohort is not None:
                 avail.add("cohort_size")
+                if self.model.expert_load is not None:
+                    avail.add("expert_load")
             if self._agg_stateful:
                 avail.add("rule_state")
             if self.offload == "discard" and uses_cache:
@@ -881,7 +907,27 @@ class FleetEngine:
         if stamp_pre_expire is not None:
             cand["stamp_pre_expire"] = stamp_pre_expire
         with tracer.span("metrics", round=rnd):
+            if "expert_load" in m_keys:
+                cand["expert_load"] = self._expert_load_jit()(
+                    global_params, self._frozen, self._x_dev, cand["idx"])
             return metrics_fn({k: cand[k] for k in m_keys})
+
+    def _expert_load_jit(self):
+        """Memoized jit of the model's held-expert load on the cohort
+        block's first local batch (telemetry only)."""
+        if self._load_fn is None:
+            b = min(self.sim_cfg.batch_size, self.data.x.shape[1])
+            load = self.model.expert_load
+
+            @jax.jit
+            def load_fn(params, frozen, x_all, idx):
+                x = jnp.take(x_all, idx, axis=0, mode="fill",
+                             fill_value=0)[:, :b]
+                return load(params, frozen, x)
+
+            self._load_fn = load_fn
+            self._x_dev = jnp.asarray(self.data.x)
+        return self._load_fn
 
     # -- robust-aggregation state / adversary plumbing ----------------------
 
@@ -1099,22 +1145,23 @@ class FleetEngine:
         # time/comm_to_accuracy and "final acc" reports see fresh data
         if time_budget is not None and hist.eval_mask \
                 and not hist.eval_mask[-1]:
-            hist.acc[-1] = float(self._acc_fn(global_params, self._test_x,
-                                              self._test_y))
+            hist.acc[-1] = float(self._acc_fn(global_params, self._frozen,
+                                              self._test_x, self._test_y))
             hist.eval_mask[-1] = True
 
         # final diagnostics (paper Fig. 1(b)(c))
-        if diagnostics:
+        if diagnostics and self.model.per_class_accuracy is not None:
             with tracer.span("diagnostics"):
                 hist.per_class_acc = np.asarray(
-                    CLF.clf_per_class_accuracy(
-                        global_params, self._test_x, self._test_y,
-                        self.data.num_classes))
+                    self.model.per_class_accuracy(
+                        global_params, self._frozen, self._test_x,
+                        self._test_y, self.data.num_classes))
                 pc = []
                 for i in range(min(fl_cfg.num_clients,
                                    self.data.x.shape[0])):
                     pc.append(float(self._acc_fn(
-                        global_params, jnp.asarray(self.data.x[i]),
+                        global_params, self._frozen,
+                        jnp.asarray(self.data.x[i]),
                         jnp.asarray(self.data.y[i]))))
                 hist.per_client_acc = np.asarray(pc)
         for k, v in policy.history_extras(state).items():
@@ -1214,8 +1261,8 @@ class FleetEngine:
         cum_time += duration
         evaluated = rnd % eval_every == 0 or rnd == n_rounds - 1
         if evaluated:
-            acc = float(self._acc_fn(global_params, self._test_x,
-                                     self._test_y))
+            acc = float(self._acc_fn(global_params, self._frozen,
+                                     self._test_x, self._test_y))
         hist.acc.append(acc)
         hist.eval_mask.append(evaluated)
         hist.comm_mb.append(cum_comm)
@@ -1295,7 +1342,7 @@ class FleetEngine:
                 final, cache_p, cached_steps, losses = self.trainer(
                     global_params, caches, self._put1(resume),
                     self._put1(steps_needed), self._put1(stop),
-                    cache_every)
+                    cache_every, self._frozen)
 
             # timing + round termination
             success = selected & ~fail & (steps_needed > 0)
@@ -1396,7 +1443,8 @@ class FleetEngine:
             trainer = make_trainer(
                 self.sim_cfg, self.data, mesh=mesh,
                 dynamics_features=feats, cohort_size=self.cohort,
-                external_cache_params=self.offload is not None)
+                external_cache_params=self.offload is not None,
+                model=self.model)
             self._dyn_cache[key] = (process, init_fn, jax.jit(step),
                                     trainer)
         return self._dyn_cache[key]
@@ -1574,7 +1622,7 @@ class FleetEngine:
                     (final, cache_p, cached_steps, losses, steps_needed,
                      fail, success, times) = trainer(
                         global_params, caches, draw, sel_d, dist_d,
-                        res_d, base_steps, cache_every)
+                        res_d, base_steps, cache_every, self._frozen)
 
                 # round termination on device: the cut is a device scalar
                 # and the receive mask stays sharded; deadline-capped
@@ -1615,7 +1663,7 @@ class FleetEngine:
                      fail, success, times, idx, overflow, losses_n,
                      fail_n, times_n) = trainer(
                         global_params, caches, draw, sel_d, dist_d,
-                        res_d, base_steps, cache_every)
+                        res_d, base_steps, cache_every, self._frozen)
                 with tracer.span("round_cut", round=rnd):
                     (t_cut, _received_x, received, capped, recv_n,
                      down_n, sel_n) = cut_fn(times, plan.quorum, success,
@@ -1630,7 +1678,7 @@ class FleetEngine:
                     selected=sel_d, distribute=dist_d, resume=res_d,
                     online=draw.online, received=received, fail=fail_n,
                     losses=losses_n, times=times_n, rows=final,
-                    rows_mask=_received_x)
+                    rows_mask=_received_x, idx=idx)
                 with tracer.span("server_step", round=rnd):
                     out = server_step(
                         global_params, caches, final, cache_p,
@@ -1665,7 +1713,8 @@ class FleetEngine:
                      fail, success, times, losses_n, fail_n,
                      times_n) = trainer(
                         global_params, caches, cache_x, idx, draw, sel_d,
-                        dist_d, res_d, base_steps, cache_every)
+                        dist_d, res_d, base_steps, cache_every,
+                        self._frozen)
                 with tracer.span("round_cut", round=rnd):
                     (t_cut, _received_x, received, capped, recv_n,
                      down_n, sel_n) = cut_fn(times, plan.quorum, success,
@@ -1678,7 +1727,7 @@ class FleetEngine:
                     selected=sel_d, distribute=dist_d, resume=res_d,
                     online=draw.online, received=received, fail=fail_n,
                     losses=losses_n, times=times_n, rows=final,
-                    rows_mask=_received_x)
+                    rows_mask=_received_x, idx=idx)
                 with tracer.span("server_step", round=rnd):
                     out = server_step(
                         global_params, caches, final, cached_steps, idx,
@@ -1711,8 +1760,8 @@ class FleetEngine:
             acc_dev = None
             if evaluated:
                 with tracer.span("eval", round=rnd):
-                    acc_dev = self._acc_fn(global_params, self._test_x,
-                                           self._test_y)
+                    acc_dev = self._acc_fn(global_params, self._frozen,
+                                           self._test_x, self._test_y)
             ledger.push(rnd, evaluated, t_cut, capped, recv_n,
                         down_n, sel_n, acc_dev, overflow=overflow,
                         metrics=mx)

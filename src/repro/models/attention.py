@@ -82,12 +82,19 @@ def mla_spec(cfg, layered: Optional[int] = None):
         return L.ParamSpec(shape, dt, axes, init, 1.0, fan_in=fan_in)
 
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
-    return {
+    if m.q_lora_rank is None:
+        # one plain query projection (q_proj)
+        q = {"wq": w((d, h, qk_dim), ("embed", "heads", "head_dim"),
+                     fan_in=d)}
+    else:
         # query low-rank path
-        "w_dq": w((d, m.q_lora_rank), ("embed", "lora")),
-        "q_norm": w((m.q_lora_rank,), ("lora",), "ones"),
-        "w_uq": w((m.q_lora_rank, h, qk_dim), ("lora", "heads", "head_dim"),
-                  fan_in=m.q_lora_rank),
+        q = {"w_dq": w((d, m.q_lora_rank), ("embed", "lora")),
+             "q_norm": w((m.q_lora_rank,), ("lora",), "ones"),
+             "w_uq": w((m.q_lora_rank, h, qk_dim),
+                       ("lora", "heads", "head_dim"),
+                       fan_in=m.q_lora_rank)}
+    return {
+        **q,
         # kv low-rank path (+ shared rope key)
         "w_dkv": w((d, m.kv_lora_rank + m.qk_rope_head_dim),
                    ("embed", "lora")),
@@ -373,55 +380,104 @@ def init_kv_cache(cfg, batch: int, max_len: int, filled: bool = False):
 # MLA (DeepSeek-V2 Multi-head Latent Attention)
 # ---------------------------------------------------------------------------
 
-def _mla_qkv(p, x, positions, cfg):
+def _adapt(adapter, name, inp, base):
+    """``base`` plus the adapter's delta for projection ``name`` (added
+    in float32, returned in ``base``'s dtype); ``base`` alone without an
+    adapter.  ``adapter(name, inp)`` maps the projection's input to a
+    (..., out) delta, ``out`` the projection's flattened output width."""
+    if adapter is None:
+        return base
+    d = adapter(name, inp)
+    return (base.astype(jnp.float32) + d.reshape(base.shape)) \
+        .astype(base.dtype)
+
+
+def _mla_qkv(p, x, positions, cfg, adapter=None):
     m = cfg.mla
     dt = x.dtype
-    cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(dt))
-    cq = _rms(cq, p["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(dt))
+    if "wq" in p:
+        q = _adapt(adapter, "q", x,
+                   jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt)))
+    else:
+        cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(dt))
+        cq = _rms(cq, p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(dt))
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(_deinterleave(q_rope), positions, cfg.rope_theta,
+                          cfg.rope_scaling)
 
-    ckv_full = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt))
+    ckv_full = _adapt(adapter, "kv_a", x,
+                      jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt)))
     c_kv, k_rope = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
-    c_kv = _rms(c_kv, p["kv_norm"])
-    k_rope = L.apply_rope(k_rope[:, :, None, :], positions,
-                          cfg.rope_theta)[:, :, 0, :]
+    c_kv = _rms(c_kv, p["kv_norm"], cfg.norm_eps)
+    k_rope = L.apply_rope(_deinterleave(k_rope[:, :, None, :]), positions,
+                          cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope
 
 
-def _rms(x, scale, eps=1e-5):
+def _deinterleave(x):
+    """DeepSeek-V2's rope layout: the rope dims hold (2i, 2i+1) pairs,
+    regrouped into halves before the half-rotation (HF
+    ``apply_rotary_pos_emb``)."""
+    *lead, d = x.shape
+    return jnp.swapaxes(x.reshape(*lead, d // 2, 2), -1, -2) \
+        .reshape(*lead, d)
+
+
+def _rms(x, scale, eps):
     xf = x.astype(jnp.float32)
     out = xf * jax.lax.rsqrt((xf ** 2).mean(-1, keepdims=True) + eps)
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times mscale(mscale_all_dim)^2 under YaRN."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_forward(p, x, positions, cfg, *, q_chunk=512, k_chunk=512,
-                unroll_causal=False, impl="chunked"):
-    """MLA attention via decompression into per-head K/V (train/prefill)."""
+                unroll_causal=False, impl="chunked", adapter=None):
+    """MLA attention via decompression into per-head K/V (train/prefill).
+
+    ``adapter`` (optional, see ``_adapt``) adds low-rank deltas to the
+    ``q``, ``kv_a`` (the joint latent + rope-key projection), ``kv_b``
+    (the K and V decompressions, one (h * (nope + v)) output split per
+    head) and ``o`` projections."""
     m = cfg.mla
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, positions, cfg)
-    dt = x.dtype
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"].astype(dt))
-    val = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"].astype(dt))
-    B, S, H, _ = k_nope.shape
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (B, S, H, m.qk_rope_head_dim))], -1)
-    q = jnp.concatenate([q_nope, q_rope], -1)
-    # treat as MHA: Hk = H, G = 1; pad v to qk dim not needed — attention
-    # core supports distinct v dim via separate einsum, so call _core directly
-    qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
-    scale = qk_dim ** -0.5
-    q5 = q[:, :, :, None, :]                              # (B,S,H,1,Dqk)
-    if impl == "dense":
-        o = dense_attention(q5, k, val, causal=True, window=None, scale=scale)
-    else:
-        o = chunked_attention(q5, k, val, causal=True, window=None,
-                              scale=scale, q_chunk=q_chunk, k_chunk=k_chunk,
-                              unroll_causal=unroll_causal)
-    o = o[:, :, :, 0, :]                                  # (B,S,H,Dv)
-    return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
+    with jax.named_scope("mla"):
+        q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, positions, cfg,
+                                                adapter)
+        dt = x.dtype
+        k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"].astype(dt))
+        val = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"].astype(dt))
+        if adapter is not None:
+            kv = _adapt(adapter, "kv_b", c_kv,
+                        jnp.concatenate([k_nope, val], -1))
+            k_nope, val = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
+        B, S, H, _ = k_nope.shape
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      (B, S, H, m.qk_rope_head_dim))], -1)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        # treat as MHA: Hk = H, G = 1; the attention core takes a v width
+        # of its own
+        scale = mla_softmax_scale(cfg)
+        q5 = q[:, :, :, None, :]                          # (B,S,H,1,Dqk)
+        if impl == "dense":
+            o = dense_attention(q5, k, val, causal=True, window=None,
+                                scale=scale)
+        else:
+            o = chunked_attention(q5, k, val, causal=True, window=None,
+                                  scale=scale, q_chunk=q_chunk,
+                                  k_chunk=k_chunk,
+                                  unroll_causal=unroll_causal)
+        o = o[:, :, :, 0, :]                              # (B,S,H,Dv)
+        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
+        return _adapt(adapter, "o", o.reshape(B, S, -1), out)
 
 
 def mla_prefill(p, x, positions, cfg, cache: MLACache, **kw):
@@ -443,7 +499,6 @@ def mla_decode_step(p, x, positions, cfg, cache: MLACache):
     Scores are computed in latent space: q_nope is absorbed through w_uk so
     the per-token cache stays (kv_lora + rope_dim) wide.
     """
-    m = cfg.mla
     dt = x.dtype
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(p, x, positions, cfg)
 
@@ -461,8 +516,7 @@ def mla_decode_step(p, x, positions, cfg, cache: MLACache):
                         preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bshk,btk->bhst", q_rope, k_rope.astype(dt),
                         preferred_element_type=jnp.float32)
-    qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
-    s = (s_nope + s_rope) * (qk_dim ** -0.5)
+    s = (s_nope + s_rope) * mla_softmax_scale(cfg)
     valid = jnp.arange(Sc)[None, :] < jnp.minimum(
         cache.length[:, None] + 1, Sc)
     s = jnp.where(valid[:, None, None], s, NEG_INF)

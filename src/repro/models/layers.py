@@ -8,6 +8,7 @@ logical axes, init law).  ``init_params`` materializes values;
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -96,7 +97,8 @@ def norm_spec(cfg, d: int, layered: Optional[int] = None):
     return p
 
 
-def apply_norm(p, x, cfg, eps: float = 1e-5):
+def apply_norm(p, x, cfg, eps: Optional[float] = None):
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = xf.mean(-1, keepdims=True)
@@ -169,18 +171,48 @@ def apply_mlp(p, x, cfg):
 # Rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
+def rope_freqs(head_dim: int, theta: float, yarn=None):
+    """Inverse frequencies (D/2,); with a ``YarnConfig`` the YaRN blend of
+    interpolated and original frequencies over the correction range
+    (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    base = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    if yarn is None:
+        return base
+
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rot * 2 * math.pi))) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp              # 1 = original frequency, 0 = scaled
+    return (base / yarn.factor * (1.0 - keep) + base * keep
+            ).astype(np.float32)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  With a
+    ``YarnConfig``, YaRN frequencies and cos/sin scaled by
+    mscale(mscale) / mscale(mscale_all_dim)."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta))            # (D/2,)
+    freqs = jnp.asarray(rope_freqs(d, theta, yarn))      # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]                        # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) \
+            / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -163,3 +163,87 @@ def moe_forward(p, x, cfg, exec_cfg=None):
     pmean = probs.mean((0, 1))
     aux = E * jnp.sum(frac * pmean) * m.router_aux_weight
     return out.reshape(B, S, d), aux
+
+
+# ---------------------------------------------------------------------------
+# Expert-parallel share: the experts one chip holds, dropless
+# ---------------------------------------------------------------------------
+
+def held_moe_spec(cfg, layered: Optional[int] = None):
+    """One chip's share of an expert-parallel MoE layer: the router over
+    all ``num_experts``, the ``experts_held`` routed experts it holds and
+    the shared experts (no capacity: the share is dropless)."""
+    m = cfg.moe
+    held = m.experts_held or m.num_experts
+    d, eff = cfg.d_model, m.expert_d_ff or cfg.d_ff
+    dt = L.cfg_dtype(cfg.param_dtype)
+
+    def w(shape, axes):
+        if layered is not None:
+            shape, axes = (layered,) + shape, ("layers",) + axes
+        return L.ParamSpec(shape, dt, axes, "normal")
+
+    p = {"router": w((d, m.num_experts), ("embed", "expert_gate")),
+         "wi": w((held, d, eff), ("expert", "embed", "expert_mlp")),
+         "wg": w((held, d, eff), ("expert", "embed", "expert_mlp")),
+         "wo": w((held, eff, d), ("expert", "expert_mlp", "embed"))}
+    if m.num_shared_experts:
+        p["shared"] = L.mlp_spec(
+            cfg, d, (m.shared_d_ff or eff) * m.num_shared_experts,
+            layered=layered, ff_axis="mlp")
+    return p
+
+
+def route(router_w, x, cfg):
+    """Softmax router over every expert: ``(gates, experts)``, each
+    (T, top_k), gates in float32 and not renormalised over the top k
+    (DeepSeek-V2-Lite: ``norm_topk_prob`` false, scaling factor 1)."""
+    logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.moe.top_k)
+
+
+def held_moe_forward(p, x, cfg, first_expert: int = 0,
+                     with_shared: bool = True):
+    """x: (T, d) -> ((T, d), load): this chip's part of the MoE layer.
+
+    The router scores all ``num_experts``; of each token's top-k
+    (token, slot) pairs those that land on the held experts
+    ``[first_expert, first_expert + experts_held)`` are sorted by expert
+    and run through ``jax.lax.ragged_dot`` (every pair, none dropped), the
+    rest contribute nothing here (another chip holds their expert).  The
+    shared experts run on every token once (``with_shared=False`` leaves
+    them to another share).  ``load`` is the (experts_held,) count of
+    pairs each held expert took."""
+    m = cfg.moe
+    held = p["wi"].shape[0]
+    T, d = x.shape
+    k = m.top_k
+    dt = x.dtype
+    with jax.named_scope("moe.route"):
+        gates, experts = route(p["router"], x, cfg)
+        local = experts.reshape(-1) - first_expert            # (T*k,)
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        tok = order // k
+        sizes = jnp.zeros((held,), jnp.int32).at[
+            jnp.where(mine, local, held)].add(1, mode="drop")
+        w_pair = jnp.where(mine, gates.reshape(-1), 0.0)[order]
+        keep = mine[order]
+    with jax.named_scope("moe.experts"):
+        # rows past the held groups are never written by the grouped
+        # product, nor by its transpose in the backward pass: select, do
+        # not multiply, on the way out and (for the input gradient the
+        # gather scatters back) on the way in, so nothing stale leaks in
+        xs = jnp.where(keep[:, None], jnp.take(x, tok, axis=0), 0)
+        h = jax.lax.ragged_dot(xs, p["wi"].astype(dt), sizes)
+        g = jax.lax.ragged_dot(xs, p["wg"].astype(dt), sizes)
+        y = jax.lax.ragged_dot(_act(cfg, h, g), p["wo"].astype(dt), sizes)
+        y = jnp.where(keep[:, None], y.astype(jnp.float32)
+                      * w_pair[:, None], 0.0)
+        out = jnp.zeros((T, d), jnp.float32).at[tok].add(y)
+    if m.num_shared_experts and with_shared:
+        with jax.named_scope("moe.shared"):
+            out = out + L.apply_mlp(p["shared"], x, cfg).astype(jnp.float32)
+    return out.astype(dt), sizes

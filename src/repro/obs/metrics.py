@@ -24,7 +24,9 @@ times (inf = no upload); ``progress, stamp`` — (N,) C3 cache metadata
 — (N,) stamps before the discard-mode expiry (discard runs only);
 ``rule_state`` — (N,) robust-aggregation state (stateful rules);
 ``rows, rows_mask, global`` — stacked client params, their receive
-mask, and the pre-step global model; ``rnd`` — the round index.
+mask, and the pre-step global model; ``rnd`` — the round index;
+``expert_load`` — (experts_held,) pairs the held experts of an
+expert-parallel MoE model took (cohort paths of such a model only).
 
 Static keys (``make_metrics_fn(static=...)``): ``num_clients``,
 ``cohort_size`` (None on the full scan), ``local_steps``,
@@ -186,6 +188,19 @@ def _cache(ctx, static):
         "cache_rows": _count(ctx["stamp"] >= 0),
         "cache_hit_count": _count(ctx["resume"] & ctx["selected"]),
     }
+
+
+@register_metric("expert_load", needs=("expert_load",))
+def _expert_load(ctx, static):
+    """Held-expert load of an expert-parallel MoE model: (token, slot)
+    pairs routed to this chip's experts, summed over its MoE layers, for
+    the cohort block's first local batch through the round's starting
+    model (empty cohort slots train, and count, too), and the
+    most-loaded held expert's share of them."""
+    load = ctx["expert_load"].astype(jnp.float32)
+    total = jnp.sum(load)
+    return {"moe_routed_pairs": total,
+            "moe_max_expert_share": jnp.max(load) / jnp.maximum(total, 1.0)}
 
 
 @register_metric("cohort_fill", needs=("selected", "cohort_size"))

@@ -74,6 +74,14 @@ def test_fed_agg_compiles(one_chip, c):
     _assert_kernel(compiled)
 
 
+def test_fed_agg_compiles_for_adapter_rows(one_chip):
+    """The deepseek-v2-lite cell's uploads: 16 rows of its LoRA adapters
+    (D = 1,579,008 = 2,048 x 771)."""
+    compiled = fed_agg_pallas.lower(
+        _sds((16, 1579008), one_chip), _sds((16,), one_chip)).compile()
+    _assert_kernel(compiled)
+
+
 def test_residual_norms_compiles(one_chip):
     compiled = residual_norms_pallas.lower(
         _sds((X, D), one_chip), _sds((D,), one_chip)).compile()
