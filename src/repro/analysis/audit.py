@@ -4,7 +4,8 @@ The auditor replays the construction of one engine round — the same
 argument plumbing as ``FleetEngine._device_rounds``, on a live engine —
 but instead of just executing the jitted dispatches it *lowers and
 compiles* each one (trainer, round cut, metrics, server step, flude
-plan/update, cohort index, cache expiry, eval) and statically checks
+plan/update, cohort index, cache expiry, the cache stream's expansion
+and compaction, eval) and statically checks
 the post-SPMD HLO against the round-path contracts
 (:mod:`repro.analysis.hlo_checks`):
 
@@ -90,18 +91,19 @@ def _mode(engine) -> str:
 def transfer_ceiling(engine, uses_cache: bool) -> Dict[str, int]:
     """Static per-round ceiling on the engine's cache-stream transfers.
 
-    The offload stream's steady-state round is exactly: one async d2h of
-    the cohort index plus one async d2h of the staged write-back, one
-    async h2d of the fetched (X, D) block, two pre-issued host reads
-    (gather + prune bookkeeping), and **zero** synchronous copies — the
-    double-buffering contract ``tests/test_cache_store.py`` pins
+    The offload stream's steady-state round is at most: one async d2h
+    of the cohort index, one of the staged write-back's (X,) arrays and
+    one of its compacted written rows (none in a round that wrote no
+    row), one async h2d of the fetched hit rows and their positions, a
+    pre-issued host read of each d2h copy, and **zero** synchronous
+    copies — the contract ``tests/test_cache_store.py`` pins
     dynamically.  Everything else (resident caches, or a policy that
     never caches) moves nothing per round."""
     if engine.offload is None or not uses_cache:
         return {"d2h_async": 0, "h2d_async": 0,
                 "pre_issued_reads": 0, "sync_copies": 0}
-    return {"d2h_async": 2, "h2d_async": 1,
-            "pre_issued_reads": 2, "sync_copies": 0}
+    return {"d2h_async": 3, "h2d_async": 1,
+            "pre_issued_reads": 3, "sync_copies": 0}
 
 
 def check_transfer_stats(engine, rounds: int, uses_cache: bool,
@@ -260,6 +262,22 @@ def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
             (global_params, caches, final, cached_steps, idx, sel_d,
              fail, received_x, res_d, n_samples, extra_w, rnd, *extra),
             min_aliases=donated))
+        if uses_cache:
+            # the stream's own programs, at their smallest bucket: the
+            # expansion of the fetched rows and the compaction of the
+            # written ones (the round's receive mask stands in for the
+            # server step's write mask: same shape, dtype and placement)
+            stream = engine._cache_stream
+            store = stream.store
+            b = stream._buckets[0]
+            rows = store._treedef.unflatten(
+                [jax.ShapeDtypeStruct((b,) + shape, dtype)
+                 for shape, dtype in zip(store._shapes, store._dtypes)])
+            pos = jax.ShapeDtypeStruct((b,), np.int32)
+            out.append(_Dispatch("cache_expand", stream.expand_fn,
+                                 (rows, pos)))
+            out.append(_Dispatch("cache_compact", stream.compact_fns[b],
+                                 (cache_p, received_x, idx)))
 
     if metrics_fn is not None:
         ctx = {k: ctx_common[k] for k in m_keys}

@@ -110,7 +110,6 @@ HOST_SYNC_ALLOWLIST: Dict[str, Set[str]] = {
         # the host store's own plumbing: gather/apply run on host rows,
         # and the stream's pre-issued reads are the documented async
         # fetch path (counted in TransferStats.pre_issued_reads)
-        "_tree_bytes",
         "HostCacheStore",
         "CohortCacheStream",
     },

@@ -14,18 +14,31 @@ round trip:
   slot — zero params — which is exactly what the resident pytree's
   gather produces for rows whose metadata says "no cache", so the jitted
   round body needs no special handling.
-* :class:`CohortCacheStream` — the async double-buffering protocol
-  around the store.  Written slots stream back with
-  ``copy_to_host_async`` immediately after the server step is
-  *dispatched* and are drained one round later, when the next fetch
-  needs them; the next cohort's slots are gathered and shipped with an
-  async ``jax.device_put`` as soon as the cohort index is known.  No
-  O(X·D) copy ever blocks the round that produced it — the only
-  blocking reads are on handles whose device-to-host copies were issued
-  a full dispatch earlier (counted in the :class:`TransferStats` the
-  stream shares with its store, exposed as ``FleetEngine.transfer_stats`` —
-  counters are strictly per-engine; the old process-wide ``STATS``
-  aggregate is gone, and ``repro.analysis.lint`` rejects the pattern).
+* :class:`CohortCacheStream` — the async protocol around the store,
+  which moves only live rows across the host link.  ``stage`` parks the
+  round's device handles, the trainer's (X, ...) cache block among
+  them, and starts async device-to-host copies of the four (X,) arrays
+  only: cohort index, write and clear masks, stamps.  The next round's
+  drain (its ``fetch`` runs it before the gather, so a client written in
+  round r can resume in round r + 1) reads those, dispatches the
+  compaction ``jit_cache_compact`` — the K written rows gathered into a
+  row-major (bucket(K), ...) block — copies that block back, applies it
+  and drops the (X, ...) block.  ``fetch`` puts only the H rows the
+  store holds for the cohort, as a row-major (bucket(H), ...) block,
+  with their (bucket(H),) positions (padding points past the cohort);
+  the expansion ``jit_cache_expand`` scatters them into a zero (X, ...)
+  block on the cohort sharding: the trainer's input, equal to the full
+  block the store would gather.  A round with no hit puts positions
+  only, a round with no write copies back nothing.  Buckets are the
+  multiples of max(8, ⌈X/16⌉) rows (rounded to the mesh's device count)
+  up to X, at most 16 sizes each way, all compiled at the stream's
+  first fetch, so a run's later rounds and later runs compile nothing.
+  No copy ever blocks the round that produced it: the blocking reads
+  are the next round's, on copies issued beforehand (counted in the
+  :class:`TransferStats` the stream shares with its store, exposed as
+  ``FleetEngine.transfer_stats`` — counters are strictly per-engine;
+  the old process-wide ``STATS`` aggregate is gone, and
+  ``repro.analysis.lint`` rejects the pattern).
 
 ``cache_offload="discard"`` additionally drops rows whose round stamp is
 more than ``cache_staleness_bound`` rounds old (the paper's cache is
@@ -36,18 +49,18 @@ round with the same bound, so the planner never resumes a pruned row.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
-from typing import Any, List, Optional
+import math
+from typing import Any, Dict, List, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from repro.obs.trace import NULL_TRACER
-
-# Rows are picked out of a block whose cohort axis is innermost (the
-# TPU's layout for the trainer's (X, ...) cache block) a tile of ~4 MB
-# of the block at a time, so that the tile stays in cache meanwhile.
-_TILE_BYTES = 4 << 20
 
 
 @dataclasses.dataclass
@@ -55,12 +68,17 @@ class TransferStats:
     """Per-stream counters of the offload stream's host transfers.
 
     ``*_async`` count *dispatches* of asynchronous copies (one per
-    pytree, not per leaf); ``pre_issued_reads`` counts blocking
-    ``np.asarray`` reads on handles whose device-to-host copy was
-    already issued a dispatch earlier (the double-buffering drain);
-    ``sync_copies`` counts synchronous round-blocking copies — the
-    streaming protocol never performs one, and the transfer-count tests
-    assert it stays zero.
+    pytree, not per leaf): a round puts one block of hit rows and their
+    positions, and copies back its cohort index, its four (X,) arrays
+    and, when it wrote rows, one compacted block.  ``pre_issued_reads``
+    counts blocking ``np.asarray`` reads on handles whose
+    device-to-host copy was issued before the read — every copy is read
+    once, in the next round's drain or fetch; ``sync_copies`` counts
+    synchronous round-blocking copies — the streaming protocol never
+    performs one, and the transfer-count tests assert it stays zero.
+    ``*_bytes`` count what crossed the link, from shapes; ``h2d_rows``
+    and ``d2h_rows`` the cache rows that crossed, bucket padding
+    included.
 
     The host store's own counters: ``rows_written`` rows copied into
     the store, ``rows_cleared`` rows it released (a received upload's
@@ -73,6 +91,8 @@ class TransferStats:
     d2h_async: int = 0
     h2d_bytes: int = 0
     d2h_bytes: int = 0
+    h2d_rows: int = 0
+    d2h_rows: int = 0
     pre_issued_reads: int = 0
     sync_copies: int = 0
     rows_written: int = 0
@@ -89,21 +109,24 @@ class TransferStats:
 
 
 def _tree_bytes(tree) -> int:
-    return sum(int(np.asarray(l).nbytes) for l in jax.tree.leaves(tree))
+    """Bytes of a pytree's leaves, from their shapes and dtypes alone:
+    no leaf is read."""
+    return sum(math.prod(l.shape) * np.dtype(l.dtype).itemsize
+               for l in jax.tree.leaves(tree))
 
 
-def _pick_rows(src_t: np.ndarray, ks: np.ndarray, dst) -> None:
-    """Rows ``ks`` of ``src_t`` (a block with its cohort axis moved
-    last, C-contiguous) into the slab rows ``dst`` (``(slab leaf, rows,
-    positions in ks)`` per chunk), a cache-sized tile of the leading
-    axis at a time, reading the block in its memory order."""
-    n = src_t.shape[0]
-    tile = max(1, _TILE_BYTES // max(src_t[0].nbytes, 1))
-    for a in range(0, n, tile):
-        b = min(a + tile, n)
-        picked = np.moveaxis(np.take(src_t[a:b], ks, axis=-1), -1, 0)
-        for slab, rs, js in dst:
-            slab[rs, a:b] = picked[js]
+def row_buckets(x: int, devices: int = 1) -> List[int]:
+    """Row counts the stream rounds a block of up to ``x`` rows up to:
+    the multiples of max(8, ⌈x/16⌉), rounded up to a multiple of
+    ``devices`` (a mesh shards every bucket evenly), capped at ``x`` —
+    at most 16 sizes."""
+    step = max(8, -(-x // 16))
+    step = -(-step // devices) * devices
+    return sorted({min(step * j, x) for j in range(1, -(-x // step) + 1)})
+
+
+def _bucket(buckets: List[int], n: int) -> int:
+    return 0 if n == 0 else buckets[bisect.bisect_left(buckets, n)]
 
 
 class HostCacheStore:
@@ -202,45 +225,26 @@ class HostCacheStore:
 
     def _write_rows(self, leaves: List[np.ndarray], ks: np.ndarray,
                     slots: np.ndarray) -> None:
-        """Copy rows ``ks`` of the (X, ...) ``leaves`` into ``slots``.
-
-        A leaf stored row by row is copied a row at a time.  A leaf that
-        stores its cohort axis innermost has strided rows (one cache
-        line holds 16 rows' float32s, and a row copy reads a line per
-        element): it is read in memory order instead (``_pick_rows``),
-        so each line is read once.
-        """
-        if not ks.size:
-            return
-        chunk, row = np.divmod(slots, self._chunk)
-        groups = [(c, row[chunk == c], np.flatnonzero(chunk == c))
-                  for c in np.unique(chunk).tolist()]
-        for j, src in enumerate(leaves):
-            src_t = np.moveaxis(src, 0, -1)
-            if src.flags.c_contiguous or not src_t.flags.c_contiguous:
-                for k, slot in zip(ks.tolist(), slots.tolist()):
-                    slab, r = self._locate(slot)
-                    slab[j][r] = src[k]
-            else:
-                _pick_rows(src_t, ks, [(self._slabs[c][j], rs, js)
-                                       for c, rs, js in groups])
+        """Copy rows ``ks`` of the leaves ``leaves`` into ``slots``, a
+        row at a time (rows of any layout; the stream's compacted
+        blocks arrive row-major, so each row is one contiguous copy)."""
+        for k, slot in zip(ks.tolist(), slots.tolist()):
+            slab, r = self._locate(slot)
+            for dst, src in zip(slab, leaves):
+                dst[r] = src[k]
 
     def _new_block(self, x: int) -> List[np.ndarray]:
         return [np.zeros((x,) + s, d)
                 for s, d in zip(self._shapes, self._dtypes)]
 
-    def _copy_rows(self, idx: np.ndarray, out: List[np.ndarray]
-                   ) -> np.ndarray:
-        """Copy the stored rows of ``idx`` into the matching rows of the
-        (X, ...) leaves ``out``; rows without a stored row are left
-        alone.  Returns the row positions that were filled."""
-        slots = self._slots_of(idx)
-        hit = np.flatnonzero(slots >= 0)
-        for k, slot in zip(hit.tolist(), slots[hit].tolist()):
+    def _copy_rows(self, slots: np.ndarray, out: List[np.ndarray],
+                   at) -> None:
+        """Copy the rows in ``slots`` (all live) into rows ``at`` of the
+        leaves ``out``."""
+        for k, slot in zip(at, slots.tolist()):
             slab, r = self._locate(slot)
             for dst, src in zip(out, slab):
                 dst[k] = src[r]
-        return hit
 
     # -- fetch / apply ------------------------------------------------------
 
@@ -258,16 +262,22 @@ class HostCacheStore:
         """
         idx = np.asarray(idx)
         out = self._new_block(idx.shape[0])
-        self._copy_rows(idx, out)
+        slots = self._slots_of(idx)
+        hit = np.flatnonzero(slots >= 0)
+        self._copy_rows(slots[hit], out, hit.tolist())
         return jax.tree.unflatten(self._treedef, out)
 
     def apply(self, idx: np.ndarray, write: np.ndarray, clear: np.ndarray,
-              stamps: np.ndarray, block, current_round: int) -> None:
+              stamps: np.ndarray, block, current_round: int,
+              rows: Optional[np.ndarray] = None) -> None:
         """Apply one round's cache bookkeeping to the store.
 
         ``idx``/``write``/``clear``/``stamps`` are (X,) host arrays;
         ``block`` is the (X, ...) cohort cache-params pytree the trainer
-        produced.  Rows are copied into their slots where ``write``,
+        produced or, where ``rows`` is given, a block whose leading rows
+        are the rows at those (ascending) positions of ``idx`` — the
+        stream's compacted write-back, which holds every written row.
+        Rows are copied into their slots where ``write``,
         released where ``clear`` (a received upload invalidates the slot
         — the host row becomes unreachable because the device metadata
         is reset, so keeping it would only hold memory).  ``write`` and
@@ -294,8 +304,9 @@ class HostCacheStore:
         for cid in cids[self._slot[cids] < 0].tolist():
             self._slot[cid] = self._take_slot()
         self._stamp[cids] = stamps[wrote]
+        src = wrote if rows is None else np.searchsorted(rows, wrote)
         self._write_rows([np.asarray(l) for l in jax.tree.leaves(block)],
-                         wrote, self._slot[cids])
+                         src, self._slot[cids])
         self.stats.rows_written += int(wrote.size)
         if self.staleness_bound is not None:
             self.prune(current_round)
@@ -310,42 +321,45 @@ class HostCacheStore:
         self._release(dead)
 
 
+def _row_major(ndim: int, sharding) -> Format:
+    """Format of a block whose rows are contiguous (cohort axis
+    outermost), so that its host copy is C-contiguous: the TPU's default
+    layout may put the cohort axis innermost."""
+    return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
+
+
 class CohortCacheStream:
-    """Double-buffered device↔host streaming of cohort cache slots.
+    """Device↔host streaming of the live rows of cohort cache slots.
 
     The engine drives it with two calls per round:
 
     * ``fetch(idx, rnd)`` — called as soon as the round's cohort index
       is dispatched.  Starts the async device-to-host copy of ``idx``,
-      drains the *previous* round's staged write-back (whose async
-      copies have been in flight since that round's server step was
-      dispatched), gathers the cohort's rows from the store and ships
-      them back with an async ``jax.device_put`` onto the cohort
-      sharding.
+      drains the *previous* round's staged write-back, copies the
+      cohort's stored rows into a (bucket(H), ...) staging block, puts
+      it with the rows' positions in one async ``jax.device_put`` and
+      returns the (X, ...) block ``jit_cache_expand`` builds from them.
     * ``stage(idx, write, clear, block, stamps)`` — called right after
-      the server step is dispatched.  Starts ``copy_to_host_async`` on
-      every handle and parks them; nothing blocks until the next
-      round's ``fetch`` (or ``flush``) reads them.
+      the server step is dispatched.  Parks the handles and starts the
+      async copies of the four (X,) arrays; it reads nothing, and the
+      (X, ...) ``block`` stays on the device until the next drain
+      compacts its written rows (``jit_cache_compact``).
 
-    The fetched rows are gathered into one of two (X, ...) staging
-    blocks the stream owns, used in turn so that a block is never
-    rewritten while a put from it may still be in flight; before each
-    use only the rows that held hits last time are zeroed, so the block
-    handed to ``device_put`` is byte-identical to a fresh
-    ``store.gather(idx)`` at a cost in hits, not X.  The next-but-one
-    fetch rewrites a block only after its drain has read the previous
-    round's write-back, which the device produces after the trainer
-    that consumed the block's put.  (On
-    a backend whose ``device_put`` may alias host memory, the CPU, the
-    fetched array shares its staging block: consume it before then, as
-    the round does.)
+    Hit rows are copied into the leading rows of one of two (X, ...)
+    staging blocks the stream owns, used in turn, so that a block is
+    never rewritten while a put from it may still be in flight: the
+    next-but-one fetch rewrites a block only after its drain has read
+    the previous round's masks, which the device produces after the
+    expansion that consumed the block's put.  (On a backend whose
+    ``device_put`` may alias host memory, the CPU, the put shares its
+    staging block.)
 
     ``tracer`` (the engine hands over its run's tracer, and
     ``NULL_TRACER`` when none) spans each step of the protocol as a
     child of the engine's ``cache_fetch``/``cache_stage``/
-    ``cache_flush`` span: ``cache_d2h_issue``, ``cache_count_bytes``,
-    ``cache_drain``, ``cache_read``, ``cache_apply``, ``cache_gather``
-    and ``cache_put``.
+    ``cache_flush`` span: ``cache_d2h_issue``, ``cache_drain``,
+    ``cache_read``, ``cache_compact``, ``cache_apply``,
+    ``cache_gather``, ``cache_put`` and ``cache_expand``.
     """
 
     def __init__(self, store: HostCacheStore, mesh=None,
@@ -357,18 +371,86 @@ class CohortCacheStream:
         self.stats = store.stats
         self.tracer = NULL_TRACER
         self._pending = None
-        # two staging blocks, used in turn, and the rows of each that
-        # hold hits
+        # per cohort size X: the buckets, the staging blocks and the
+        # compiled expansion and compaction of every bucket
+        self._x = 0
+        self._buckets: List[int] = []
         self._blocks: List[List[np.ndarray]] = []
-        self._hits: List[np.ndarray] = []
+        self._put_shardings = None
+        # the expansion and compactions as jitted functions (the
+        # auditor lowers them) and compiled for every bucket
+        self.expand_fn = None
+        self.compact_fns: Dict[int, Any] = {}
+        self._expand: Dict[int, Any] = {}
+        self._compact: Dict[int, Any] = {}
 
-    def _sharding(self, tree):
+    def _sharding(self, ndim: int):
+        """The cohort sharding of an (X, ...) or bucket block; on one
+        device none, so that the fetched block stays uncommitted like
+        the round's other inputs (a committed one would commit every
+        output downstream and recompile the round's programs)."""
         if self.mesh is None:
             return None
         from repro.sharding import partitioning as SP
-        return jax.tree.map(
-            lambda l: SP.cohort_sharding(self.mesh, np.asarray(l).ndim),
-            tree)
+        return SP.cohort_sharding(self.mesh, ndim)
+
+    def _size_for(self, x: int) -> None:
+        """Buckets, staging blocks, expansions and compactions for
+        cohorts of ``x`` rows, every bucket compiled now (at the first
+        fetch, before the first trainer)."""
+        if x == self._x:
+            return
+        store = self.store
+        devices = 1 if self.mesh is None else self.mesh.devices.size
+        self._x = x
+        self._buckets = row_buckets(x, devices)
+        self._blocks = [store._new_block(x) for _ in range(2)]
+        ndims = [1 + len(s) for s in store._shapes]
+
+        def cache_expand(rows, pos):
+            return jax.tree.map(
+                lambda r: jnp.zeros((x,) + r.shape[1:], r.dtype)
+                .at[pos].set(r, mode="drop"), rows)
+
+        if self.mesh is None:
+            self.expand_fn = jax.jit(cache_expand)
+        else:
+            out = store._treedef.unflatten(
+                [self._sharding(nd) for nd in ndims])
+            self._put_shardings = (out, self._sharding(1))
+            self.expand_fn = jax.jit(cache_expand,
+                                     in_shardings=self._put_shardings,
+                                     out_shardings=out)
+        self._expand = {}
+        for h in [0] + self._buckets:
+            rows = store._treedef.unflatten(
+                [jax.ShapeDtypeStruct((h,) + s, d)
+                 for s, d in zip(store._shapes, store._dtypes)])
+            self._expand[h] = self.expand_fn.lower(
+                rows, jax.ShapeDtypeStruct((h,), jnp.int32)).compile()
+
+        n = store.num_clients
+        outs = store._treedef.unflatten(
+            [_row_major(nd, self._sharding(nd)
+                        or SingleDeviceSharding(jax.devices()[0]))
+             for nd in ndims])
+
+        def compaction(b):
+            def cache_compact(block, write, idx):
+                keep = write & (idx >= 0) & (idx < n)
+                at = jnp.nonzero(keep, size=b, fill_value=0)[0]
+                return jax.tree.map(lambda l: l[at], block)
+            return jax.jit(cache_compact, out_shardings=outs)
+
+        block = store._treedef.unflatten(
+            [jax.ShapeDtypeStruct((x,) + s, d, sharding=self._sharding(nd))
+             for s, d, nd in zip(store._shapes, store._dtypes, ndims)])
+        write = jax.ShapeDtypeStruct((x,), bool, sharding=self._sharding(1))
+        idx = jax.ShapeDtypeStruct((x,), jnp.int32,
+                                   sharding=self._sharding(1))
+        self.compact_fns = {b: compaction(b) for b in self._buckets}
+        self._compact = {b: fn.lower(block, write, idx).compile()
+                         for b, fn in self.compact_fns.items()}
 
     def _start_d2h(self, tree) -> None:
         with self.tracer.span("cache_d2h_issue"):
@@ -376,62 +458,78 @@ class CohortCacheStream:
                 if isinstance(leaf, jax.Array):
                     leaf.copy_to_host_async()
         self.stats.d2h_async += 1
-        with self.tracer.span("cache_count_bytes"):
-            self.stats.d2h_bytes += _tree_bytes(tree)
+        self.stats.d2h_bytes += _tree_bytes(tree)
 
     def _read(self, tree):
-        """Blocking read of handles whose copy was pre-issued."""
+        """Blocking read of handles whose copy was issued beforehand."""
         self.stats.pre_issued_reads += 1
         with self.tracer.span("cache_read"):
             return jax.tree.map(np.asarray, tree)
 
     def fetch(self, idx, rnd: int):
-        """(X, ...) device block of the cohort's cache rows (async put)."""
+        """(X, ...) device block of the cohort's cache rows: the stored
+        rows cross the link, the expansion fills in the zeros."""
         self._start_d2h(idx)           # overlap with draining the pending
         self.drain(rnd)
         idx_np = self._read(idx)
+        self._size_for(idx_np.shape[0])
         with self.tracer.span("cache_gather"):
-            block = self._gather(idx_np)
-        sh = self._sharding(block)
+            rows, pos = self._gather(idx_np)
         with self.tracer.span("cache_put"):
-            put = jax.device_put(block) if sh is None \
-                else jax.device_put(block, sh)
+            put = jax.device_put((rows, pos), self._put_shardings)
         self.stats.h2d_async += 1
-        self.stats.h2d_bytes += _tree_bytes(block)
-        return put
+        self.stats.h2d_bytes += _tree_bytes((rows, pos))
+        self.stats.h2d_rows += pos.shape[0]
+        with self.tracer.span("cache_expand"):
+            return self._expand[pos.shape[0]](*put)
 
     def _gather(self, idx: np.ndarray):
-        """``store.gather(idx)`` into the next staging block."""
-        x = idx.shape[0]
-        if not self._blocks or self._blocks[0][0].shape[0] != x:
-            self._blocks = [self.store._new_block(x) for _ in range(2)]
-            self._hits = [np.empty(0, int)] * 2
+        """The stored rows of ``idx``, in the leading rows of the next
+        staging block, and their (bucket,) positions (padding: X)."""
+        slots = self.store._slots_of(idx)
+        hit = np.flatnonzero(slots >= 0)
+        h = _bucket(self._buckets, hit.size)
         self._blocks.reverse()
-        self._hits.reverse()
-        leaves = self._blocks[0]
-        for leaf in leaves:
-            leaf[self._hits[0]] = 0
-        self._hits[0] = self.store._copy_rows(idx, leaves)
-        self.stats.rows_hit += int(self._hits[0].size)
-        return jax.tree.unflatten(self.store._treedef, leaves)
+        leaves = [leaf[:h] for leaf in self._blocks[0]]
+        self.store._copy_rows(slots[hit], leaves, range(hit.size))
+        pos = np.full(h, idx.shape[0], np.int32)
+        pos[:hit.size] = hit
+        self.stats.rows_hit += int(hit.size)
+        return jax.tree.unflatten(self.store._treedef, leaves), pos
 
     def stage(self, idx, write, clear, block, stamps) -> None:
-        """Park one round's cache write-back; copies start now."""
+        """Park one round's cache write-back; the (X,) copies start now
+        and nothing is read."""
         self.drain()                   # at most one round in flight
-        payload = (idx, write, clear, stamps, block)
-        self._start_d2h(payload)
-        self._pending = payload
+        self._start_d2h((idx, write, clear, stamps))
+        self._pending = (idx, write, clear, stamps, block)
 
     def drain(self, rnd: Optional[int] = None) -> None:
-        """Apply the parked write-back (blocks on pre-issued copies)."""
+        """Apply the parked write-back: read its (X,) arrays, copy back
+        the compacted written rows and apply them."""
         if self._pending is None:
             return
         with self.tracer.span("cache_drain"):
-            idx, write, clear, stamps, block = self._read(self._pending)
+            idx_d, write_d, clear, stamps, block = self._pending
             self._pending = None
+            idx, write, clear, stamps = self._read(
+                (idx_d, write_d, clear, stamps))
+            self._size_for(idx.shape[0])
+            n = self.store.num_clients
+            live = np.flatnonzero(write.astype(bool) & (idx >= 0)
+                                  & (idx < n))
+            rows = None
+            if live.size:
+                k = _bucket(self._buckets, live.size)
+                with self.tracer.span("cache_compact"):
+                    rows = self._compact[k](block, write_d, idx_d)
+                self._start_d2h(rows)
+                self.stats.d2h_rows += k
+                rows = self._read(rows)
+            del block
             with self.tracer.span("cache_apply"):
-                self.store.apply(idx, write, clear, stamps, block,
-                                 0 if rnd is None else int(rnd))
+                self.store.apply(idx, write, clear, stamps, rows,
+                                 0 if rnd is None else int(rnd), rows=live)
 
     def flush(self, rnd: Optional[int] = None) -> None:
         self.drain(rnd)

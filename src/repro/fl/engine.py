@@ -1745,6 +1745,11 @@ class FleetEngine:
                         self._cache_stream.stage(idx, write_x,
                                                  _received_x, cache_p,
                                                  stamp_x)
+                # the round's (X, D) blocks are dead once dispatched (the
+                # stream holds the staged one until its drain): drop them,
+                # so that they are not live under the next round's fetch
+                # and trainer
+                del cache_x, final, cache_p
                 report = RoundReport(received=received, fail=fail_n,
                                      losses=losses_n, durations=times_n,
                                      duration=t_cut, rnd=rnd)

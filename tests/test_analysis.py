@@ -187,15 +187,15 @@ def test_transfer_ceiling_is_zero_without_offload_or_cache():
     assert transfer_ceiling(_fake_engine(None), True) == zeros
     assert transfer_ceiling(_fake_engine(object()), False) == zeros
     assert transfer_ceiling(_fake_engine(object()), True) == {
-        "d2h_async": 2, "h2d_async": 1,
-        "pre_issued_reads": 2, "sync_copies": 0}
+        "d2h_async": 3, "h2d_async": 1,
+        "pre_issued_reads": 3, "sync_copies": 0}
 
 
 def test_check_transfer_stats_flags_sync_copy_and_excess():
-    eng = _fake_engine(object(), d2h_async=6, h2d_async=3,
-                       pre_issued_reads=6, sync_copies=0)
+    eng = _fake_engine(object(), d2h_async=9, h2d_async=3,
+                       pre_issued_reads=9, sync_copies=0)
     assert check_transfer_stats(eng, rounds=3, uses_cache=True) == []
-    eng = _fake_engine(object(), d2h_async=7, sync_copies=1)
+    eng = _fake_engine(object(), d2h_async=10, sync_copies=1)
     bad = check_transfer_stats(eng, rounds=3, uses_cache=True)
     keys = {f.message.split("=")[0] for f in bad}
     assert keys == {"d2h_async", "sync_copies"}

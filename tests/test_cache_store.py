@@ -236,11 +236,10 @@ def test_store_clear_keeps_capacity_and_reuses_slots():
 
 
 @pytest.mark.parametrize("x", [1, 7, 64])
-def test_store_reads_cohort_minor_blocks(x, monkeypatch):
-    """A block that stores its cohort axis innermost (the TPU's layout
-    for the trainer's cache block) is written exactly like the same
-    values stored row by row, whatever the tile size."""
-    monkeypatch.setattr(CS, "_TILE_BYTES", 64)
+def test_store_reads_cohort_minor_blocks(x):
+    """A block that stores its cohort axis innermost (the TPU's default
+    layout for the trainer's cache block) is written exactly like the
+    same values stored row by row."""
     rng = np.random.default_rng(x)
     template = {"w": np.zeros((9, 5), np.float32),
                 "b": np.zeros((5,), np.float32),
@@ -464,47 +463,244 @@ def test_rerun_allocates_no_host_memory(data):
     assert cap1 >= engine.cache_store.nbytes
 
 
+def _record_stream(engine):
+    """Record, per fetch, the rows the stream's gather hit and, per
+    drain, the rows the store wrote."""
+    stream, stats = engine._cache_stream, engine.transfer_stats
+    rec = {"hits": [], "written": []}
+    gather, apply = stream._gather, stream.store.apply
+
+    def counted(fn, key, counter):
+        def call(*a, **k):
+            before = getattr(stats, counter)
+            out = fn(*a, **k)
+            rec[key].append(getattr(stats, counter) - before)
+            return out
+        return call
+
+    stream._gather = counted(gather, "hits", "rows_hit")
+    stream.store.apply = counted(apply, "written", "rows_written")
+    return rec
+
+
 # ---------------------------------------------------------------------------
 # Streaming contract: async only, O(1) per round, O(X) bytes
 # ---------------------------------------------------------------------------
 
 def test_stream_never_blocks_a_round(data):
     """The protocol's invariant: zero synchronous copies; every blocking
-    read is on a handle whose device-to-host copy was issued a full
-    dispatch earlier; one fetch + one write-back stage per round."""
+    read is on a handle whose device-to-host copy was issued before it,
+    and each copy is read once; one fetch + one write-back stage per
+    round."""
     fl = dataclasses.replace(FL, cache_offload="host")
     engine = FleetEngine(data, SIM, fl)
     engine.run("flude", diagnostics=False)          # compile + place
     engine.transfer_stats.reset()
+    rec = _record_stream(engine)
     engine.run("flude", rounds=3, diagnostics=False)
     s = engine.transfer_stats.snapshot()
     assert s["sync_copies"] == 0
-    # per round: one d2h dispatch for the fetch's idx + one for the
-    # staged write-back; one h2d for the fetched block
-    assert s["d2h_async"] == 2 * 3
+    # per round: one d2h dispatch for the fetch's idx, one for the
+    # staged (X,) arrays and one for the compacted rows of a round that
+    # wrote any; one h2d for the hit rows and their positions
+    compactions = sum(w > 0 for w in rec["written"])
+    assert s["d2h_async"] == 2 * 3 + compactions
     assert s["h2d_async"] == 3
-    assert s["pre_issued_reads"] == 2 * 3
+    assert s["pre_issued_reads"] == s["d2h_async"]
 
 
 def test_stream_transfers_round_count_independent(data):
     """Per-round transfer work is constant: counts scale linearly in
-    rounds with zero fixed-point drift, and bytes scale with X·D, not
-    N·D."""
-    fl = dataclasses.replace(FL, cache_offload="host")
+    rounds with zero fixed-point drift, and the rows that cross are the
+    live ones, bucket-padded: below the (X, ...) block whenever a round
+    hits fewer than X rows, and never N·D."""
+    x = N
+    fl = dataclasses.replace(FL, cache_offload="host", cohort_size=x)
     engine = FleetEngine(data, SIM, fl)
     engine.run("flude", diagnostics=False)
+    row = engine.cache_store.row_bytes
+    buckets = CS.row_buckets(x)
+    rec = _record_stream(engine)
     per_run = []
     for rounds in (1, 3):
         engine.transfer_stats.reset()
+        rec["hits"].clear()
+        rec["written"].clear()
         engine.run("flude", rounds=rounds, diagnostics=False)
-        per_run.append(engine.transfer_stats.snapshot())
-    assert per_run[0]["d2h_async"] * 3 == per_run[1]["d2h_async"]
-    assert per_run[0]["h2d_async"] * 3 == per_run[1]["h2d_async"]
-    # every h2d payload is one (X, ...) block (+ negligible (X,) masks)
-    x, n = FL.cohort_size, N
-    block_bytes = x * engine.cache_store.row_bytes
-    assert per_run[1]["h2d_bytes"] == 3 * block_bytes
-    assert per_run[1]["h2d_bytes"] < 3 * n * engine.cache_store.row_bytes
+        s = engine.transfer_stats.snapshot()
+        compactions = sum(w > 0 for w in rec["written"])
+        assert s["d2h_async"] - compactions == 2 * rounds
+        assert s["h2d_async"] == rounds
+        # exactly the padded live rows cross, plus the (bucket,) int32
+        # positions up and the (X,) arrays down
+        puts = [CS._bucket(buckets, h) for h in rec["hits"]]
+        assert s["h2d_bytes"] == sum(puts) * (row + 4)
+        assert all(p * (row + 4) < x * row
+                   for p, h in zip(puts, rec["hits"]) if h < x)
+        assert s["h2d_bytes"] < rounds * x * row
+        small = s["d2h_bytes"] - s["d2h_rows"] * row
+        per_run.append(small / rounds)
+        assert 0 < small <= rounds * x * 16
+    assert per_run[0] == per_run[1]
+
+
+class _Opaque:
+    """A device handle stand-in: a shape and a dtype, and no value."""
+
+    def __init__(self, shape, dtype):
+        self.shape, self.dtype = shape, np.dtype(dtype)
+
+    def __array__(self, *args, **kwargs):
+        raise AssertionError("stage() read a leaf")
+
+
+def test_stage_reads_no_leaf():
+    """``stage`` only parks handles and starts copies: it counts bytes
+    from shapes and reads no leaf, so it never waits on the device."""
+    x = 6
+    template = _template()
+    store = CS.HostCacheStore(template, num_clients=10)
+    stream = CS.CohortCacheStream(store)
+    block = {k: _Opaque((x,) + v.shape, v.dtype)
+             for k, v in template.items()}
+    stream.stage(_Opaque((x,), np.int32), _Opaque((x,), bool),
+                 _Opaque((x,), bool), block, _Opaque((x,), np.int32))
+    s = store.stats.snapshot()
+    assert s["d2h_async"] == 1 and s["pre_issued_reads"] == 0
+    assert s["d2h_bytes"] == x * (4 + 1 + 1 + 4)
+    assert s["d2h_rows"] == 0 and s["sync_copies"] == 0
+    stream.reset()
+    assert stream._pending is None
+
+
+def _edge_rounds(x, step):
+    """(ids, write) per round, crossing the bucket edges: no hit and
+    every row written, then every row a hit and exactly one bucket
+    written, then one hit and one row written over new ids."""
+    first = np.arange(x)
+    one = np.zeros(x, bool)
+    one[:step] = True
+    later = np.arange(x, 2 * x)
+    single = np.zeros(x, bool)
+    single[0] = True
+    return [(first, np.ones(x, bool)), (first, one),
+            (np.concatenate([first[:1], later[1:]]), single),
+            (later, np.zeros(x, bool))]
+
+
+@pytest.mark.parametrize("x", [8, 24, 40])
+def test_stream_bit_identical_across_bucket_edges(x):
+    """Rounds with no hit, with every row written and with exactly one
+    bucket of writes leave the store bit-identical to a store fed the
+    whole (X, ...) blocks, every fetched block equals ``gather``, and
+    ``h2d_rows``/``d2h_rows`` are the bucket-rounded hits and writes."""
+    rng = np.random.default_rng(x)
+    n = 2 * x
+    template = _template()
+    store = CS.HostCacheStore(template, n)
+    ref = CS.HostCacheStore(template, n)
+    stream = CS.CohortCacheStream(store)
+    buckets = CS.row_buckets(x)
+    want_rows = {"h2d_rows": 0, "d2h_rows": 0}
+    for rnd, (idx, write) in enumerate(_edge_rounds(x, buckets[0])):
+        hits = sum(ref.stamp_of(c) is not None for c in idx)
+        put = stream.fetch(idx, rnd)
+        want = ref.gather(idx)
+        for k in template:
+            np.testing.assert_array_equal(np.asarray(put[k]), want[k])
+        clear = ~write & (rng.random(x) < 0.3)
+        blk = _block(rng, template, x)
+        stream.stage(idx, write, clear, blk, np.full(x, rnd))
+        ref.apply(idx, write, clear, np.full(x, rnd), blk, rnd)
+        want_rows["h2d_rows"] += CS._bucket(buckets, hits)
+        want_rows["d2h_rows"] += CS._bucket(buckets, int(write.sum()))
+        stream.drain(rnd)
+        assert store.ids() == ref.ids()
+        probe = np.arange(n + 1)
+        for k in template:
+            np.testing.assert_array_equal(store.gather(probe)[k],
+                                          ref.gather(probe)[k])
+        assert [store.stamp_of(c) for c in probe] == \
+            [ref.stamp_of(c) for c in probe]
+    got = store.stats.snapshot()
+    assert {k: got[k] for k in want_rows} == want_rows
+    assert got["rows_hit"] > 0
+
+
+def test_row_buckets_from_cohort_size():
+    """Multiples of max(8, ceil(X/16)) rows up to X — at most 16 sizes
+    — and, on a mesh, multiples of its device count."""
+    assert CS.row_buckets(8) == [8]
+    assert CS.row_buckets(12) == [8, 12]
+    assert CS.row_buckets(16) == [8, 16]
+    assert CS.row_buckets(512) == list(range(32, 513, 32))
+    assert len(CS.row_buckets(1000)) == 16
+    assert CS.row_buckets(1000)[-1] == 1000
+    assert CS.row_buckets(48, devices=16) == [16, 32, 48]
+    for x in (1, 7, 100, 4096):
+        assert 1 <= len(CS.row_buckets(x)) <= 16
+    assert CS._bucket([8, 16], 0) == 0
+    assert CS._bucket([8, 16], 9) == 16
+
+
+class _Compiles:
+    """Counts JAX traces and backend compiles while open."""
+    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.count = 0
+
+    def _on(self, event, duration, **kw):
+        if event in self.EVENTS:
+            self.count += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+
+def test_second_run_compiles_nothing(data):
+    """Every bucket's expansion and compaction is compiled in the first
+    run: a second run on one engine, whose hits and writes differ,
+    compiles and traces nothing."""
+    fl = dataclasses.replace(FL, cache_offload="host", cohort_size=N)
+    engine = FleetEngine(data, dataclasses.replace(SIM, rounds=2), fl)
+    engine.run("flude", diagnostics=False)
+    stream = engine._cache_stream
+    assert sorted(stream._expand) == [0] + CS.row_buckets(N)
+    assert sorted(stream._compact) == CS.row_buckets(N)
+    with _Compiles() as compiles:
+        engine.run("flude", rounds=5, diagnostics=False)
+    assert compiles.count == 0
+
+
+def test_stream_rows_are_bucket_rounded_live_rows(data):
+    """Over an engine run, ``h2d_rows`` is each fetch's hits and
+    ``d2h_rows`` each drain's writes, rounded up to a bucket, and the
+    History, a first round with no hit included, is the resident
+    path's bit for bit."""
+    sim = dataclasses.replace(SIM, rounds=6)
+    resident = dataclasses.replace(FL, cohort_size=24)
+    fl = dataclasses.replace(resident, cache_offload="host")
+    engine = FleetEngine(data, sim, fl)
+    rec = _record_stream(engine)
+    hist = engine.run("flude", diagnostics=False)
+    _assert_hist_equal(FleetEngine(data, sim, resident).run(
+        "flude", diagnostics=False), hist)
+    assert rec["hits"][0] == 0
+    s = engine.transfer_stats.snapshot()
+    buckets = CS.row_buckets(24)
+    assert s["rows_hit"] > 0 and s["rows_written"] > 0
+    assert s["h2d_rows"] == sum(CS._bucket(buckets, h)
+                                for h in rec["hits"])
+    assert s["d2h_rows"] == sum(CS._bucket(buckets, w)
+                                for w in rec["written"])
+    assert s["rows_hit"] <= s["h2d_rows"] < 6 * 24
+    assert s["rows_written"] <= s["d2h_rows"]
 
 
 def test_no_stream_transfers_without_cache(data):

@@ -511,13 +511,15 @@ def test_offload_run_nests_cache_stream_spans():
     parents = {}
     for name, _ts, _dur, _args, parent, _self in tel.tracer.events:
         parents.setdefault(name, set()).add(parent)
-    want = {"cache_d2h_issue": {"cache_fetch", "cache_stage"},
-            "cache_count_bytes": {"cache_fetch", "cache_stage"},
+    want = {"cache_d2h_issue": {"cache_fetch", "cache_stage",
+                                "cache_drain"},
             "cache_drain": {"cache_fetch", "cache_flush"},
             "cache_read": {"cache_fetch", "cache_drain"},
+            "cache_compact": {"cache_drain"},
             "cache_apply": {"cache_drain"},
             "cache_gather": {"cache_fetch"},
             "cache_put": {"cache_fetch"},
+            "cache_expand": {"cache_fetch"},
             "cache_fetch": {"rounds"}, "cache_stage": {"rounds"},
             "cache_flush": {"rounds"}, "cohort_index": {"rounds"}}
     for name, ps in want.items():
@@ -525,8 +527,10 @@ def test_offload_run_nests_cache_stream_spans():
     s = tel.tracer.summary()
     n = len(h.acc)
     assert s["cache_gather"]["count"] == s["cache_put"]["count"] == n
+    assert s["cache_expand"]["count"] == n
     # one drain a round after the first, and the run-end flush's
     assert s["cache_drain"]["count"] == s["cache_apply"]["count"] == n
+    assert 0 < s["cache_compact"]["count"] <= n
     assert s["cache_fetch"]["self_s"] <= s["cache_fetch"]["total_s"]
 
 
@@ -547,8 +551,8 @@ def test_device_round_path_traces_into_the_profiler(tmp_path):
     assert {"fl.rounds", "fl.dynamics_step", "fl.plan", "fl.cohort_index",
             "fl.cache_fetch", "fl.cache_read", "fl.cache_put",
             "fl.trainer", "fl.round_cut", "fl.server_step",
-            "fl.cache_stage", "fl.cache_d2h_issue",
-            "fl.cache_count_bytes", "fl.ledger_resolve", "fl.eval",
+            "fl.cache_stage", "fl.cache_d2h_issue", "fl.cache_compact",
+            "fl.cache_expand", "fl.ledger_resolve", "fl.eval",
             "fl.cache_flush"} <= set(names)
 
 
@@ -667,8 +671,9 @@ def test_engine_without_offload_has_zero_transfer_stats():
     e.run("flude", diagnostics=False)
     assert e.transfer_stats.snapshot() == {
         "h2d_async": 0, "d2h_async": 0, "h2d_bytes": 0, "d2h_bytes": 0,
-        "pre_issued_reads": 0, "sync_copies": 0, "rows_written": 0,
-        "rows_cleared": 0, "rows_hit": 0, "host_grows": 0}
+        "h2d_rows": 0, "d2h_rows": 0, "pre_issued_reads": 0,
+        "sync_copies": 0, "rows_written": 0, "rows_cleared": 0,
+        "rows_hit": 0, "host_grows": 0}
 
 
 # ---------------------------------------------------------------------------
