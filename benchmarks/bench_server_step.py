@@ -95,7 +95,7 @@ def run():
         step = core.make_server_round_step(g, local_steps=LOCAL_STEPS,
                                            agg_impl="xla")
         cached_steps = jnp.full((C,), LOCAL_STEPS, jnp.int32)
-        args = (g, caches, final, final, cached_steps,
+        args = (g, caches, final, final, cached_steps, None,
                 jnp.asarray(selected), jnp.asarray(fail),
                 jnp.asarray(received), jnp.asarray(resume), n_samples,
                 jnp.ones((C,), jnp.float32), 3)

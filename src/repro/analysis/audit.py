@@ -1,11 +1,11 @@
 """Invariant auditor: static verification of the engine's round path.
 
-The auditor replays the construction of one engine round — the same
-argument plumbing as ``FleetEngine._device_rounds``, on a live engine —
-but instead of just executing the jitted dispatches it *lowers and
-compiles* each one (trainer, round cut, metrics, server step, flude
-plan/update, cohort index, cache expiry, the cache stream's expansion
-and compaction, eval) and statically checks
+The auditor runs one engine round (``FleetEngine._device_round``, on a
+live engine) through a dispatch hook that records every jitted
+dispatch, then *lowers and compiles* each one (dynamics step, cache
+expiry, cohort index, trainer, round cut, metrics, server step, eval,
+plus flude's plan/update jits and the cache stream's expansion and
+compaction) and statically checks
 the post-SPMD HLO against the round-path contracts
 (:mod:`repro.analysis.hlo_checks`):
 
@@ -27,9 +27,8 @@ or audit a live engine in tests / notebooks::
     report = audit_engine(engine, "flude")
     report.raise_on_findings()
 
-Lowering traces but executes nothing; the replay itself runs only the
-cheap setup dispatches (dynamics draw, trainer, cut) on a toy fleet, so
-a full matrix audit is seconds, not minutes.
+Lowering traces but executes nothing; the recorded round itself runs
+on a toy fleet, so a full matrix audit is seconds, not minutes.
 """
 from __future__ import annotations
 
@@ -127,177 +126,97 @@ def check_transfer_stats(engine, rounds: int, uses_cache: bool,
 
 
 # ---------------------------------------------------------------------------
-# One-round replay: collect every jitted dispatch with live arguments
+# One recorded round: every jitted dispatch with its abstract arguments
 # ---------------------------------------------------------------------------
 
-def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
-    """Mirror one ``_device_rounds`` round, recording each jitted
-    dispatch with the exact arguments the engine would pass.  The cheap
-    upstream dispatches (dynamics draw, trainer, cut) are executed so
-    downstream ones get real, correctly-sharded operands; the expensive
-    or donating ones (server step, metrics, eval) are only recorded."""
+def _abstract(tree):
+    """Arrays -> ``ShapeDtypeStruct``s, committed ones with their
+    shardings (other leaves as they are): what lowering needs, and what
+    survives donation."""
     import jax
+
+    def one(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None,
+                weak_type=getattr(x, "weak_type", False))
+        return x
+
+    return jax.tree.map(one, tree)
+
+
+def _collect_dispatches(engine, policy, fleet) -> List[_Dispatch]:
+    """Run one engine round (``FleetEngine._device_round``) with a hook
+    that records each jitted dispatch — its arguments abstracted before
+    the call, so that lowering later survives donation — and then runs
+    it.  Added to those: what the round reaches only through the policy
+    (flude's plan jits) and through the cache stream (its expansion and
+    compaction programs)."""
+    import jax
+    import jax.numpy as jnp
     import numpy as np
 
-    from repro.fl.api import RoundObservation
+    from repro.obs import NULL_TRACER
 
-    uses_cache = policy.uses_cache
-    process, init_fn, step_fn, trainer = engine._dynamics_fns(fleet)
-    cache_every, ones_w, full_steps = engine._dyn_consts(fleet, uses_cache)
-    server_step = engine._server_step(uses_cache)
-    rule_state = engine._init_rule_state()
-    cut_fn = engine._round_cut(policy.waits_for_stragglers)
-    metrics_fn, m_keys = engine._metrics_fn(
-        "full", uses_cache,
-        rows_bound=None if engine.cohort is not None
-        else policy.selection_bound())
+    out: List[_Dispatch] = []
 
-    global_params = engine._template
-    caches = engine._fresh_caches(global_params)
-    n_samples = engine._n_samples
-    rnd = 0
-
-    dyn_base = jax.random.fold_in(jax.random.key(engine.sim_cfg.seed),
-                                  0x0F1EE7)
-    fstate = init_fn(jax.random.fold_in(dyn_base, 1 << 20))
-    step_key = jax.random.fold_in(dyn_base, rnd)
-    out: List[_Dispatch] = [
-        _Dispatch("dynamics_step", step_fn, (fstate, step_key))]
-    fstate, draw = step_fn(fstate, step_key)
-
-    if engine.offload == "discard" and uses_cache:
-        expire_fn = engine._expire_fn_jit()
-        out.append(_Dispatch("cache_expire", expire_fn, (caches, rnd)))
-        caches = expire_fn(caches, rnd)
+    def record(name, fn, args, donated_args=0, sharded=True):
+        out.append(_Dispatch(
+            name, fn, _abstract(args),
+            min_aliases=len(jax.tree.leaves(args[:donated_args])),
+            sharded=sharded))
+        return fn(*args)
 
     state = policy.init_state()
+    global_params = engine._template
+    if engine.donate:
+        # the server step donates the global model: keep the template
+        global_params = jax.tree.map(jnp.copy, global_params)
+    caches = engine._fresh_caches(global_params)
+    caches_spec = _abstract(caches)
+    run, carry = engine._device_run(policy, fleet, state, global_params,
+                                    caches, "full", NULL_TRACER)
     rng = jax.random.fold_in(jax.random.key(engine.sim_cfg.seed), 1)
+    report, _entry = engine._device_round(run, carry, 0, rng, True,
+                                          record)
+
     plan_jit = getattr(policy, "_plan_jit", None)
     if plan_jit is not None:
-        # flude: planning itself is a jitted round-path dispatch
+        # flude: planning, its fused Eq. 1 update + next plan, and the
+        # run-end flush are jitted dispatches of the policy's own
+        online = carry.draw.online
         out.append(_Dispatch(
             "flude_plan", plan_jit,
-            (state.core, caches, draw.online, rng, policy._hints)))
-    obs = RoundObservation(rnd, draw.online, caches, draw=draw)
-    state, plan = policy.plan(state, obs, rng)
-
-    sel_d = engine._from_plan(plan.selected)
-    dist_d = engine._from_plan(plan.distribute)
-    res_d = engine._from_plan(plan.resume)
-    base_steps = full_steps if plan.steps_override is None else \
-        engine._from_plan(plan.steps_override, np.int32)
-    extra_w = ones_w if plan.agg_weights is None else \
-        engine._from_plan(plan.agg_weights, np.float32)
-    extra = engine._step_extra(rule_state)
-    donated = 0 if not engine.donate else (
-        len(jax.tree.leaves(global_params)) + len(jax.tree.leaves(caches)))
-
-    ctx_common = dict(selected=sel_d, distribute=dist_d, resume=res_d,
-                      online=draw.online, progress=caches.progress,
-                      stamp=caches.round_stamp, rnd=rnd)
-    ctx_common["global"] = global_params
-    if rule_state is not None:
-        ctx_common["rule_state"] = rule_state
-    if engine.offload == "discard" and uses_cache:
-        ctx_common["stamp_pre_expire"] = caches.round_stamp
-
-    if engine.cohort is None:
-        t_args = (global_params, caches, draw, sel_d, dist_d, res_d,
-                  base_steps, cache_every, engine._frozen)
-        out.append(_Dispatch("trainer", trainer, t_args))
-        (final, cache_p, cached_steps, losses, _steps, fail, success,
-         times) = trainer(*t_args)
-        c_args = (times, plan.quorum, success, draw.online, dist_d, sel_d)
-        out.append(_Dispatch("round_cut", cut_fn, c_args))
-        _t, received, *_rest = cut_fn(*c_args)
-        ctx_common.update(received=received, fail=fail, losses=losses,
-                          times=times, rows=final, rows_mask=received)
-        out.append(_Dispatch(
-            "server_step", server_step,
-            (global_params, caches, final, cache_p, cached_steps, sel_d,
-             fail, received, res_d, n_samples, extra_w, rnd, *extra),
-            min_aliases=donated))
-    elif engine.offload is None:
-        t_args = (global_params, caches, draw, sel_d, dist_d, res_d,
-                  base_steps, cache_every, engine._frozen)
-        out.append(_Dispatch("trainer", trainer, t_args))
-        (final, cache_p, cached_steps, _lx, _sx, fail, success, times,
-         idx, _overflow, losses_n, fail_n, times_n) = trainer(*t_args)
-        c_args = (times, plan.quorum, success, idx, draw.online, dist_d,
-                  sel_d)
-        out.append(_Dispatch("round_cut", cut_fn, c_args))
-        _t, _rx, received, *_rest = cut_fn(*c_args)
-        received_x = _rx
-        ctx_common.update(received=received, fail=fail_n,
-                          losses=losses_n, times=times_n, rows=final,
-                          rows_mask=received_x)
-        out.append(_Dispatch(
-            "server_step", server_step,
-            (global_params, caches, final, cache_p, cached_steps, idx,
-             sel_d, fail, received_x, res_d, n_samples, extra_w, rnd,
-             *extra),
-            min_aliases=donated))
-    else:
-        idx_fn = engine._offload_idx_fn()
-        out.append(_Dispatch("cohort_index", idx_fn, (sel_d,)))
-        idx, _overflow = idx_fn(sel_d)
-        if uses_cache:
-            cache_x = engine._cache_stream.fetch(idx, rnd)
-        else:
-            cache_x = engine._zero_cohort_block()
-        t_args = (global_params, caches, cache_x, idx, draw, sel_d,
-                  dist_d, res_d, base_steps, cache_every, engine._frozen)
-        out.append(_Dispatch("trainer", trainer, t_args))
-        (final, cache_p, cached_steps, _lx, _sx, fail, success, times,
-         losses_n, fail_n, times_n) = trainer(*t_args)
-        c_args = (times, plan.quorum, success, idx, draw.online, dist_d,
-                  sel_d)
-        out.append(_Dispatch("round_cut", cut_fn, c_args))
-        _t, received_x, received, *_rest = cut_fn(*c_args)
-        ctx_common.update(received=received, fail=fail_n,
-                          losses=losses_n, times=times_n, rows=final,
-                          rows_mask=received_x)
-        out.append(_Dispatch(
-            "server_step", server_step,
-            (global_params, caches, final, cached_steps, idx, sel_d,
-             fail, received_x, res_d, n_samples, extra_w, rnd, *extra),
-            min_aliases=donated))
-        if uses_cache:
-            # the stream's own programs, at their smallest bucket: the
-            # expansion of the fetched rows and the compaction of the
-            # written ones (the round's receive mask stands in for the
-            # server step's write mask: same shape, dtype and placement)
-            stream = engine._cache_stream
-            store = stream.store
-            b = stream._buckets[0]
-            rows = store._treedef.unflatten(
-                [jax.ShapeDtypeStruct((b,) + shape, dtype)
-                 for shape, dtype in zip(store._shapes, store._dtypes)])
-            pos = jax.ShapeDtypeStruct((b,), np.int32)
-            out.append(_Dispatch("cache_expand", stream.expand_fn,
-                                 (rows, pos)))
-            out.append(_Dispatch("cache_compact", stream.compact_fns[b],
-                                 (cache_p, received_x, idx)))
-
-    if metrics_fn is not None:
-        ctx = {k: ctx_common[k] for k in m_keys}
-        out.append(_Dispatch("metrics", metrics_fn, (ctx,)))
-
-    if plan_jit is not None:
-        # flude's fused Eq. 1 update + next plan, and the run-end flush
+            (state.core, caches_spec, online, rng, policy._hints)))
+        last = _abstract(carry.state.last)
         out.append(_Dispatch(
             "flude_update_plan", policy._update_plan_jit,
-            (state.core, state.last, received, caches, draw.online, rng,
-             policy._hints)))
+            (carry.state.core, last, report.received, caches_spec, online,
+             rng, policy._hints)))
         out.append(_Dispatch(
             "flude_update", policy._update_jit,
-            (state.core, state.last, received)))
+            (carry.state.core, last, report.received)))
 
-    # eval reads replicated operands by design — exempt from contract 4
-    out.append(_Dispatch(
-        "eval_accuracy", engine._acc_fn,
-        (global_params, engine._frozen, engine._test_x, engine._test_y),
-        sharded=False))
+    stream = engine._cache_stream
+    if stream is not None and policy.uses_cache:
+        # the stream's own programs, at their smallest bucket: the
+        # expansion of the fetched rows and the compaction of the
+        # written ones (the round's receive mask stands in for the
+        # server step's write mask: same shape, dtype and placement)
+        step = next(d for d in out if d.name == "server_step")
+        cache_rows, idx, received_x = (step.args[3], step.args[5],
+                                       step.args[8])
+        store = stream.store
+        b = stream._buckets[0]
+        rows = store._treedef.unflatten(
+            [jax.ShapeDtypeStruct((b,) + shape, dtype)
+             for shape, dtype in zip(store._shapes, store._dtypes)])
+        pos = jax.ShapeDtypeStruct((b,), np.int32)
+        out.append(_Dispatch("cache_expand", stream.expand_fn,
+                             (rows, pos)))
+        out.append(_Dispatch("cache_compact", stream.compact_fns[b],
+                             (cache_rows, received_x, idx)))
     return out
 
 

@@ -9,7 +9,8 @@ from repro.core.caching import (ClientCaches, adaptive_cache_interval,
                                 clear_cache, expire_caches, gather_caches,
                                 has_cache, init_caches, reset_caches,
                                 resume_params, scatter_clear_cache,
-                                scatter_write_cache, staleness, write_cache)
+                                scatter_write_cache, staleness, take_rows,
+                                write_cache)
 from repro.core.cache_store import (CohortCacheStream, HostCacheStore,
                                     TransferStats)
 from repro.core.distribution import (DistributionPlan, DistributorState,

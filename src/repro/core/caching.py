@@ -83,20 +83,28 @@ def clear_cache(caches: ClientCaches, mask: jax.Array) -> ClientCaches:
 # the sentinel) and drop out-of-range writes — together a gather→update→
 # scatter round trip equals the full-fleet ``jnp.where`` update exactly.
 
+def take_rows(a, idx, fill):
+    """Rows ``idx`` of ``a`` along dim 0, sentinel rows reading ``fill``;
+    ``idx=None`` is the identity (every row, in order)."""
+    if idx is None:
+        return a
+    return jnp.take(a, idx, axis=0, mode="fill", fill_value=fill)
+
+
 def gather_caches(caches: ClientCaches, idx: jax.Array) -> ClientCaches:
     """Dense (X, ...) view of the cache slots at ``idx``.
 
     Sentinel (padding) rows read as empty: zero params, zero progress,
     round stamp -1 — the same values an untouched fresh slot holds, so
     downstream resume/staleness logic needs no special pad handling.
+    ``idx=None`` is the identity (every row, in order).
     """
-    def take(a, fill):
-        return jnp.take(a, idx, axis=0, mode="fill", fill_value=fill)
-
+    if idx is None:
+        return caches
     return ClientCaches(
-        jax.tree.map(lambda a: take(a, 0), caches.params),
-        take(caches.progress, 0.0),
-        take(caches.round_stamp, -1))
+        jax.tree.map(lambda a: take_rows(a, idx, 0), caches.params),
+        take_rows(caches.progress, idx, 0.0),
+        take_rows(caches.round_stamp, idx, -1))
 
 
 def scatter_write_cache(caches: ClientCaches, idx: jax.Array,
@@ -109,7 +117,10 @@ def scatter_write_cache(caches: ClientCaches, idx: jax.Array,
     so every unwritten (N,) slot keeps its existing buffer — equal to the
     full-fleet rolling ``jnp.where`` update when the full write mask is
     zero outside the cohort (which it is: writes require selection).
+    ``idx=None`` is :func:`write_cache` itself.
     """
+    if idx is None:
+        return write_cache(caches, mask, new_params, progress, rnd)
     n = caches.progress.shape[0]
     target = jnp.where(mask, idx, n)
 
@@ -128,7 +139,10 @@ def scatter_clear_cache(caches: ClientCaches, idx: jax.Array,
                         mask: jax.Array) -> ClientCaches:
     """:func:`clear_cache` restricted to the cohort rows ``idx`` (params
     stay, metadata resets — identical to the full-fleet clear for masks
-    that are zero outside the cohort)."""
+    that are zero outside the cohort).  ``idx=None`` is
+    :func:`clear_cache` itself."""
+    if idx is None:
+        return clear_cache(caches, mask)
     n = caches.progress.shape[0]
     target = jnp.where(mask, idx, n)
     return ClientCaches(

@@ -127,9 +127,7 @@ def make_server_round_step(template_params, *, local_steps: int,
                            staleness_discount: float = 1.0,
                            uses_cache: bool = True,
                            block_c: int = 8, block_d: int = 2048,
-                           mesh=None, donate: bool = False,
-                           cohort_size: Optional[int] = None,
-                           cache_offload: Optional[str] = None):
+                           mesh=None, donate: bool = False):
     """Build the fused per-round server step (one jit, zero host syncs).
 
     The returned callable runs everything the server does between "uploads
@@ -141,6 +139,27 @@ def make_server_round_step(template_params, *, local_steps: int,
     template_params: the *unstacked* global model pytree — fixes the packed
     (C, D) layout once.  ``uses_cache=False`` policies get an identity
     cache path (compiled out).
+
+    The step runs over the round's rows, which its inputs name: ``idx``
+    is the (X,) cohort index (sentinel-padded) or None, the identity —
+    all N rows in order, with no gather or scatter.  Given an index, the
+    (N,) plan masks, cache metadata and weights are gathered into (X,)
+    blocks, aggregation packs and reduces an (X, D) buffer, and the
+    cache writes scatter back into the (N,) fleet state (predicated
+    ``.at[].set(mode="drop")`` writes: sentinel and masked-off rows touch
+    nothing).  Weighted aggregation over the gathered rows is the same
+    sequential fp32 reduction over the same nonzero-weight terms, so a
+    single-device cohort round is bit-identical to the full scan; under
+    a client mesh cohort members regroup across shards and the psum
+    reassociates (integer trajectory exact, accuracies to float
+    tolerance).
+
+    Where the cache rows live is read from ``caches`` too: with resident
+    params the step writes ``cache_rows`` (the trainer's cache block) into
+    the pytree; with an empty params pytree (host-offloaded caches, the
+    rows in ``repro.core.cache_store``) only the metadata changes, and the
+    engine stages ``cache_rows`` to the host store with the returned
+    ``write``/``base_round``.
 
     ``mesh``: optional fleet mesh with a ``clients`` axis — the packed
     (C, D) buffer aggregates as per-shard partial sums + psum and the
@@ -154,46 +173,24 @@ def make_server_round_step(template_params, *, local_steps: int,
     jax's unusable-donation warning.  Donated host handles (the caller's
     previous global/caches references) are invalidated by the call.
 
-    ``cohort_size``: static X switches to the compact-cohort variant: the
-    stacked trainer outputs arrive as dense (X, ...) blocks plus the (X,)
-    cohort index, aggregation packs and reduces an (X, D) buffer instead
-    of (N, D), and the C3 cache bookkeeping scatters back into the (N,)
-    fleet state (predicated ``.at[].set(mode="drop")`` writes — sentinel
-    and masked-off rows touch nothing).  Weighted aggregation over the
-    gathered rows is the same sequential fp32 reduction over the same
-    nonzero-weight terms, so a single-device compact round is
-    bit-identical to the full scan; under a client mesh cohort members
-    regroup across shards and the psum reassociates (integer trajectory
-    exact, accuracies to float tolerance — same contract as the sharded
-    full scan vs single device).
-
     ``agg_rule`` / ``agg_rule_params``: the robust-aggregation axis
     (``repro.core.agg_rules``), orthogonal to ``agg_impl``.  The default
     ``"mean"`` keeps the historical direct ``fed_aggregate_packed`` call
     — the traced jaxpr (and therefore the trajectory) is bit-identical
     to the pre-registry step.  Non-mean rules pack the stacked trainer
     outputs once and run the rule's reduction on the (C, D) buffer; a
-    *stateful* rule ("trust") appends one (N,) state input and output to
-    the jitted signature — the engine threads it like the caches, so
-    rounds still sync nothing.
+    *stateful* rule ("trust") takes one (N,) state input and returns it
+    updated — the engine threads it like the caches, so rounds still
+    sync nothing.
 
     ``adversary_scale``: when set, the returned step additionally takes
     an (N,) malicious mask (appended after ``rnd``, before any rule
     state) and transforms the marked clients' uploads inside the jit:
     ``u' = g + adversary_scale * (u - g)`` — the model-poisoning channel
     of ``repro.fleet.adversary``.  Benign runs compile the attack out.
-
-    ``cache_offload`` (cohort variant only): the host-offloaded cache
-    path.  ``caches`` then carries *metadata only* (params is an empty
-    pytree — the (N, D) slots live in ``repro.core.cache_store``), the
-    ``cache_params`` argument is dropped (the engine streams the
-    trainer's (X, ...) cache block to host itself) and the step returns
-    ``(new_global, new_caches_meta, write, base_round[, rule_state])``
-    — ``write``/``base_round`` are the (X,) cache-write mask and round
-    stamps the engine's write-back stages to the host store.  The
-    weight math, aggregation and metadata scatters are the exact ops of
-    the resident cohort step, so trajectories are bit-identical.
     """
+    from repro.sharding import partitioning as SP
+
     layout = AGG.pack_layout(template_params)
     donate_argnums = (0, 1) if donate else ()
     rule = None
@@ -252,213 +249,85 @@ def make_server_round_step(template_params, *, local_steps: int,
         rule_state = extra[-1] if stateful else None
         return malicious, rule_state
 
-    if cache_offload is not None and cohort_size is None:
-        raise ValueError("cache_offload requires the cohort server-step "
-                         "variant (pass cohort_size)")
-
-    if cohort_size is not None and cache_offload is not None:
-        @functools.partial(jax.jit, donate_argnums=donate_argnums)
-        def server_round_step_cohort_offload(global_params,
-                                             caches: C.ClientCaches,
-                                             final_params, cached_steps,
-                                             idx, selected, fail,
-                                             received, resume, n_samples,
-                                             extra_weights, rnd, *extra):
-            """-> (new_global, new_caches_meta, write, base_round
-            [, new_rule_state]).
-
-            The host-offload twin of ``server_round_step_cohort``:
-            ``caches`` is the metadata-only ClientCaches (empty params
-            pytree), and instead of scattering the cohort's cache params
-            back into a resident (N, D) pytree the step returns the (X,)
-            write mask and base-round stamps — the engine stages the
-            trainer's cache block to the host store with them.  Every
-            weight / aggregation / metadata op is identical to the
-            resident cohort step.
-            """
-            from repro.sharding import partitioning as SP
-
-            malicious, rule_state = split_extra(extra)
-            rnd = jnp.asarray(rnd, jnp.int32)
-
-            def take(a, fill):
-                return jnp.take(a, idx, axis=0, mode="fill",
-                                fill_value=fill)
-
-            selected = take(selected, False)              # (X,)
-            resume = take(resume, False)
-            stamp = take(caches.round_stamp, -1)          # (X,)
-            base_stale = jnp.where(resume & (stamp >= 0),
-                                   jnp.maximum(rnd - stamp, 0),
-                                   0).astype(jnp.float32)
-            w = AGG.aggregation_weights(
-                received, n_samples=take(n_samples, 0.0),
-                staleness=base_stale,
-                staleness_discount=staleness_discount) \
-                * take(extra_weights, 0.0)
-            w = SP.cohort_constraint(w, mesh, cohort_size)
-            if has_adv:
-                mal_x = SP.cohort_constraint(take(malicious, False),
-                                             mesh, cohort_size)
-                final_params = poison(final_params, global_params, mal_x)
-            state_x = None
-            if stateful:
-                state_x = SP.cohort_constraint(take(rule_state, 0.0),
-                                               mesh, cohort_size)
-            new_global, state_x = aggregate(global_params, final_params,
-                                            w, state_x)
-            if stateful:
-                rule_state = rule_state.at[idx].set(state_x, mode="drop")
-                rule_state = SP.cohort_scatter_constraint(
-                    rule_state, mesh, rule_state.shape[0])
-            if uses_cache:
-                prior_steps = jnp.round(
-                    take(caches.progress, 0.0) * local_steps
-                ).astype(jnp.int32)
-                total_cached = jnp.where(resume, prior_steps, 0) \
-                    + cached_steps
-                write = selected & fail & (total_cached > 0)
-                base_round = jnp.where(resume & (stamp >= 0), stamp, rnd)
-                # metadata-only scatters: the params pytree is empty, so
-                # the same predicated writes the resident step runs
-                # touch only progress / round_stamp
-                caches = C.scatter_write_cache(
-                    caches, idx, write, caches.params,
-                    (total_cached / max(local_steps, 1)
-                     ).astype(jnp.float32), base_round)
-                caches = C.scatter_clear_cache(caches, idx, received)
-                caches = SP.cohort_scatter_constraint(
-                    caches, mesh, caches.progress.shape[0])
-            else:
-                write = jnp.zeros((cohort_size,), bool)
-                base_round = jnp.full((cohort_size,), -1, jnp.int32)
-            write, base_round = SP.cohort_constraint(
-                (write, base_round), mesh, cohort_size)
-            if stateful:
-                return new_global, caches, write, base_round, rule_state
-            return new_global, caches, write, base_round
-
-        return server_round_step_cohort_offload
-
-    if cohort_size is not None:
-        @functools.partial(jax.jit, donate_argnums=donate_argnums)
-        def server_round_step_cohort(global_params,
-                                     caches: C.ClientCaches,
-                                     final_params, cache_params,
-                                     cached_steps, idx, selected, fail,
-                                     received, resume, n_samples,
-                                     extra_weights, rnd, *extra):
-            """-> (new_global_params, new_caches[, new_rule_state]).
-
-            final_params / cache_params / cached_steps and the
-            ``fail``/``received`` masks are (X,)-leading cohort blocks
-            (trainer / round-cut outputs); ``idx`` is the (X,) cohort
-            index (sentinel-padded).  ``selected``/``resume`` arrive as
-            the (N,) plan masks the engine holds and are gathered here;
-            caches / n_samples / extra_weights stay (N,)-sized — the
-            only fleet-proportional state the step touches.  ``extra``
-            appends the (N,) malicious mask (adversary configured) and
-            the (N,) rule state (stateful rule) — both gathered here
-            and, for the state, scattered back.
-            """
-            from repro.sharding import partitioning as SP
-
-            malicious, rule_state = split_extra(extra)
-            rnd = jnp.asarray(rnd, jnp.int32)
-
-            def take(a, fill):
-                return jnp.take(a, idx, axis=0, mode="fill",
-                                fill_value=fill)
-
-            selected = take(selected, False)              # (X,)
-            resume = take(resume, False)
-            stamp = take(caches.round_stamp, -1)          # (X,)
-            base_stale = jnp.where(resume & (stamp >= 0),
-                                   jnp.maximum(rnd - stamp, 0),
-                                   0).astype(jnp.float32)
-            w = AGG.aggregation_weights(
-                received, n_samples=take(n_samples, 0.0),
-                staleness=base_stale,
-                staleness_discount=staleness_discount) \
-                * take(extra_weights, 0.0)
-            w = SP.cohort_constraint(w, mesh, cohort_size)
-            if has_adv:
-                mal_x = SP.cohort_constraint(take(malicious, False),
-                                             mesh, cohort_size)
-                final_params = poison(final_params, global_params, mal_x)
-            state_x = None
-            if stateful:
-                state_x = SP.cohort_constraint(take(rule_state, 0.0),
-                                               mesh, cohort_size)
-            new_global, state_x = aggregate(global_params, final_params,
-                                            w, state_x)
-            if stateful:
-                rule_state = rule_state.at[idx].set(state_x, mode="drop")
-                rule_state = SP.cohort_scatter_constraint(
-                    rule_state, mesh, rule_state.shape[0])
-            if uses_cache:
-                prior_steps = jnp.round(
-                    take(caches.progress, 0.0) * local_steps
-                ).astype(jnp.int32)
-                total_cached = jnp.where(resume, prior_steps, 0) \
-                    + cached_steps
-                write = selected & fail & (total_cached > 0)
-                base_round = jnp.where(resume & (stamp >= 0), stamp, rnd)
-                caches = C.scatter_write_cache(
-                    caches, idx, write, cache_params,
-                    (total_cached / max(local_steps, 1)
-                     ).astype(jnp.float32), base_round)
-                caches = C.scatter_clear_cache(caches, idx, received)
-                caches = SP.cohort_scatter_constraint(
-                    caches, mesh, caches.progress.shape[0])
-            if stateful:
-                return new_global, caches, rule_state
-            return new_global, caches
-
-        return server_round_step_cohort
-
     @functools.partial(jax.jit, donate_argnums=donate_argnums)
     def server_round_step(global_params, caches: C.ClientCaches,
-                          final_params, cache_params, cached_steps,
-                          selected, fail, received, resume,
-                          n_samples, extra_weights, rnd, *extra):
-        """-> (new_global_params, new_caches[, new_rule_state]).
+                          final_params, cache_rows, cached_steps, idx,
+                          selected, fail, received, resume, n_samples,
+                          extra_weights, rnd, *extra):
+        """-> (new_global, new_caches, write, base_round, new_rule_state).
 
-        final_params / cache_params: stacked (N, ...) trainer outputs.
-        selected/fail/received/resume: (N,) bool round masks.
-        extra_weights: (N,) policy weight multiplier (ones if unused).
-        rnd: scalar int32 — current round index.
-        extra: the (N,) malicious mask (adversary configured) then the
-        (N,) rule state (stateful rule) — see the factory docstring.
+        final_params / cache_rows / cached_steps and the ``fail``/
+        ``received`` masks are the round's row blocks (trainer / round-cut
+        outputs): (X, ...) under a cohort index ``idx``, (N, ...) when
+        ``idx`` is None.  ``selected``/``resume`` are the (N,) plan masks
+        and are gathered here; caches / n_samples / extra_weights stay
+        (N,)-sized.  ``extra_weights``: (N,) policy weight multiplier
+        (ones if unused).  ``rnd``: scalar int32, the current round.
+        ``extra`` appends the (N,) malicious mask (adversary configured)
+        and the (N,) rule state (stateful rule).
+
+        ``write``/``base_round`` are the rows' cache-write mask and round
+        stamps (what the engine stages to the host store under offload);
+        ``new_rule_state`` is None for stateless rules.
         """
         malicious, rule_state = split_extra(extra)
         rnd = jnp.asarray(rnd, jnp.int32)
-        stamp = caches.round_stamp
+        rows = received.shape[0]
+
+        def take(a, fill):
+            return C.take_rows(a, idx, fill)
+
+        def pin(tree, num):
+            if idx is None:
+                return tree
+            return SP.fleet_constraint(tree, mesh, num)
+
+        selected = take(selected, False)
+        resume = take(resume, False)
+        stamp = take(caches.round_stamp, -1)
         # staleness of the BASE model each update was trained from
         base_stale = jnp.where(resume & (stamp >= 0),
                                jnp.maximum(rnd - stamp, 0),
                                0).astype(jnp.float32)
         w = AGG.aggregation_weights(
-            received, n_samples=n_samples, staleness=base_stale,
-            staleness_discount=staleness_discount) * extra_weights
+            received, n_samples=take(n_samples, 0.0),
+            staleness=base_stale,
+            staleness_discount=staleness_discount) \
+            * take(extra_weights, 0.0)
+        w = pin(w, rows)
         if has_adv:
-            final_params = poison(final_params, global_params, malicious)
-        new_global, rule_state = aggregate(global_params, final_params,
-                                           w, rule_state)
+            final_params = poison(final_params, global_params,
+                                  pin(take(malicious, False), rows))
+        state_x = None
+        if stateful:
+            state_x = pin(take(rule_state, 0.0), rows)
+        new_global, state_x = aggregate(global_params, final_params, w,
+                                        state_x)
+        if stateful:
+            rule_state = state_x if idx is None else \
+                rule_state.at[idx].set(state_x, mode="drop")
+            rule_state = pin(rule_state, rule_state.shape[0])
         if uses_cache:
             prior_steps = jnp.round(
-                caches.progress * local_steps).astype(jnp.int32)
+                take(caches.progress, 0.0) * local_steps).astype(jnp.int32)
             total_cached = jnp.where(resume, prior_steps, 0) + cached_steps
             write = selected & fail & (total_cached > 0)
             base_round = jnp.where(resume & (stamp >= 0), stamp, rnd)
-            caches = C.write_cache(
-                caches, write, cache_params,
+            # offloaded caches carry no params: the same predicated
+            # writes then touch only progress / round_stamp
+            new_rows = cache_rows if jax.tree.leaves(caches.params) \
+                else caches.params
+            caches = C.scatter_write_cache(
+                caches, idx, write, new_rows,
                 (total_cached / max(local_steps, 1)).astype(jnp.float32),
                 base_round)
-            caches = C.clear_cache(caches, received)
-        if stateful:
-            return new_global, caches, rule_state
-        return new_global, caches
+            caches = C.scatter_clear_cache(caches, idx, received)
+            caches = pin(caches, caches.progress.shape[0])
+        else:
+            write = jnp.zeros((rows,), bool)
+            base_round = jnp.full((rows,), -1, jnp.int32)
+        write, base_round = pin((write, base_round), rows)
+        return new_global, caches, write, base_round, rule_state
 
     return server_round_step
 
@@ -489,9 +358,7 @@ def host_round_cut(times, quorum, round_deadline: float,
 
 
 def make_round_cut(num_clients: int, round_deadline: float,
-                   waits_for_stragglers: bool, mesh=None,
-                   scatter_num_clients: Optional[int] = None,
-                   with_counts: bool = False):
+                   waits_for_stragglers: bool, mesh=None):
     """Build the jitted device-resident round cut (lines 13–16).
 
     Semantically identical to :func:`host_round_cut` — and bit-identical
@@ -506,14 +373,20 @@ def make_round_cut(num_clients: int, round_deadline: float,
       bills the *float64* deadline, which float32 cannot always
       represent — e.g. ``round_deadline=100.3`` — so the cap is returned
       as a flag and the ledger substitutes the exact config value);
-    * ``received`` — the (N,) receive mask, pinned to the client-mesh
-      sharding when ``mesh`` is given.  Deadline-capped rounds compare
-      against the float32-*nearest* cast of the deadline — exactly what
-      the pre-pipelining loop's jitted ``times <= cut`` did with the
-      host's float64 cut, so depth-1 receive masks stay bit-identical;
+    * ``received`` — the receive mask over the rows of ``times``, pinned
+      to the client-mesh sharding when ``mesh`` is given.  Deadline-capped
+      rounds compare against the float32-*nearest* cast of the deadline —
+      exactly what the pre-pipelining loop's jitted ``times <= cut`` did
+      with the host's float64 cut, so depth-1 receive masks stay
+      bit-identical;
     * ``capped`` — bool device scalar: the round idle-waited (or closed
       at) the deadline rather than an arrival.  The flag itself is exact
       (``t > deadline`` decided via the largest float32 ≤ deadline).
+
+    A positive quorum counts at least one upload, however small: XLA
+    flushes float32 subnormals to zero, so positivity is read from the
+    quorum's bits, which are never flushed (the host's ``ceil`` gives 1
+    there too).
 
     Everything stays on device, so the engine can dispatch the server
     step — and further rounds — without draining the device queue.
@@ -521,25 +394,24 @@ def make_round_cut(num_clients: int, round_deadline: float,
     compiles the extra close-at-last-arrival branch in, the sync variant
     compiles it out.
 
-    ``scatter_num_clients``: compact-cohort variant.  ``num_clients`` is
-    then the static cohort size X — ``times``/``success`` arrive as (X,)
-    gathered blocks — and the returned callable additionally takes the
-    (X,) cohort index ``idx`` and returns ``(t_cut, received,
-    received_full, capped)`` where ``received_full`` is the (N,) receive
-    mask scattered back onto the fleet (sentinel rows dropped).  The cut
-    itself is exact: every finite finish time belongs to a selected
-    client, selected ⊆ cohort, so the order statistics over the X rows
-    equal those over the full N — bit-identical even under a mesh.
-
-    ``with_counts``: fuse the round's three History ledger reductions
-    into the cut dispatch.  The callable then takes three trailing (N,)
-    masks ``(online, distribute, selected)`` and appends the device
-    scalars ``(received_count, download_count, selected_count)`` to its
-    outputs (``download_count`` is ``(distribute & online).sum()`` —
-    ``FleetDraw.download_mask`` inlined).  This removes the separate
-    per-round ledger-counts dispatch: everything the deferred History
-    needs leaves the cut as O(1) replicated device scalars.
+    The engine's form passes two more inputs: the (X,) cohort index
+    ``idx`` the rows of ``times``/``success`` were gathered by (None: the
+    rows are the fleet's ``num_clients`` in order), then the (N,) masks
+    ``(online, distribute, selected)``.  It returns ``(t_cut, received,
+    received_full, capped, received_count, download_count,
+    selected_count)``: ``received_full`` is the (N,) receive mask
+    (scattered back onto the fleet through ``idx``, sentinel rows
+    dropped; ``received`` itself when ``idx`` is None), and the three
+    device scalars are the round's History ledger counts
+    (``download_count`` is ``(distribute & online).sum()``), fused into
+    the cut so everything the deferred History needs leaves it as O(1)
+    replicated scalars.  The cut over cohort rows is exact: every finite
+    finish time belongs to a selected client, selected ⊆ cohort, so the
+    order statistics over the X rows equal those over the full N —
+    bit-identical even under a mesh.
     """
+    from repro.sharding import partitioning as SP
+
     deadline = float(round_deadline)
     # nearest float32 (what the old received_fn's weak f64->f32 cast did)
     d_cmp = np.float32(deadline)
@@ -549,15 +421,19 @@ def make_round_cut(num_clients: int, round_deadline: float,
         d_flag = np.nextafter(d_flag, np.float32(-np.inf))
 
     def cut_core(times, quorum, success):
-        q = jnp.ceil(jnp.asarray(quorum, jnp.float32)).astype(jnp.int32)
+        rows = times.shape[0]
+        q = jnp.asarray(quorum, jnp.float32)
+        positive = jax.lax.bitcast_convert_type(q, jnp.int32) > 0
+        q = jnp.where(positive,
+                      jnp.maximum(jnp.ceil(q).astype(jnp.int32), 1), 0)
         order = jnp.sort(times)                   # inf sorts to the end
         finite_count = jnp.isfinite(times).sum()
-        t_quorum = order[jnp.clip(q - 1, 0, num_clients - 1)]
+        t_quorum = order[jnp.clip(q - 1, 0, rows - 1)]
         has_quorum = (finite_count >= q) & (q > 0)
         t_raw = jnp.where(has_quorum, t_quorum, jnp.inf)
         if not waits_for_stragglers:
             # async/semi-async designs close at the last arrival
-            t_last = order[jnp.clip(finite_count - 1, 0, num_clients - 1)]
+            t_last = order[jnp.clip(finite_count - 1, 0, rows - 1)]
             t_raw = jnp.where(~has_quorum & (finite_count > 0), t_last,
                               t_raw)
         capped = t_raw > d_flag
@@ -565,48 +441,25 @@ def make_round_cut(num_clients: int, round_deadline: float,
         received = success & (times <= t_cut)
         return t_cut, received, capped
 
-    def ledger_counts(received_rows, online, distribute, selected):
-        """The three (N,)→scalar History reductions, fused into the cut
-        (``received_rows`` may be the (X,) cohort block — sentinel rows
-        are never received, so its sum equals the fleet sum)."""
-        from repro.sharding import partitioning as SP
-        counts = (received_rows.sum(), (distribute & online).sum(),
-                  selected.sum())
-        return SP.replicated_constraint(counts, mesh)
-
-    if scatter_num_clients is not None:
-        @jax.jit
-        def round_cut_cohort(times, quorum, success, idx, *masks):
-            from repro.sharding import partitioning as SP
-            t_cut, received, capped = cut_core(times, quorum, success)
-            received_full = jnp.zeros((scatter_num_clients,), bool) \
-                .at[idx].set(received, mode="drop")
-            if mesh is not None:
-                received = SP.cohort_constraint(received, mesh,
-                                                num_clients)
-                received_full = SP.cohort_scatter_constraint(
-                    received_full, mesh, scatter_num_clients)
-                t_cut, capped = SP.replicated_constraint(
-                    (t_cut, capped), mesh)
-            if with_counts:
-                return (t_cut, received, received_full, capped) \
-                    + ledger_counts(received, *masks)
-            return t_cut, received, received_full, capped
-
-        return round_cut_cohort
-
     @jax.jit
-    def round_cut(times, quorum, success, *masks):
+    def round_cut(times, quorum, success, idx=None, *masks):
         t_cut, received, capped = cut_core(times, quorum, success)
-        if mesh is not None:
-            from repro.sharding import partitioning as SP
-            received = SP.fleet_constraint(received, mesh, num_clients)
-            t_cut, capped = SP.replicated_constraint((t_cut, capped),
-                                                     mesh)
-        if with_counts:
-            return (t_cut, received, capped) \
-                + ledger_counts(received, *masks)
-        return t_cut, received, capped
+        received = SP.fleet_constraint(received, mesh, times.shape[0])
+        t_cut, capped = SP.replicated_constraint((t_cut, capped), mesh)
+        if not masks:
+            return t_cut, received, capped
+        received_full = received
+        if idx is not None:
+            received_full = SP.cohort_scatter_constraint(
+                jnp.zeros((num_clients,), bool).at[idx].set(
+                    received, mode="drop"), mesh, num_clients)
+        online, distribute, selected = masks
+        # sentinel rows are never received, so the rows' sum is the
+        # fleet's
+        counts = SP.replicated_constraint(
+            (received.sum(), (distribute & online).sum(), selected.sum()),
+            mesh)
+        return (t_cut, received, received_full, capped) + counts
 
     return round_cut
 

@@ -46,8 +46,8 @@ def cohort_index(selected, cohort_size: int) -> jax.Array:
     of the selected set, padded to the static ``cohort_size`` with the
     out-of-range sentinel N (= ``selected.shape[0]``).
 
-    Traceable (fixed output shape), so the engine derives it *inside* the
-    jitted round body — no host sync.  Sentinel entries make every
+    Traceable (fixed output shape), so the engine derives it on device
+    in a small jit of its own — no host sync.  Sentinel entries make every
     ``mode="fill"`` gather read a benign default and every
     ``mode="drop"`` scatter skip the row, which is what keeps the compact
     (X, ...) round path bit-identical to the full scan.  When more than

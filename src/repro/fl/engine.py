@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, List, Optional, Union
+from typing import Any, Callable, List, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -54,20 +54,27 @@ from repro.sharding import partitioning as SP
 BIG = 1 << 20
 
 
+def _call(name, fn, args, **contract):
+    """The round's dispatch hook as the engine runs it: call ``fn``.
+    ``name`` and ``contract`` (``donated_args``: how many leading
+    arguments the call donates; ``sharded=False``: replicated operands by
+    design) are for hooks that record the dispatch, such as
+    ``repro.analysis.audit``."""
+    return fn(*args)
+
+
 # ---------------------------------------------------------------------------
 # Vectorized local trainer
 # ---------------------------------------------------------------------------
 
 def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
                  mesh=None, donate: bool = False, dynamics_features=None,
-                 cohort_size: Optional[int] = None,
-                 external_cache_params: bool = False,
                  model: Optional["FLM.FLModel"] = None):
-    """Build the jitted all-fleet local trainer.
+    """Build the jitted local trainer.
 
     ``model`` (a ``repro.fl.models.FLModel``, default the ``"mlp"``
-    classifier) supplies the per-client loss and gradient.  Every variant
-    takes the model's frozen leaves as its last argument ``frozen``
+    classifier) supplies the per-client loss and gradient.  Both variants
+    take the model's frozen leaves as its last argument ``frozen``
     (default: none): one copy the whole cohort shares, a jit argument
     (never closed over, so it is not compiled into the executable, and
     never donated).  Only the trainable leaves are trained, snapshotted
@@ -93,27 +100,12 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
     donated on this variant (the draw is also exposed to policies via
     ``RoundObservation`` and must stay live).
 
-    ``cohort_size``: static X (dynamics variant only) switches to the
-    compact-cohort round body: the cohort index is derived on device
-    from the plan's selection mask, the clients' data / caches / draw /
-    plan arrays are gathered into dense (X, ...) blocks, and the
-    vmap+scan runs over X rows instead of N — round FLOPs track the
-    cohort, not the fleet.  Returns the (X,) blocks the compact cut and
-    server step consume, plus scattered (N,) report views (losses /
-    fail / finish times) for policies, the cohort index, and a device
-    overflow flag (``|selected| > X`` — the engine defers it through
-    the round ledger).  Everything happens inside the one jitted
-    dispatch: compaction adds no per-round host transfer.
-
-    ``external_cache_params``: the ``cache_offload`` trainer variant
-    (requires ``cohort_size``).  ``caches`` then carries metadata only
-    (empty params pytree) and the cohort's (X, ...) cache-params block
-    arrives as an explicit argument — fetched from the host store by
-    the engine's cache stream — together with the precomputed cohort
-    index (the engine derives it in its own small jit so the host can
-    start the fetch as soon as the selection mask is dispatched).  The
-    round body is otherwise identical, so outputs are bit-identical to
-    the resident cohort variant fed the same rows.
+    The dynamics variant runs over the round's rows, which its inputs
+    name (see ``train_round``): the (X,) cohort index, or None for all N
+    rows in order; and the source of the rows' cache params — an (X, ...)
+    block fetched from the host store, or None to gather them from the
+    resident (N, ...) caches.  Round FLOPs track the rows, not the
+    fleet, and the gather happens inside the one jitted dispatch.
     """
     x_all = jnp.asarray(data.x)            # (N, n, d)
     y_all = jnp.asarray(data.y)            # (N, n)
@@ -128,12 +120,6 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
 
     grad_fn = (model or FLM.get_model("mlp")).value_and_grad
     donate_argnums = (3,) if donate and dynamics_features is None else ()
-    if cohort_size is not None and dynamics_features is None:
-        raise ValueError("cohort_size requires the dynamics trainer "
-                         "variant (pass dynamics_features)")
-    if external_cache_params and cohort_size is None:
-        raise ValueError("external_cache_params requires the compact "
-                         "cohort trainer variant (pass cohort_size)")
 
     def local_scan(x_arr, y_arr, start_params, steps_needed, stop_step,
                    cache_every, frozen):
@@ -238,72 +224,69 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         return (params, cache, cached_steps, mean_loss, steps_needed, fail,
                 success, times)
 
-    if cohort_size is None:
-        @jax.jit
-        def train_all_dyn(global_params, caches, draw, selected,
-                          distribute, resume, base_steps, cache_every,
-                          frozen=None):
-            """Dynamics round body: workload + failures + training +
-            timing.
-
-            draw:       repro.fleet.FleetDraw for this round (device
-                        arrays).
-            selected/distribute/resume: (N,) bool plan masks.
-            base_steps: (N,) int planned steps before resume credit.
-            Returns (final_params, cache_params, cached_steps, mean_loss,
-            steps_needed, fail, success, times) — times in simulated
-            seconds, inf where the device never uploads.
-            """
-            return round_body(x_all, y_all, feats.steps_per_sec,
-                              global_params, caches, draw, selected,
-                              distribute, resume, base_steps, cache_every,
-                              frozen)
-
-        return train_all_dyn
-
-    X = int(cohort_size)
     N = x_all.shape[0]
 
-    def cohort_round(idx, cache_params_x, global_params, caches, draw,
-                     selected, distribute, resume, base_steps,
-                     cache_every, frozen):
-        """Shared gather → (X, ...) round body → scatter given the cohort
-        index.  ``cache_params_x`` is None on the resident path (the
-        cohort's cache slots are gathered from the (N, D) pytree) or the
-        externally-fetched (X, ...) block on the offload path — every
-        other op is identical, which is what keeps the two variants
-        bit-identical row for row."""
-        def take(a, fill):
-            return jnp.take(a, idx, axis=0, mode="fill", fill_value=fill)
+    @jax.jit
+    def train_round(global_params, caches, cache_rows, idx, draw,
+                    selected, distribute, resume, base_steps, cache_every,
+                    frozen=None):
+        """Dynamics round body over the round's rows: workload + failures
+        + training + timing, one dispatch.
 
+        idx:        (X,) cohort index (sentinel-padded, from the engine's
+                    index jit) or None — every row of the fleet in order,
+                    with no gather and no scatter.
+        cache_rows: the rows' (X, ...) cache params (the host store's
+                    fetch; ``caches`` then carries metadata only) or None
+                    — gathered from the resident (N, ...) ``caches``.
+        draw:       repro.fleet.FleetDraw for this round (device arrays).
+        selected/distribute/resume: (N,) bool plan masks.
+        base_steps: (N,) int planned steps before resume credit.
+
+        Returns ``(final_params, cache_params, cached_steps, mean_loss,
+        steps_needed, fail, success, times, losses_n, fail_n, times_n)``:
+        the first eight are row blocks (times in simulated seconds, inf
+        where the device never uploads), the last three the (N,) report
+        views policies consume — idle clients read exactly what the full
+        scan computes for them (loss 0, no failure, inf time).  With
+        ``idx`` None the views are the blocks themselves.
+        """
+        def take(a, fill):
+            return core.take_rows(a, idx, fill)
+
+        if idx is not None:
+            idx = SP.cohort_constraint(idx, mesh, idx.shape[0])
         sel_x = take(selected, False)
         dist_x = take(distribute, False)
         res_x = take(resume, False)
         base_x = take(base_steps, 0)
         ce_x = take(cache_every, 1)
         sps_x = take(feats.steps_per_sec, 1.0)
-        draw_x = draw.take(idx)
-        if cache_params_x is None:
+        draw_x = draw if idx is None else draw.take(idx)
+        if cache_rows is None:
             caches_x = core.gather_caches(caches, idx)
         else:
-            caches_x = core.ClientCaches(cache_params_x,
+            caches_x = core.ClientCaches(cache_rows,
                                          take(caches.progress, 0.0),
                                          take(caches.round_stamp, -1))
-        x_x = jnp.take(x_all, idx, axis=0, mode="fill", fill_value=0)
-        y_x = jnp.take(y_all, idx, axis=0, mode="fill", fill_value=0)
-        (x_x, y_x, caches_x, draw_x, sel_x, dist_x, res_x, base_x, ce_x,
-         sps_x) = SP.cohort_constraint(
+        x_x = take(x_all, 0)
+        y_x = take(y_all, 0)
+        if idx is not None:
             (x_x, y_x, caches_x, draw_x, sel_x, dist_x, res_x, base_x,
-             ce_x, sps_x), mesh, X)
+             ce_x, sps_x) = SP.cohort_constraint(
+                (x_x, y_x, caches_x, draw_x, sel_x, dist_x, res_x, base_x,
+                 ce_x, sps_x), mesh, idx.shape[0])
 
         (params, cache, cached_steps, mean_loss, steps_needed, fail,
          success, times) = round_body(
             x_x, y_x, sps_x, global_params, caches_x, draw_x, sel_x,
             dist_x, res_x, base_x, ce_x, frozen)
+        if idx is None:
+            return (params, cache, cached_steps, mean_loss, steps_needed,
+                    fail, success, times, mean_loss, fail, times)
 
-        # (N,) report views: scatter the cohort rows, fill the rest with
-        # exactly what the full scan computes for idle clients (loss 0,
-        # no failure, inf finish time); sentinel rows drop
+        # (N,) report views: scatter the cohort rows, fill the rest;
+        # sentinel rows drop
         losses_n = jnp.zeros((N,), mean_loss.dtype) \
             .at[idx].set(mean_loss, mode="drop")
         fail_n = jnp.zeros((N,), bool).at[idx].set(fail, mode="drop")
@@ -314,52 +297,7 @@ def make_trainer(sim_cfg: SimConfig, data: FederatedClassification,
         return (params, cache, cached_steps, mean_loss, steps_needed,
                 fail, success, times, losses_n, fail_n, times_n)
 
-    if external_cache_params:
-        @jax.jit
-        def train_cohort_dyn_offload(global_params, caches,
-                                     cache_params_x, idx, draw, selected,
-                                     distribute, resume, base_steps,
-                                     cache_every, frozen=None):
-            """Offload cohort round body: like ``train_cohort_dyn`` but
-            the cohort index arrives precomputed (the engine's idx jit —
-            same ``cohort_index`` values) and the cohort's cache params
-            arrive as the host-store fetch; ``caches`` carries metadata
-            only.  Returns the 11-tuple without ``idx``/``overflow``
-            (the engine already holds both)."""
-            idx = SP.cohort_constraint(idx, mesh, X)
-            return cohort_round(idx, cache_params_x, global_params,
-                                caches, draw, selected, distribute,
-                                resume, base_steps, cache_every, frozen)
-
-        return train_cohort_dyn_offload
-
-    @jax.jit
-    def train_cohort_dyn(global_params, caches, draw, selected,
-                         distribute, resume, base_steps, cache_every,
-                         frozen=None):
-        """Compact-cohort dynamics round body (see the factory
-        docstring): gather → (X, ...) round body → scatter, one dispatch.
-
-        Inputs are the same (N,)-sized round arrays as the full-scan
-        variant; the cohort index is derived *inside* the jit.  Returns
-        ``(final_params_x, cache_params_x, cached_steps_x, mean_loss_x,
-        steps_needed_x, fail_x, success_x, times_x, idx, overflow,
-        losses_n, fail_n, times_n)`` — the ``_x`` blocks are (X,)-leading
-        cohort arrays; ``losses_n``/``fail_n``/``times_n`` are the (N,)
-        report views policies consume (idle clients read the same
-        zero-loss / no-fail / inf-time values the full scan computes for
-        them).
-        """
-        idx = cohort_index(selected, X)
-        idx = SP.cohort_constraint(idx, mesh, X)
-        overflow = cohort_overflow(selected, X)
-        outs = cohort_round(idx, None, global_params, caches, draw,
-                            selected, distribute, resume, base_steps,
-                            cache_every, frozen)
-        overflow, = SP.replicated_constraint((overflow,), mesh)
-        return outs[:8] + (idx, overflow) + outs[8:]
-
-    return train_cohort_dyn
+    return train_round
 
 
 # ---------------------------------------------------------------------------
@@ -562,6 +500,35 @@ class _RoundLedger:
             if self.progress and (rnd % 10 == 0
                                   or rnd == self.n_rounds - 1):
                 self.progress(rnd, self.acc, self.cum_comm, self.cum_time)
+
+
+class _DeviceRun(NamedTuple):
+    """What a device run holds fixed across its rounds."""
+    policy: Policy
+    tracer: Any
+    step_fn: Callable          # jitted dynamics step
+    trainer: Callable          # jitted ``train_round``
+    cut_fn: Callable           # jitted round cut, the policy's trait
+    server_step: Callable      # jitted fused server step
+    cache_every: Any           # (N,) cache interval
+    ones_w: Any                # (N,) unit aggregation weights
+    full_steps: Any            # (N,) planned local steps
+    metrics_fn: Optional[Callable]
+    m_keys: tuple
+    dyn_base: Any              # the dynamics key stream's base key
+
+
+@dataclasses.dataclass
+class _RoundCarry:
+    """What a device round reads and replaces: the policy state, the
+    fleet-dynamics state and its last draw, the global model, the caches
+    and the robust rule's state (None for stateless rules)."""
+    state: Any
+    fstate: Any
+    global_params: Any
+    caches: Any
+    rule_state: Any
+    draw: Any = None
 
 
 # ---------------------------------------------------------------------------
@@ -780,14 +747,9 @@ class FleetEngine:
         return caches
 
     def _server_step(self, uses_cache: bool):
-        # keyed on mesh shape + donation + cohort so ``run(policy)``
-        # reuse stays valid if the engine's placement knobs ever diverge
-        # per run (the cohort key is what memoizes the compact (X, D)
-        # step separately from the full-scan one)
-        mesh_key = None if self.mesh is None else \
-            tuple(self.mesh.devices.shape)
-        key = (bool(uses_cache), mesh_key, self.donate, self.cohort,
-               self.offload)
+        """Memoized fused server step for the policy's cache trait (the
+        rows it runs over come from its inputs)."""
+        key = bool(uses_cache)
         if key not in self._server_steps:
             self._server_steps[key] = core.make_server_round_step(
                 self._template, local_steps=self.sim_cfg.local_steps,
@@ -796,11 +758,10 @@ class FleetEngine:
                 agg_rule_params=self.fl_cfg.agg_rule_params,
                 adversary_scale=self._adv_scale,
                 staleness_discount=self.fl_cfg.staleness_discount,
-                uses_cache=bool(uses_cache),
+                uses_cache=key,
                 block_c=self.fl_cfg.agg_block_c,
                 block_d=self.fl_cfg.agg_block_d, mesh=self.mesh,
-                donate=self.donate, cohort_size=self.cohort,
-                cache_offload=self.offload)
+                donate=self.donate)
         return self._server_steps[key]
 
     # -- telemetry plumbing (repro.obs) -------------------------------------
@@ -892,13 +853,15 @@ class FleetEngine:
 
     def _metrics_dispatch(self, metrics_fn, m_keys, tracer, rnd,
                           global_params, caches, rule_state,
-                          stamp_pre_expire, **cand):
-        """Issue the fused metrics dispatch.  Must be called *before*
-        the round's server step: with ``donate_buffers`` the step
-        consumes (invalidates) the pre-step global model and cache
-        metadata the reductions read."""
+                          stamp_pre_expire, dispatch=None, **cand):
+        """Issue the fused metrics dispatch (through ``dispatch``, the
+        round's hook; default: call it).  Must be called *before* the
+        round's server step: with ``donate_buffers`` the step consumes
+        (invalidates) the pre-step global model and cache metadata the
+        reductions read."""
         if metrics_fn is None:
             return None
+        dispatch = dispatch or _call
         cand.update(progress=caches.progress, stamp=caches.round_stamp,
                     rnd=rnd)
         cand["global"] = global_params
@@ -908,9 +871,12 @@ class FleetEngine:
             cand["stamp_pre_expire"] = stamp_pre_expire
         with tracer.span("metrics", round=rnd):
             if "expert_load" in m_keys:
-                cand["expert_load"] = self._expert_load_jit()(
-                    global_params, self._frozen, self._x_dev, cand["idx"])
-            return metrics_fn({k: cand[k] for k in m_keys})
+                cand["expert_load"] = dispatch(
+                    "expert_load", self._expert_load_jit(),
+                    (global_params, self._frozen, self._x_dev,
+                     cand["idx"]))
+            return dispatch("metrics", metrics_fn,
+                            ({k: cand[k] for k in m_keys},))
 
     def _expert_load_jit(self):
         """Memoized jit of the model's held-expert load on the cohort
@@ -1027,35 +993,25 @@ class FleetEngine:
         N = self.fl_cfg.num_clients
         rows = N if self.cohort is None else int(self.cohort)
         step = self._server_step(uses_cache)
-        meta_only = self.offload is not None
-        caches = core.init_caches({} if meta_only else self._template, N)
+        caches = core.init_caches(
+            {} if self.offload is not None else self._template, N)
         stacked = jax.tree.map(
             lambda a: jnp.zeros((rows,) + a.shape, a.dtype),
             self._template)
         if self.mesh is not None:
             caches = SP.place_fleet(caches, self.mesh, N)
             stacked = SP.place_fleet(stacked, self.mesh, rows)
+        idx = None if self.cohort is None else \
+            self._put1(np.arange(rows, dtype=np.int32))
         mask = self._put1(np.zeros(rows, bool))
+        mask_n = self._put1(np.zeros(N, bool))
         steps_i = self._put1(np.zeros(rows, np.int32))
         ones = self._put1(np.ones(N, np.float32))
         rule_state = self._init_rule_state()
-        extra = self._step_extra(rule_state)
-        if self.cohort is None:
-            lowered = step.lower(self._template, caches, stacked, stacked,
-                                 steps_i, mask, mask, mask, mask,
-                                 self._n_samples, ones, 0, *extra)
-        elif meta_only:
-            idx = self._put1(np.arange(rows, dtype=np.int32))
-            mask_n = self._put1(np.zeros(N, bool))
-            lowered = step.lower(self._template, caches, stacked,
-                                 steps_i, idx, mask_n, mask, mask, mask_n,
-                                 self._n_samples, ones, 0, *extra)
-        else:
-            idx = self._put1(np.arange(rows, dtype=np.int32))
-            mask_n = self._put1(np.zeros(N, bool))
-            lowered = step.lower(self._template, caches, stacked, stacked,
-                                 steps_i, idx, mask_n, mask, mask, mask_n,
-                                 self._n_samples, ones, 0, *extra)
+        lowered = step.lower(self._template, caches, stacked, stacked,
+                             steps_i, idx, mask_n, mask, mask, mask_n,
+                             self._n_samples, ones, 0,
+                             *self._step_extra(rule_state))
         return lowered, caches, rule_state
 
     def run(self, policy: Union[str, Policy], rounds: Optional[int] = None,
@@ -1202,27 +1158,20 @@ class FleetEngine:
 
     def _round_cut(self, waits_for_stragglers: bool):
         """Memoized jitted device round cut (one variant per the policy's
-        straggler trait), everything device-resident.  With a cohort the
-        cut runs over the (X,) gathered finish times and additionally
+        straggler trait), everything device-resident.  Given a cohort
+        index the cut runs over the (X,) gathered finish times and
         scatters the (N,) receive mask (every finite time belongs to a
         cohort member, so the order statistics — and the cut — are
-        exact).  Built with ``with_counts=True``: the cut also returns
-        the round's (received, download, selected) ledger counts as
-        device scalars, fused into the same dispatch — the loop hands
-        them straight to the ledger, so per-round host bookkeeping is
-        O(1) scalar handles instead of an extra (N,)-reducing jit."""
-        key = (bool(waits_for_stragglers), self.cohort)
+        exact).  The loop passes the round's (N,) masks too, so the cut
+        also returns the round's (received, download, selected) ledger
+        counts as device scalars, fused into the same dispatch — per-round
+        host bookkeeping is O(1) scalar handles instead of an extra
+        (N,)-reducing jit."""
+        key = bool(waits_for_stragglers)
         if key not in self._cut_fns:
-            if self.cohort is None:
-                self._cut_fns[key] = core.make_round_cut(
-                    self.fl_cfg.num_clients, self.sim_cfg.round_deadline,
-                    key[0], mesh=self.mesh, with_counts=True)
-            else:
-                self._cut_fns[key] = core.make_round_cut(
-                    self.cohort, self.sim_cfg.round_deadline, key[0],
-                    mesh=self.mesh,
-                    scatter_num_clients=self.fl_cfg.num_clients,
-                    with_counts=True)
+            self._cut_fns[key] = core.make_round_cut(
+                self.fl_cfg.num_clients, self.sim_cfg.round_deadline, key,
+                mesh=self.mesh)
         return self._cut_fns[key]
 
     def _validate_plan(self, plan):
@@ -1368,16 +1317,12 @@ class FleetEngine:
                 fail=fail, losses=losses, times=times, rows=final,
                 rows_mask=received)
             with tracer.span("server_step", round=rnd):
-                out = server_step(
+                global_params, caches, _, _, rule_state = server_step(
                     global_params, caches, final, cache_p, cached_steps,
-                    self._put1(selected), self._put1(fail),
+                    None, self._put1(selected), self._put1(fail),
                     self._put1(received), self._put1(resume),
                     n_samples, extra_w, rnd,
                     *self._step_extra(rule_state))
-            if self._agg_stateful:
-                global_params, caches, rule_state = out
-            else:
-                global_params, caches = out
 
             if self.debug_checks:
                 self._debug_round_check(global_params, losses, None, rnd)
@@ -1416,13 +1361,12 @@ class FleetEngine:
 
     def _dynamics_fns(self, fleet):
         """Memoized device-dynamics artifacts for the configured process:
-        (process, jitted init, jitted step, fused dynamics trainer).  The
+        (process, jitted init, jitted step, fused round trainer).  The
         jitted step applies the fleet sharding constraint so draws stay
         sharded over the client mesh no matter what the process body
         produced.  (The round cut is memoized separately per straggler
         trait — see ``_round_cut``.)"""
-        key = (self.fl_cfg.dynamics, self.fl_cfg.dynamics_params,
-               self.cohort, self.offload)
+        key = (self.fl_cfg.dynamics, self.fl_cfg.dynamics_params)
         if key not in self._dyn_cache:
             N = self.fl_cfg.num_clients
             mesh = self.mesh
@@ -1439,14 +1383,11 @@ class FleetEngine:
             def dynamics_init(k):
                 return SP.fleet_constraint(process.init_state(k), mesh, N)
 
-            init_fn = jax.jit(dynamics_init)
-            trainer = make_trainer(
-                self.sim_cfg, self.data, mesh=mesh,
-                dynamics_features=feats, cohort_size=self.cohort,
-                external_cache_params=self.offload is not None,
-                model=self.model)
-            self._dyn_cache[key] = (process, init_fn, jax.jit(step),
-                                    trainer)
+            trainer = make_trainer(self.sim_cfg, self.data, mesh=mesh,
+                                   dynamics_features=feats,
+                                   model=self.model)
+            self._dyn_cache[key] = (process, jax.jit(dynamics_init),
+                                    jax.jit(step), trainer)
         return self._dyn_cache[key]
 
     def _dyn_consts(self, fleet, uses_cache):
@@ -1478,15 +1419,13 @@ class FleetEngine:
             return self._put1(np.asarray(arr) if dtype is None
                               else np.asarray(arr, dtype))
 
-    # -- cache-offload round plumbing ----------------------------------------
+    # -- the device round: one body over an index of rows ----------------
 
-    def _offload_idx_fn(self):
+    def _cohort_idx_fn(self):
         """Memoized jit deriving the round's cohort index + overflow flag
-        from the selection mask.  On the resident path this lives inside
-        the trainer jit; the offload path needs the index *before* the
-        trainer runs (the host-store fetch consumes it), so it gets its
-        own small dispatch — same ``cohort_index`` computation, so the
-        values (and everything downstream) are identical."""
+        from the selection mask (``FLConfig.cohort_size`` set).  It is its
+        own small dispatch, so the host can start the host store's fetch
+        as soon as the index exists, before the trainer is dispatched."""
         if self._idx_fn is None:
             X, mesh = int(self.cohort), self.mesh
 
@@ -1518,10 +1457,10 @@ class FleetEngine:
 
     def _zero_cohort_block(self):
         """Memoized all-zero (X, ...) cache block for policies that never
-        cache (``uses_cache=False``): the resident path would gather the
-        never-written zero pytree, so a constant zeros block placed once
-        keeps the offload trainer's inputs — and its rounds — identical,
-        with no per-round transfer at all."""
+        cache (``uses_cache=False``) under offload: the resident path
+        would gather the never-written zero pytree, so a constant zeros
+        block placed once keeps the trainer's inputs — and its rounds —
+        identical, with no per-round transfer at all."""
         if self._zeros_x is None:
             X = int(self.cohort)
             block = jax.tree.map(
@@ -1534,49 +1473,185 @@ class FleetEngine:
             self._zeros_x = block
         return self._zeros_x
 
+    def _device_run(self, policy, fleet, state, global_params, caches,
+                    level, tracer):
+        """Build what a device run of ``policy`` holds fixed (a
+        ``_DeviceRun``) and its rounds' first ``_RoundCarry``.  ``level``
+        is the telemetry level (None: off)."""
+        _process, init_fn, step_fn, trainer = self._dynamics_fns(fleet)
+        cache_every, ones_w, full_steps = self._dyn_consts(
+            fleet, policy.uses_cache)
+        # cohort rows are already the compact (X, ...) block; the full
+        # scan advertises the policy's selection bound so O(rows · D)
+        # metrics gather received rows instead of reading all N
+        metrics_fn, m_keys = (None, ()) if level is None else \
+            self._metrics_fn(level, policy.uses_cache,
+                             rows_bound=None if self.cohort is not None
+                             else policy.selection_bound())
+        # independent dynamics key stream, reproducible per run
+        dyn_base = jax.random.fold_in(jax.random.key(self.sim_cfg.seed),
+                                      0x0F1EE7)
+        run = _DeviceRun(policy, tracer, step_fn, trainer,
+                         self._round_cut(policy.waits_for_stragglers),
+                         self._server_step(policy.uses_cache), cache_every,
+                         ones_w, full_steps, metrics_fn, m_keys, dyn_base)
+        carry = _RoundCarry(state, init_fn(jax.random.fold_in(dyn_base,
+                                                              1 << 20)),
+                            global_params, caches, self._init_rule_state())
+        return run, carry
+
+    def _device_round(self, run, carry, rnd, k_sel, evaluated,
+                      dispatch=_call):
+        """One device round, dispatched with no host value in between:
+        dynamics step → (discard expiry) → plan → cohort index → cache
+        fetch → trainer → round cut → metrics → server step → cache stage
+        → observe → (eval).  Replaces ``carry``'s fields and returns
+        ``(report, entry)``, ``entry`` being the round's ledger handles
+        after ``evaluated``.  Every jitted dispatch goes through
+        ``dispatch(name, fn, args, **contract)`` (see ``_call``).
+
+        The round runs over an index of rows: the cohort index when
+        ``FLConfig.cohort_size`` is set, else None — all N rows, with no
+        gather or scatter.  Its cache rows come from the host store's
+        fetch (offload), a zero block (offload, a policy that never
+        caches), or the resident caches (None: the trainer gathers
+        them)."""
+        tracer, policy = run.tracer, run.policy
+        uses_cache = policy.uses_cache
+        stream = self._cache_stream if uses_cache else None
+        with tracer.span("dynamics_step", round=rnd):
+            carry.fstate, draw = dispatch(
+                "dynamics_step", run.step_fn,
+                (carry.fstate, jax.random.fold_in(run.dyn_base, rnd)))
+        carry.draw = draw
+        stamp_pre_expire = None
+        if self.offload == "discard" and uses_cache:
+            # device half of the discard bound: expire stale cache
+            # metadata *before* planning reads it, so the planner never
+            # resumes a row the host store prunes (the store prunes with
+            # the same bound at write-back drain).  The pre-expiry stamps
+            # stay live for the metrics dispatch (cache_expired counts;
+            # the expire jit donates nothing)
+            if run.metrics_fn is not None:
+                stamp_pre_expire = carry.caches.round_stamp
+            with tracer.span("cache_expire", round=rnd):
+                carry.caches = dispatch("cache_expire",
+                                        self._expire_fn_jit(),
+                                        (carry.caches, rnd))
+        caches = carry.caches
+        with tracer.span("plan", round=rnd):
+            carry.state, plan = policy.plan(
+                carry.state, RoundObservation(rnd, draw.online, caches,
+                                              draw=draw), k_sel)
+        self._validate_plan(plan)
+        sel_d = self._from_plan(plan.selected)
+        dist_d = self._from_plan(plan.distribute)
+        res_d = self._from_plan(plan.resume)
+        base_steps = run.full_steps if plan.steps_override is None else \
+            self._from_plan(plan.steps_override, np.int32)
+        extra_w = run.ones_w if plan.agg_weights is None else \
+            self._from_plan(plan.agg_weights, np.float32)
+
+        idx = overflow = cache_x = None
+        if self.cohort is not None:
+            with tracer.span("cohort_index", round=rnd):
+                idx, overflow = dispatch("cohort_index",
+                                         self._cohort_idx_fn(), (sel_d,))
+            # observability seam (tests / debugging): the last round's
+            # device cohort index, still sharded
+            self._last_cohort_idx = idx
+        if stream is not None:
+            # the host store streams the cohort's cache rows: the async
+            # d2h of idx, the drain of last round's write-back and the
+            # async put of the hit rows start while this round's other
+            # dispatches are being issued
+            with tracer.span("cache_fetch", round=rnd):
+                cache_x = stream.fetch(idx, rnd)
+        elif self.offload is not None:
+            cache_x = self._zero_cohort_block()
+        with tracer.span("trainer", round=rnd):
+            (final, cache_p, cached_steps, _losses_x, _steps_x, fail,
+             success, times, losses_n, fail_n, times_n) = dispatch(
+                "trainer", run.trainer,
+                (carry.global_params, caches, cache_x, idx, draw, sel_d,
+                 dist_d, res_d, base_steps, run.cache_every,
+                 self._frozen))
+        # round termination on device: the cut is a device scalar and
+        # the receive mask stays sharded; deadline-capped rounds come
+        # back as a flag so the ledger bills the exact f64 deadline.  The
+        # ledger counts ride the same dispatch.
+        with tracer.span("round_cut", round=rnd):
+            (t_cut, received_x, received, capped, recv_n, down_n,
+             sel_n) = dispatch(
+                "round_cut", run.cut_fn,
+                (times, plan.quorum, success, idx, draw.online, dist_d,
+                 sel_d))
+        mx = self._metrics_dispatch(
+            run.metrics_fn, run.m_keys, tracer, rnd, carry.global_params,
+            caches, carry.rule_state, stamp_pre_expire, dispatch,
+            selected=sel_d, distribute=dist_d, resume=res_d,
+            online=draw.online, received=received, fail=fail_n,
+            losses=losses_n, times=times_n, rows=final,
+            rows_mask=received_x, idx=idx)
+        with tracer.span("server_step", round=rnd):
+            (carry.global_params, carry.caches, write_x, stamp_x,
+             carry.rule_state) = dispatch(
+                "server_step", run.server_step,
+                (carry.global_params, caches, final, cache_p, cached_steps,
+                 idx, sel_d, fail, received_x, res_d, self._n_samples,
+                 extra_w, rnd, *self._step_extra(carry.rule_state)),
+                donated_args=2 if self.donate else 0)
+        if stream is not None:
+            # park the round's write-back: async copies start now,
+            # nothing blocks until next round's fetch
+            with tracer.span("cache_stage", round=rnd):
+                stream.stage(idx, write_x, received_x, cache_p, stamp_x)
+        # the round's (X, D) blocks are dead once dispatched (the stream
+        # holds the staged one until its drain): drop them, so that they
+        # are not live under the eval, the next round's fetch and trainer
+        del cache_x, final, cache_p
+        report = RoundReport(received=received, fail=fail_n,
+                             losses=losses_n, durations=times_n,
+                             duration=t_cut, rnd=rnd)
+        if self.debug_checks:
+            self._debug_round_check(carry.global_params, report.losses,
+                                    idx, rnd)
+        with tracer.span("observe", round=rnd):
+            carry.state = policy.observe(carry.state, plan, report)
+        acc_dev = None
+        if evaluated:
+            with tracer.span("eval", round=rnd):
+                acc_dev = dispatch(
+                    "eval_accuracy", self._acc_fn,
+                    (carry.global_params, self._frozen, self._test_x,
+                     self._test_y), sharded=False)
+        return report, (t_cut, capped, recv_n, down_n, sel_n, acc_dev,
+                        overflow, mx)
+
     def _device_rounds(self, policy, state, fleet, hist, global_params,
                        caches, rng, n_rounds, time_budget, eval_every,
                        progress, tel=None):
         """Dynamics round loop: the round's availability/failure draw,
         workload, local training, timing model AND the quorum cut run on
-        device (sharded over the client mesh) — process step, fused
-        trainer, round cut, fused server step, four dispatches with no
-        host value in between.  Bookkeeping is deferred through a
-        ``_RoundLedger``: History rows are read back only when the
-        pipeline depth forces it, at eval boundaries (the accuracy
+        device (sharded over the client mesh) — ``_device_round``, with
+        no host value between its dispatches.  Bookkeeping is deferred
+        through a ``_RoundLedger``: History rows are read back only when
+        the pipeline depth forces it, at eval boundaries (the accuracy
         scalar), or at run end — with ``pipeline_depth`` > 1 the host
         dispatches round k+1 while round k still executes.  jnp-native
         policies (flude) keep even planning on device; host-side policies
         sync at their own ``np.asarray`` boundaries as before."""
         sim_cfg = self.sim_cfg
-        n_samples = self._n_samples
-        process, init_fn, step_fn, trainer = self._dynamics_fns(fleet)
-        cache_every, ones_w, full_steps = self._dyn_consts(
-            fleet, policy.uses_cache)
-        server_step = self._server_step(policy.uses_cache)
-        rule_state = self._init_rule_state()
-        cut_fn = self._round_cut(policy.waits_for_stragglers)
+        tracer = tel.tracer if tel is not None else obs.NULL_TRACER
+        run, carry = self._device_run(
+            policy, fleet, state, global_params, caches,
+            None if tel is None else tel.level, tracer)
         cohort_info = None if self.cohort is None \
             else (policy.name, self.cohort)
-        tracer = tel.tracer if tel is not None else obs.NULL_TRACER
-        # cohort rows are already the compact (X, ...) block; the full
-        # scan advertises the policy's selection bound so O(rows · D)
-        # metrics gather received rows instead of reading all N
-        metrics_fn, m_keys = (None, ()) if tel is None else \
-            self._metrics_fn(tel.level, policy.uses_cache,
-                             rows_bound=None if self.cohort is not None
-                             else policy.selection_bound())
         ledger = _RoundLedger(hist, sim_cfg.model_mb,
                               sim_cfg.round_deadline, progress, n_rounds,
                               cohort_info=cohort_info, telemetry=tel,
                               tracer=tracer)
-
-        # independent dynamics key stream, reproducible per run
-        dyn_base = jax.random.fold_in(jax.random.key(sim_cfg.seed),
-                                      0x0F1EE7)
-        fstate = init_fn(jax.random.fold_in(dyn_base, 1 << 20))
-
-        draw = None
         for rnd in tracer.steps("round", n_rounds):
             if time_budget is not None:
                 # the budget check needs cum_time: resolve everything
@@ -1587,189 +1662,10 @@ class FleetEngine:
             if tel is not None:
                 tel.maybe_profile(rnd)
             rng, k_sel = jax.random.split(rng)
-            with tracer.span("dynamics_step", round=rnd):
-                fstate, draw = step_fn(fstate,
-                                       jax.random.fold_in(dyn_base, rnd))
-            stamp_pre_expire = None
-            if self.offload == "discard" and policy.uses_cache:
-                # device half of the discard bound: expire stale cache
-                # metadata *before* planning reads it, so the planner
-                # never resumes a row the host store prunes (the store
-                # prunes with the same bound at write-back drain).  The
-                # pre-expiry stamps stay live for the metrics dispatch
-                # (cache_expired counts; the expire jit donates nothing)
-                if metrics_fn is not None:
-                    stamp_pre_expire = caches.round_stamp
-                with tracer.span("cache_expire", round=rnd):
-                    caches = self._expire_fn_jit()(caches, rnd)
-            with tracer.span("plan", round=rnd):
-                state, plan = policy.plan(
-                    state, RoundObservation(rnd, draw.online, caches,
-                                            draw=draw), k_sel)
-            self._validate_plan(plan)
-            sel_d = self._from_plan(plan.selected)
-            dist_d = self._from_plan(plan.distribute)
-            res_d = self._from_plan(plan.resume)
-            base_steps = full_steps if plan.steps_override is None else \
-                self._from_plan(plan.steps_override, np.int32)
-
-            extra_w = ones_w if plan.agg_weights is None else \
-                self._from_plan(plan.agg_weights, np.float32)
-            if self.cohort is None:
-                # fused round body: workload + failure/interruption +
-                # masked local training + per-device timing, one dispatch
-                with tracer.span("trainer", round=rnd):
-                    (final, cache_p, cached_steps, losses, steps_needed,
-                     fail, success, times) = trainer(
-                        global_params, caches, draw, sel_d, dist_d,
-                        res_d, base_steps, cache_every, self._frozen)
-
-                # round termination on device: the cut is a device scalar
-                # and the receive mask stays sharded; deadline-capped
-                # rounds come back as a flag so the ledger bills the
-                # exact f64 deadline.  The ledger counts ride the same
-                # dispatch (``with_counts``).
-                with tracer.span("round_cut", round=rnd):
-                    (t_cut, received, capped, recv_n, down_n,
-                     sel_n) = cut_fn(times, plan.quorum, success,
-                                     draw.online, dist_d, sel_d)
-                overflow = None
-                mx = self._metrics_dispatch(
-                    metrics_fn, m_keys, tracer, rnd, global_params,
-                    caches, rule_state, stamp_pre_expire,
-                    selected=sel_d, distribute=dist_d, resume=res_d,
-                    online=draw.online, received=received, fail=fail,
-                    losses=losses, times=times, rows=final,
-                    rows_mask=received)
-                with tracer.span("server_step", round=rnd):
-                    out = server_step(
-                        global_params, caches, final, cache_p,
-                        cached_steps, sel_d, fail, received, res_d,
-                        n_samples, extra_w, rnd,
-                        *self._step_extra(rule_state))
-                if self._agg_stateful:
-                    global_params, caches, rule_state = out
-                else:
-                    global_params, caches = out
-                report = RoundReport(received=received, fail=fail,
-                                     losses=losses, durations=times,
-                                     duration=t_cut, rnd=rnd)
-            elif self.offload is None:
-                # compact cohort: the trainer gathers the selected rows
-                # into (X, ...) blocks on device and hands back scattered
-                # (N,) report views; cut + aggregation run over X rows
-                with tracer.span("trainer", round=rnd):
-                    (final, cache_p, cached_steps, _losses_x, _steps_x,
-                     fail, success, times, idx, overflow, losses_n,
-                     fail_n, times_n) = trainer(
-                        global_params, caches, draw, sel_d, dist_d,
-                        res_d, base_steps, cache_every, self._frozen)
-                with tracer.span("round_cut", round=rnd):
-                    (t_cut, _received_x, received, capped, recv_n,
-                     down_n, sel_n) = cut_fn(times, plan.quorum, success,
-                                             idx, draw.online, dist_d,
-                                             sel_d)
-                # observability seam (tests / debugging): the last
-                # round's device cohort index, still sharded
-                self._last_cohort_idx = idx
-                mx = self._metrics_dispatch(
-                    metrics_fn, m_keys, tracer, rnd, global_params,
-                    caches, rule_state, stamp_pre_expire,
-                    selected=sel_d, distribute=dist_d, resume=res_d,
-                    online=draw.online, received=received, fail=fail_n,
-                    losses=losses_n, times=times_n, rows=final,
-                    rows_mask=_received_x, idx=idx)
-                with tracer.span("server_step", round=rnd):
-                    out = server_step(
-                        global_params, caches, final, cache_p,
-                        cached_steps, idx, sel_d, fail, _received_x,
-                        res_d, n_samples, extra_w, rnd,
-                        *self._step_extra(rule_state))
-                if self._agg_stateful:
-                    global_params, caches, rule_state = out
-                else:
-                    global_params, caches = out
-                report = RoundReport(received=received, fail=fail_n,
-                                     losses=losses_n, durations=times_n,
-                                     duration=t_cut, rnd=rnd)
-            else:
-                # host-offloaded cohort caches: derive the cohort index
-                # in its own small jit so the host can start streaming
-                # the cohort's cache rows (async d2h of idx, drain of
-                # last round's write-back, async device_put of the (X,
-                # ...) block) while this round's other dispatches are
-                # being issued; the trainer/cut/server step are the same
-                # cohort ops over the same rows, so trajectories stay
-                # bit-identical to the resident path
-                with tracer.span("cohort_index", round=rnd):
-                    idx, overflow = self._offload_idx_fn()(sel_d)
-                if policy.uses_cache:
-                    with tracer.span("cache_fetch", round=rnd):
-                        cache_x = self._cache_stream.fetch(idx, rnd)
-                else:
-                    cache_x = self._zero_cohort_block()
-                with tracer.span("trainer", round=rnd):
-                    (final, cache_p, cached_steps, _losses_x, _steps_x,
-                     fail, success, times, losses_n, fail_n,
-                     times_n) = trainer(
-                        global_params, caches, cache_x, idx, draw, sel_d,
-                        dist_d, res_d, base_steps, cache_every,
-                        self._frozen)
-                with tracer.span("round_cut", round=rnd):
-                    (t_cut, _received_x, received, capped, recv_n,
-                     down_n, sel_n) = cut_fn(times, plan.quorum, success,
-                                             idx, draw.online, dist_d,
-                                             sel_d)
-                self._last_cohort_idx = idx
-                mx = self._metrics_dispatch(
-                    metrics_fn, m_keys, tracer, rnd, global_params,
-                    caches, rule_state, stamp_pre_expire,
-                    selected=sel_d, distribute=dist_d, resume=res_d,
-                    online=draw.online, received=received, fail=fail_n,
-                    losses=losses_n, times=times_n, rows=final,
-                    rows_mask=_received_x, idx=idx)
-                with tracer.span("server_step", round=rnd):
-                    out = server_step(
-                        global_params, caches, final, cached_steps, idx,
-                        sel_d, fail, _received_x, res_d, n_samples,
-                        extra_w, rnd, *self._step_extra(rule_state))
-                if self._agg_stateful:
-                    (global_params, caches, write_x, stamp_x,
-                     rule_state) = out
-                else:
-                    global_params, caches, write_x, stamp_x = out
-                if policy.uses_cache:
-                    # park the round's write-back: async copies start
-                    # now, nothing blocks until next round's fetch
-                    with tracer.span("cache_stage", round=rnd):
-                        self._cache_stream.stage(idx, write_x,
-                                                 _received_x, cache_p,
-                                                 stamp_x)
-                # the round's (X, D) blocks are dead once dispatched (the
-                # stream holds the staged one until its drain): drop them,
-                # so that they are not live under the next round's fetch
-                # and trainer
-                del cache_x, final, cache_p
-                report = RoundReport(received=received, fail=fail_n,
-                                     losses=losses_n, durations=times_n,
-                                     duration=t_cut, rnd=rnd)
-
-            if self.debug_checks:
-                self._debug_round_check(
-                    global_params, report.losses,
-                    None if self.cohort is None else idx, rnd)
-            with tracer.span("observe", round=rnd):
-                state = policy.observe(state, plan, report)
-
             evaluated = rnd % eval_every == 0 or rnd == n_rounds - 1
-            acc_dev = None
-            if evaluated:
-                with tracer.span("eval", round=rnd):
-                    acc_dev = self._acc_fn(global_params, self._frozen,
-                                           self._test_x, self._test_y)
-            ledger.push(rnd, evaluated, t_cut, capped, recv_n,
-                        down_n, sel_n, acc_dev, overflow=overflow,
-                        metrics=mx)
+            _report, entry = self._device_round(run, carry, rnd, k_sel,
+                                                evaluated)
+            ledger.push(rnd, evaluated, *entry)
             if progress and rnd % 10 == 0:
                 ledger.resolve()        # live ticks resolve on schedule
             else:
@@ -1784,7 +1680,7 @@ class FleetEngine:
                 self._cache_stream.flush(n_rounds)
         # pipelining seam: the process state (and last draw) stay
         # device-resident between runs, like the caches
-        self._last_fleet_state = fstate
-        self._last_draw = draw
-        self._last_rule_state = rule_state
-        return state, global_params, caches
+        self._last_fleet_state = carry.fstate
+        self._last_draw = carry.draw
+        self._last_rule_state = carry.rule_state
+        return carry.state, carry.global_params, carry.caches

@@ -213,16 +213,18 @@ def test_server_step_donation_invalidates_inputs():
      resume, n_samples, ones) = _toy_round_inputs()
     ref_step = core.make_server_round_step(template, local_steps=2,
                                            donate=False)
-    ref_g, ref_c = ref_step(template, caches, final, cache_p, cached_steps,
-                            sel, fail, received, resume, n_samples, ones, 0)
+    ref_g, ref_c, _, _, _ = ref_step(template, caches, final, cache_p,
+                                     cached_steps, None, sel, fail,
+                                     received, resume, n_samples, ones, 0)
 
     (template2, caches2, final2, cache_p2, cached_steps2, *_) = \
         _toy_round_inputs()
     don_step = core.make_server_round_step(template2, local_steps=2,
                                            donate=True)
     g_in = jax.tree.map(jnp.copy, template2)
-    got_g, got_c = don_step(g_in, caches2, final2, cache_p2, cached_steps2,
-                            sel, fail, received, resume, n_samples, ones, 0)
+    got_g, got_c, _, _, _ = don_step(g_in, caches2, final2, cache_p2,
+                                     cached_steps2, None, sel, fail,
+                                     received, resume, n_samples, ones, 0)
     # donated inputs (previous global model + caches) are dead...
     assert all(l.is_deleted() for l in jax.tree.leaves(g_in))
     assert all(l.is_deleted() for l in jax.tree.leaves(caches2))

@@ -69,6 +69,17 @@ def test_cut_quorum_edges_0_1_N(n, seed, waits):
             _check(times, q, waits)
 
 
+@pytest.mark.parametrize("waits", [True, False])
+def test_cut_subnormal_quorum_counts_one_upload(waits):
+    """A positive quorum below float32's normal range still waits for
+    one upload: XLA flushes subnormals to zero on the device, where the
+    host's ``ceil`` gives 1."""
+    quorum = float(np.float32(1e-40))
+    assert 0.0 < quorum < float(np.finfo(np.float32).tiny)
+    for inf_rate in (0.0, 0.5, 1.0):
+        _check(_times(16, inf_rate, 3), quorum, waits)
+
+
 @given(st.integers(1, 48), st.floats(0.3, 1.0),
        st.integers(0, 2 ** 31 - 1))
 def test_cut_async_last_arrival(n, inf_rate, seed):

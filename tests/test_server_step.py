@@ -140,11 +140,11 @@ def test_server_round_step_matches_leafwise_3round_smoke(impl):
         if rnd == 1:
             received[:] = False                    # empty round
         resume = selected & (rng.rand(N) < 0.5)
-        g_new, caches_new = step(
+        g_new, caches_new, _, _, _ = step(
             g_new, caches_new, final, cache_p, jnp.asarray(cached_steps),
-            jnp.asarray(selected), jnp.asarray(fail), jnp.asarray(received),
-            jnp.asarray(resume), n_samples, jnp.ones((N,), jnp.float32),
-            rnd)
+            None, jnp.asarray(selected), jnp.asarray(fail),
+            jnp.asarray(received), jnp.asarray(resume), n_samples,
+            jnp.ones((N,), jnp.float32), rnd)
         g_old, caches_old = _old_leafwise_round(
             g_old, caches_old, final, cache_p, cached_steps, selected,
             fail, received, resume, n_samples, rnd, local_steps)
